@@ -7,7 +7,7 @@ logs with no single ordered record of what happened when.  This module
 is that record.  Events are emitted from the real seams — CLI phase
 transitions, compile start/end (engine._guard_first_call), tier
 fallbacks, fault firings, supervisor kill/restart/elastic decisions,
-coordinated checkpoint publish/GC, chip-probe verdicts — one JSON
+coordinated checkpoint publish/GC — one JSON
 object per line, flushed per event so a SIGKILLed process's last
 decision is on disk.
 

@@ -24,7 +24,6 @@ Naming convention (dotted, lowercase):
   engine.first_calls.degraded_inprocess[.family]   deadline-degraded
                                scan-tier family compiled in-process
                                (watchdogged; expected, not a gap)
-  engine.pallas_fallbacks      Mosaic -> XLA demotions
   engine.watchdog_barks        compile-deadline watchdog firings
   engine.nonfinite_retries/.nonfinite_recovered   NaN-lnL scan-tier retries
   bank.families/banked/timeouts/errors/skipped/fallbacks   AOT banking
@@ -47,7 +46,6 @@ Naming convention (dotted, lowercase):
                                launch-latency floor (dispatch-bound),
                                0.0 = bandwidth-meaningful
                                (obs/traffic.classify_regime)
-  chip.probe.<verdict>         chip_probe answer/no-answer/hang tallies
   faults.fired.<point>         injected faults that fired (chaos tests)
   search.spr_cycles, search.fast_cycles, search.thorough_cycles
   search.scan_dispatches, search.scan_candidates

@@ -335,14 +335,17 @@ def _candidate_manifests(manifest_name: str):
     if cache:
         p = os.path.join(cache, manifest_name)
         return [p] if os.path.exists(p) else []
-    env = os.environ.get("EXAML_COMPILE_CACHE")
-    if env == "0":
+    from examl_tpu.config import compile_cache_root
+    root = compile_cache_root()
+    if root is None:
         return []
-    root = env or os.path.expanduser("~/.cache/examl_tpu/xla")
     out = []
     try:
-        for sub in sorted(os.listdir(root)):
-            p = os.path.join(root, sub, manifest_name)
+        # The root itself (accelerators, or JAX's own directory) and
+        # its per-host CPU partitions.
+        for d in [root] + [os.path.join(root, sub)
+                           for sub in sorted(os.listdir(root))]:
+            p = os.path.join(d, manifest_name)
             if os.path.exists(p):
                 out.append(p)
     except OSError:

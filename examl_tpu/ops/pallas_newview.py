@@ -42,10 +42,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from examl_tpu.ops import kernels
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# tier (and its interpret-mode tests) runs across jax versions.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -160,7 +156,7 @@ def _run_chunk(clv, scaler, lidx, ridx, base, opl, opr, lcodes, rcodes,
         # inputs: 0 lidx, 1 ridx, 2 base, 3 clv, 4 scaler, 5 opl, 6 opr,
         # 7 lcodes, 8 rcodes, 9 scsum
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
             flops=flops_dot, transcendentals=0,

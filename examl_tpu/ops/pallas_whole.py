@@ -41,11 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 from examl_tpu.ops import kernels
 from examl_tpu.tree.topology import Tree, TraversalEntry
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# tier (and its interpret-mode tests) runs across jax versions.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 class FlatSchedule(NamedTuple):
     """Wave-ordered per-entry metadata (host arrays)."""
@@ -275,7 +270,7 @@ def run_flat_arrays(models, block_part, tips, clv, scaler, E: int,
                    jax.ShapeDtypeStruct(scaler.shape, scaler.dtype)],
         # inputs: 0 meta, 1 clv, 2 scaler, 3 pb_all, 4 codes, 5 tab2
         input_output_aliases={1: 0, 2: 1},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(meta, clvf, scaler, pb_all, codes, tab2)

@@ -17,7 +17,8 @@ plugin must not be able to hang the watcher).  It:
 * restarts from the newest checkpoint (`-R` once one exists) with
   capped retries, exponential backoff, and ESCALATING degradation pins
   mirroring the bank's escape hatches: retry 1 pins `EXAML_PALLAS=0`
-  (pallas→chunk), retry 2 pins `EXAML_UNIVERSAL=force`
+  (the default XLA chunk tier, whatever the failed run asked for),
+  retry 2 pins `EXAML_UNIVERSAL=force`
   (chunk→universal: the topology-as-data interpreter compiles ONE
   program regardless of topology, so a wedge inside a per-profile
   chunk compile cannot recur), retry 3+ pins the scan tier

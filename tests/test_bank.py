@@ -85,6 +85,45 @@ def test_cpu_cache_disabled_without_fingerprint(monkeypatch):
         assert config.enable_persistent_compilation_cache() is not None
 
 
+_CACHE_PROBE = (
+    "import jax; from examl_tpu import config; "
+    "p = config.enable_persistent_compilation_cache(); "
+    "print('PATH', p); print('JAXDIR', jax.config.jax_compilation_cache_dir)")
+
+
+def _cache_probe(env_extra):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "EXAML_COMPILE_CACHE")}
+    env.update(JAX_PLATFORMS="cpu", **env_extra)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode == 0, out.stderr
+    return dict(line.split(" ", 1) for line in out.stdout.splitlines()
+                if line.startswith(("PATH ", "JAXDIR "))), out.stderr
+
+
+def test_jax_cache_dir_variable_stands(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the package sets no directory:
+    jax keeps the one it was given, and a path in EXAML_COMPILE_CACHE
+    ranks below it (ignored, with one line saying so)."""
+    want = str(tmp_path / "from_outside")
+    got, err = _cache_probe({"JAX_COMPILATION_CACHE_DIR": want,
+                             "EXAML_COMPILE_CACHE": str(tmp_path / "x")})
+    assert got == {"PATH": want, "JAXDIR": want}
+    assert err.count("ignoring EXAML_COMPILE_CACHE") == 1
+    assert not (tmp_path / "x").exists()
+
+
+def test_default_cache_is_fixed_under_checkout():
+    """Unset, two processes resolve the same path under the checkout —
+    no pid, time or temp name in it (the path is part of the key)."""
+    a, _ = _cache_probe({})
+    b, _ = _cache_probe({})
+    assert a == b and a["PATH"] == a["JAXDIR"]
+    assert a["PATH"].startswith(os.path.join(REPO, ".xla_cache") + os.sep)
+
+
 # -- family enumeration / manifest / exit diagnosis -------------------------
 
 

@@ -354,7 +354,7 @@ def test_run_report_renders_synthetic_artifacts(tmp_path):
     snap = reg.snapshot()
     snap["counters"] = {"engine.dispatch_count": 3,
                         "engine.traffic_bytes": 3e9,
-                        "chip.probe.answer": 1}
+                        "faults.fired.compile_hang": 1}
     snap["gauges"] = {"engine.achieved_gbps.scan": 21.0,
                       "engine.regime_dispatch_bound.scan": 1.0}
     ledger.enable(str(tmp_path), proc=0)
@@ -381,7 +381,7 @@ def test_run_report_renders_synthetic_artifacts(tmp_path):
     assert "compile" in text                    # timeline event
     assert "family=wedged" in text              # unmatched start kept
     assert text.count("status=start") == 1      # matched start dropped
-    assert "chip probes" in text and "answer=1" in text
+    assert "faults fired" in text and "compile_hang=1" in text
     assert f"{traffic.ROOFLINE_TARGET_GBPS:.0f} GB/s" in text
 
 

@@ -73,13 +73,15 @@ def test_pallas_scaling_events_match():
 
 
 def test_engine_full_traversal_pallas(monkeypatch):
-    """End to end through the engine: EXAML_PALLAS_INTERPRET routes the
-    jitted fast program through the Pallas kernels; lnL must match the
-    XLA fast path."""
+    """End to end through the engine: EXAML_PALLAS=1 asks for the
+    kernels and EXAML_PALLAS_INTERPRET routes the jitted fast program
+    through the interpreter; lnL must match the XLA fast path."""
+    monkeypatch.delenv("EXAML_PALLAS", raising=False)
     inst = _instance("AA", 16, 200, seed=4)
     tree = inst.random_tree(4)
     lnl_ref = inst.evaluate(tree, full=True)
 
+    monkeypatch.setenv("EXAML_PALLAS", "1")
     monkeypatch.setenv("EXAML_PALLAS_INTERPRET", "1")
     inst2 = _instance("AA", 16, 200, seed=4)
     eng2 = inst2.engines[20]
@@ -87,6 +89,33 @@ def test_engine_full_traversal_pallas(monkeypatch):
     tree2 = inst2.random_tree(4)
     lnl_pal = inst2.evaluate(tree2, full=True)
     assert lnl_pal == pytest.approx(lnl_ref, abs=5e-3)
+
+
+def test_engine_pallas_unset_is_xla_tier(monkeypatch):
+    """With EXAML_PALLAS unset no engine takes a kernel tier, and the
+    interpret switch alone does not ask for one."""
+    monkeypatch.delenv("EXAML_PALLAS", raising=False)
+    monkeypatch.setenv("EXAML_PALLAS_INTERPRET", "1")
+    eng = _instance("DNA", 8, 64, seed=6).engines[4]
+    assert not eng.use_pallas and not eng.pallas_whole
+
+
+def test_engine_pallas_kernel_error_surfaces(monkeypatch):
+    """A kernel that raises (what a Mosaic refusal looks like) is the
+    run's error: nothing demotes the engine to the XLA tier."""
+    monkeypatch.setenv("EXAML_PALLAS", "1")
+    monkeypatch.setenv("EXAML_PALLAS_INTERPRET", "1")
+    inst = _instance("DNA", 8, 64, seed=6)
+    eng = inst.engines[4]
+    assert eng.use_pallas
+
+    def refuse(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(pallas_newview, "chunk_applier", refuse)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        inst.evaluate(inst.random_tree(6), full=True)
+    assert eng.use_pallas
 
 
 def test_whole_traversal_matches_fastpath():

@@ -1,0 +1,266 @@
+"""Ask the TPU's compiler, without a TPU, about the main path's programs.
+
+The compiler for a v5e is installed in the CPU sandbox and compiles for a
+chip that is DESCRIBED, not attached (`jax.experimental.topologies`).
+Nothing runs, so these say nothing about results or times — only that the
+program the chip will be handed compiles at the smoke's real shapes, fits
+the device's memory, carries the collectives it should, and that a kernel
+Mosaic refuses is known to be refused.
+
+The engine reads `jax.devices()` and would take its CPU branch, so each
+case builds a 140-taxon ONE-block f32 engine on CPU, takes the jitted
+body the engine would dispatch (steering the engine from here: the jit
+cache hands back the raw `jax.jit`, `use_pallas` is set by hand), and
+lowers it with `ShapeDtypeStruct`s on the described device with the block
+axis scaled up to the real width.
+
+This is the only file that describes a topology, and only inside
+fixtures: one process at a time may load the TPU's library, so the call
+must not happen at import, in conftest, or in an autouse fixture.
+"""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from examl_tpu.instance import PhyloInstance  # noqa: E402
+from examl_tpu.io.alignment import build_alignment_data  # noqa: E402
+from examl_tpu.ops import fastpath  # noqa: E402
+
+HBM_BYTES = 16 * 1000 ** 3        # one v5e chip: 16 GB (Cloud TPU docs)
+NTAXA = 140
+# What Mosaic says of the chunk kernel's [B, lane, R*K] row DMA today.
+MOSAIC_REFUSAL = "must be aligned to tiling (128), but is 16"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def chip_compile():
+    """The chip runs f32 with x64 off; and a compile for a described
+    device can be written to the persistent cache but not read back, so
+    the cache is off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    x64 = jax.config.jax_enable_x64
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_x64", False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+        jax.config.update("jax_enable_compilation_cache", cache)
+        cc.reset_cache()
+
+
+def _one_block_engine(datatype: str):
+    """140 taxa x 128 random sites (one 128-lane block) on CPU, f32,
+    with a full-traversal schedule of a random tree."""
+    rng = np.random.default_rng(7)
+    alphabet = {"AA": "ARNDCQEGHILKMFPSTWYV", "DNA": "ACGT"}[datatype]
+    names = [f"t{i}" for i in range(NTAXA)]
+    seqs = ["".join(alphabet[c] for c in rng.integers(0, len(alphabet), 128))
+            for _ in names]
+    inst = PhyloInstance(
+        build_alignment_data(names, seqs, datatype_name=datatype),
+        dtype=jnp.float32)
+    (eng,) = inst.engines.values()
+    assert eng.B == 1 and eng.dtype == jnp.float32
+    eng.cache_put = lambda key, fn: fn       # hand back the raw jax.jit
+    tree = inst.random_tree(3)
+    p = tree.centroid_branch()
+    flat = tree.flat_full_traversal(p)
+    st = eng._fast_structure(flat)
+    return inst, eng, tree, p, flat, st
+
+
+def _chunk_eval_call(eng, p, flat, st):
+    """(jitted chunk+evaluate program, its arguments) exactly as
+    `_run_fast_flat` dispatches them."""
+    zl, zr = fastpath.refresh_z(st, flat, eng.num_branch_slots, eng.dtype)
+    fn = eng._fast_fn_flat(st.profile, with_eval=True)
+    zv = jnp.asarray(np.asarray(p.z, dtype=np.float32))
+    args = (eng.clv, eng.scaler, st.base, st.lidx, st.ridx, st.lcode,
+            st.rcode, zl, zr,
+            jnp.int32(eng._gidx_of(st, p.number)),
+            jnp.int32(eng._gidx_of(st, p.back.number)), zv,
+            eng.models, eng.block_part, eng.weights, eng.tips)
+    return fn, args
+
+
+def _grad_call(eng, p, flat, st):
+    """(jitted gradient pass, its arguments) as `whole_tree_gradients`
+    dispatches them (ops/gradient.py)."""
+    from examl_tpu.ops import gradient
+    from examl_tpu.ops.kernels import OutrootTraversal
+    eng._install_row_map(st)
+    gs = eng._grad_structure(flat)
+    root_z = np.asarray(p.z, dtype=np.float64)
+    pre, ex_rows, ey_gidx, ez = gradient.grad_arrays(
+        gs, flat, eng.row_map, eng.num_branch_slots, root_z)
+    up_row, lrow, rrow, lg, rg, zu, zl, zr = pre
+    f32 = lambda a: jnp.asarray(a, dtype=jnp.float32)  # noqa: E731
+    tvp = OutrootTraversal(
+        up_row=jnp.asarray(up_row), lrow=jnp.asarray(lrow),
+        rrow=jnp.asarray(rrow), left=jnp.asarray(lg),
+        right=jnp.asarray(rg), zu=f32(zu), zl=f32(zl), zr=f32(zr))
+    pn, qn = gs.roots
+    args = (eng.clv, eng.scaler, jnp.int32(pn - 1), jnp.int32(qn - 1),
+            jnp.int32(eng._gidx(pn)), jnp.int32(eng._gidx(qn)), tvp,
+            jnp.asarray(ex_rows), jnp.asarray(ey_gidx), f32(ez),
+            eng.models, eng.block_part, eng.weights, eng.tips, None)
+    return jax.jit(eng._grad_impl), args
+
+
+def _as_shapes(eng, args, blocks: int, place):
+    """`args` as ShapeDtypeStructs with the block axis scaled from 1 to
+    `blocks` (axis 1 of clv / scaler / tips.codes, axis 0 of block_part
+    / weights); `place(kind)` gives each leaf's sharding, kind being the
+    SiteSharding attribute the engine would place it with."""
+    block_axis = {id(eng.clv): (1, "clv"), id(eng.scaler): (1, "scaler"),
+                  id(eng.tips.codes): (1, "scaler"),
+                  id(eng.block_part): (0, "blocks"),
+                  id(eng.weights): (0, "sites")}
+
+    def leaf(x):
+        x = x if hasattr(x, "shape") else np.asarray(x)
+        shape, kind = tuple(x.shape), "replicated"
+        if id(x) in block_axis:
+            ax, kind = block_axis[id(x)]
+            assert shape[ax] == 1
+            shape = shape[:ax] + (blocks,) + shape[ax + 1:]
+        dtype = jax.dtypes.canonicalize_dtype(x.dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=place(kind))
+
+    return jax.tree.map(leaf, args)
+
+
+def _fits(compiled) -> dict:
+    m = compiled.memory_analysis()
+    sizes = {"arguments": m.argument_size_in_bytes,
+             "temporaries": m.temp_size_in_bytes,
+             "outputs": m.output_size_in_bytes,
+             "aliased": m.alias_size_in_bytes}
+    print("memory_analysis:", sizes)          # shown with pytest -s
+    assert sizes["arguments"] + sizes["temporaries"] < HBM_BYTES, sizes
+    return sizes
+
+
+def test_chunk_evaluate_program_140x131072_dna(one_chip, chip_compile):
+    """The fullwidth phase's first program: full traversal (XLA chunk
+    tier) + root evaluation at 140 x 131,072 DNA patterns, f32."""
+    _, eng, _, p, flat, st = _one_block_engine("DNA")
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    compiled = fn.lower(*_as_shapes(eng, args, 1024,
+                                    lambda kind: one_chip)).compile()
+    sizes = _fits(compiled)
+    arena = eng.num_rows * 1024 * 128 * 16 * 4
+    assert sizes["arguments"] > arena            # the real-width arena
+    assert "tpu_custom_call" not in compiled.as_text()    # no kernel
+
+
+def test_gradient_pass_140x131072_dna(one_chip, chip_compile):
+    """The whole-tree gradient pass (ops/gradient.py) at the same
+    width: the outroot arena (2n-1 rows) lives inside the program."""
+    _, eng, _, p, flat, st = _one_block_engine("DNA")
+    fn, args = _grad_call(eng, p, flat, st)
+    eng.B = 1024                       # _grad_impl sizes its arena by it
+    compiled = fn.lower(*_as_shapes(eng, args, 1024,
+                                    lambda kind: one_chip)).compile()
+    sizes = _fits(compiled)
+    outroot = (2 * NTAXA - 1) * 1024 * 128 * 16 * 4
+    assert sizes["temporaries"] >= outroot
+
+
+def test_chunk_evaluate_program_140x16384_protein(one_chip, chip_compile):
+    """K = 20: the same chunk program on 140 x 16,384 protein patterns
+    (R*K = 80 minor dimension)."""
+    _, eng, _, p, flat, st = _one_block_engine("AA")
+    assert eng.K == 20
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    compiled = fn.lower(*_as_shapes(eng, args, 128,
+                                    lambda kind: one_chip)).compile()
+    _fits(compiled)
+
+
+def test_site_sharded_chunk_program_one_all_reduce(topo, chip_compile):
+    """The default path on a four-chip host: the chunk+evaluate program
+    with the block axis sharded over a 4-device mesh (140 x 16,384 DNA).
+    The root lnL segment-sum is the ONE cross-shard collective — ExaML's
+    single Allreduce."""
+    from examl_tpu.parallel.sharding import make_mesh, site_sharding
+    sh = site_sharding(make_mesh(devices=topo.devices[:4]))
+    _, eng, _, p, flat, st = _one_block_engine("DNA")
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    compiled = fn.lower(*_as_shapes(
+        eng, args, 128, lambda kind: getattr(sh, kind))).compile()
+    sizes = _fits(compiled)
+    # per-device bytes: a quarter of the 140 x 16,384 arena, not all
+    arena = eng.num_rows * 128 * 128 * 16 * 4
+    assert sizes["arguments"] < arena // 2
+    text = compiled.as_text()
+    n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
+    assert n_reduce == 1, n_reduce
+    for other in ("all-gather", "all-to-all", "collective-permute"):
+        assert f" {other}(" not in text and f" {other}-start(" not in text
+
+
+def test_newton_program_compiles(one_chip, chip_compile):
+    """`_newton_impl` (fused partial traversal + per-branch Newton: what
+    sharded arenas smooth with) at 140 x 16,384 DNA."""
+    inst, eng, tree, p, flat, st = _one_block_engine("DNA")
+    eng._install_row_map(st)
+    tv = eng._traversal_arrays(flat.to_entries()[-4:])
+    C = eng.num_branch_slots
+    args = (eng.clv, eng.scaler, (), tv,
+            jnp.int32(eng._gidx(p.number)),
+            jnp.int32(eng._gidx(p.back.number)),
+            jnp.asarray(np.asarray(p.z, dtype=np.float32)),
+            jnp.full(C, 16, dtype=jnp.int32), jnp.zeros(C, dtype=bool),
+            eng.models, eng.block_part, eng.weights, eng.tips, None)
+    compiled = jax.jit(eng._newton_impl).lower(
+        *_as_shapes(eng, args, 128, lambda kind: one_chip)).compile()
+    _fits(compiled)
+
+
+class _KnownRefusal(Exception):
+    """Mosaic refused the kernel with exactly today's message."""
+
+
+@pytest.mark.xfail(
+    strict=True, raises=_KnownRefusal,
+    reason="Mosaic failed to compile TPU kernel: Slice shape along "
+           "dimension 3 must be aligned to tiling (128), but is 16 — the "
+           "arena's minor dimension is R*K and the kernel DMAs whole "
+           "[B, lane, RK] rows; the PR that fixes the layout flips this")
+def test_pallas_chunk_kernel_compiles(one_chip, chip_compile):
+    """What EXAML_PALLAS=1 would hand the chip: the same chunk program
+    with `pallas_newview` kernels in it.  Strict xfail: passes the day
+    the kernel compiles, fails on any OTHER error than the known one."""
+    _, eng, _, p, flat, st = _one_block_engine("DNA")
+    eng.use_pallas = True              # what EXAML_PALLAS=1 sets on a TPU
+    assert not eng.pallas_interpret
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    try:
+        fn.lower(*_as_shapes(eng, args, 8, lambda kind: one_chip)).compile()
+    except Exception as e:
+        if MOSAIC_REFUSAL in str(e):
+            raise _KnownRefusal(str(e)[:400]) from e
+        raise

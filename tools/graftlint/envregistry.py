@@ -24,11 +24,13 @@ ENV_REGISTRY = {
         "note": "0 pins the scan tier for full traversals (ladder rung)."},
     "EXAML_PALLAS": {
         "doc": "readme",
-        "note": "0 disables Mosaic kernels; 'whole' selects the "
-                "whole-traversal Pallas tier."},
+        "note": "unset/0 = XLA chunk tier (default); 1 asks for the "
+                "Mosaic chunk kernels, 'whole' for the whole-traversal "
+                "Pallas tier (a compiler refusal is an error)."},
     "EXAML_PALLAS_INTERPRET": {
         "doc": "readme",
-        "note": "1 runs Pallas kernels in interpret mode (CPU-testable)."},
+        "note": "1 runs Pallas kernels in interpret mode (CPU tests "
+                "only; refused on a TPU placement)."},
     "EXAML_BATCH_SCAN": {
         "doc": "readme",
         "note": "0 disables the batched SPR scan tier."},
@@ -216,10 +218,6 @@ ENV_REGISTRY = {
         "note": "1 strips PYTHONPATH from bench worker children "
                 "(hermetic-subprocess debugging aid)."},
     # -- tools -------------------------------------------------------------
-    "EXAML_CHIP_PROBE_CMD": {
-        "doc": "registry",
-        "note": "test hook: overrides the chip-probe child command to "
-                "exercise no-answer/hang verdicts without hardware."},
     "EXAML_DEBUG_MODOPT": {
         "doc": "registry",
         "note": "1 prints per-round model-optimizer traces (dev aid; "

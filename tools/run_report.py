@@ -7,7 +7,7 @@ metrics snapshot, a run ledger and (for bench rounds) a BENCH json, and
 THIS tool turns them into the human report: per-tier achieved GB/s
 against the 306 GB/s roofline target with the dispatch-bound vs
 bandwidth-meaningful regime verdict, latency-histogram quantiles for
-the hot timers, and the merged event timeline.  `hw_round.sh` /
+the hot timers, and the merged event timeline.  The
 BENCH_r06 rows flow through here; a window that produced artifacts but
 no report is a window half wasted.
 
@@ -515,7 +515,6 @@ def render_counters(out, snap: dict) -> None:
         ("engine.traffic_bytes", "modeled HBM bytes"),
         ("engine.compile_count", "compiles"),
         ("engine.compile_seconds", "compile seconds"),
-        ("engine.pallas_fallbacks", "pallas->XLA fallbacks"),
         ("engine.watchdog_barks", "watchdog barks"),
         ("search.spr_cycles", "SPR cycles"),
         ("search.fast_cycles", "fast SPR cycles"),
@@ -537,11 +536,9 @@ def render_counters(out, snap: dict) -> None:
         # on the per-branch Newton path.
         lines.append(("dispatches / smoothing round",
                       g["engine.dispatches_per_smoothing_round"]))
-    probes = {k.rsplit(".", 1)[1]: v for k, v in c.items()
-              if k.startswith("chip.probe.")}
     faults = {k[len("faults.fired."):]: v for k, v in c.items()
               if k.startswith("faults.fired.")}
-    if not (lines or probes or faults):
+    if not (lines or faults):
         return
     out("")
     out("Run evidence (counters):")
@@ -550,9 +547,6 @@ def render_counters(out, snap: dict) -> None:
             out(f"  {label:28s} {v / 1e9:,.2f} GB")
         else:
             out(f"  {label:28s} {v:,.0f}")
-    if probes:
-        out("  chip probes              "
-            + "  ".join(f"{k}={int(v)}" for k, v in sorted(probes.items())))
     if faults:
         out("  faults fired             "
             + "  ".join(f"{k}={int(v)}" for k, v in sorted(faults.items())))
@@ -657,7 +651,7 @@ def render(metrics: dict, events: list, bench: dict,
 # Counters whose mere GROWTH between two comparable runs is a finding
 # (error/degradation evidence, not workload scale).
 _DIFF_ALARM_COUNTERS = (
-    "engine.watchdog_barks", "engine.pallas_fallbacks",
+    "engine.watchdog_barks",
     "bank.export.write_errors", "bank.export.corrupt",
     "bank.export.quarantined", "fleet.quarantined", "fleet.rejected",
     "engine.first_calls.unbanked",
