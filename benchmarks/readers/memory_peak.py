@@ -1,0 +1,7 @@
+"""`memory_stats()["peak_bytes_in_use"]` of the fullest chip after the
+window, in GB."""
+
+
+def read(run, spec):
+    peak = run.get("memory_peak_bytes")
+    return None if not peak else peak / 1e9
