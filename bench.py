@@ -831,6 +831,7 @@ def _merge_metrics(results: dict, snapshot: dict) -> None:
         else:
             cur["count"] += t.get("count", 0)
             cur["total_s"] += t.get("total_s", 0.0)
+            cur["self_s"] = cur.get("self_s", 0.0) + t.get("self_s", 0.0)
             pairs = [(cur.get("min_s"), t.get("min_s"), min),
                      (cur.get("max_s"), t.get("max_s"), max)]
             for key, (a, b, pick) in zip(("min_s", "max_s"), pairs):

@@ -368,6 +368,11 @@ def phase_device(ctx) -> None:
     from examl_tpu.config import enable_persistent_compilation_cache
     t0 = time.time()
     cache = enable_persistent_compilation_cache()
+    if ctx["rehearse"]:
+        # The toys compile in 0.2-0.5 s each, around the cache's
+        # threshold: keep every one, so a second rehearsal is warmer
+        # whatever the host's speed.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     emit(ctx, "device", count=ctx["count"], jax=jax.__version__,
          compile_cache=cache,
          jax_compilation_cache_dir_env=os.environ.get(

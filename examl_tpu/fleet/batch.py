@@ -352,8 +352,8 @@ class BatchEvaluator:
         pq = [(self._gidx_st(j.st, j.p.number),
                self._gidx_st(j.st, j.p.back.number)) for j in jobs]
         obs.inc("engine.dispatch_count")
-        with obs.device_span("fleet:batch_eval",
-                             args={"jobs": len(jobs), "jpad": jpad}):
+        with obs.span("fleet:batch_eval",
+                      args={"jobs": len(jobs), "jpad": jpad}):
             out = fn(clv, scaler,
                      self._pad_stack([j.st.base for j in jobs], jpad),
                      self._pad_stack([j.st.lidx for j in jobs], jpad),
@@ -390,8 +390,8 @@ class BatchEvaluator:
         pq = [(self._gidx_identity(j.p.number),
                self._gidx_identity(j.p.back.number)) for j in jobs]
         obs.inc("engine.dispatch_count")
-        with obs.device_span("fleet:batch_eval_scan",
-                             args={"jobs": len(jobs), "jpad": jpad}):
+        with obs.span("fleet:batch_eval_scan",
+                      args={"jobs": len(jobs), "jpad": jpad}):
             _, _, out = fn(clv, scaler, tv,
                            self._pad_stack([jnp.int32(p) for p, _ in pq],
                                            jpad),
@@ -489,9 +489,8 @@ class BatchEvaluator:
             pq = [(self._gidx_st(j.st, j.p.number),
                    self._gidx_st(j.st, j.p.back.number)) for j in jobs]
             obs.inc("engine.dispatch_count")
-            with obs.device_span("fleet:batch_universal",
-                                 args={"jobs": J, "jpad": jpad,
-                                       "steps": npad}):
+            with obs.span("fleet:batch_universal",
+                          args={"jobs": J, "jpad": jpad, "steps": npad}):
                 out = fn(clv, scaler,
                          self._pad_stack([d[0] for d in descs], jpad),
                          self._pad_stack([d[1] for d in descs], jpad),
@@ -622,8 +621,8 @@ class BatchEvaluator:
                 zr=stk([d[0][7] for d in dyn], eng.dtype))
             obs.inc("engine.dispatch_count")
             obs.inc("engine.grad_pass_dispatches")
-            with obs.device_span("fleet:grad_smooth",
-                                 args={"jobs": J, "jpad": jpad}):
+            with obs.span("fleet:grad_smooth",
+                          args={"jobs": J, "jpad": jpad}):
                 e1, e2 = fn(
                     clv, scaler,
                     self._pad_stack([j.st.base for j in jobs], jpad),
@@ -748,8 +747,8 @@ class BatchEvaluator:
             buf, _aux = eng._state()
             zv = jnp.asarray(z_slots(p.z, self.C), dtype=eng.dtype)
             obs.inc("engine.dispatch_count")
-            with obs.device_span("fleet:weights_eval",
-                                 args={"jobs": J, "jpad": jpad}):
+            with obs.span("fleet:weights_eval",
+                          args={"jobs": J, "jpad": jpad}):
                 out = fn(self._pad_stack(
                              [jnp.asarray(x, eng.dtype) for x in w], jpad),
                          buf, eng.scaler,

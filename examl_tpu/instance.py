@@ -316,7 +316,7 @@ class PhyloInstance:
             # invalidate_all + the two compute_traversal calls (every
             # inner node recomputed and re-oriented toward this edge).
             from examl_tpu import obs
-            with obs.timer("host_schedule"):
+            with obs.span("engine:tree/schedule", also="host_schedule"):
                 entries = tree.flat_full_traversal(p)
         else:
             entries = (self._collect(tree, p, full)

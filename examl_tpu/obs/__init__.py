@@ -7,10 +7,16 @@ instruments are gettime() deltas and ExaML_info prints):
   timers with log-bucketed latency histograms (`obs.hist`) — always on,
   dict-update cheap — plus a heartbeat-ticked periodic snapshot flush
   so a killed process leaves its last-known counters behind;
-* a **span tracer** (`obs.trace`): Chrome-trace/Perfetto-compatible
-  per-process JSONL files, off unless `--trace-events` /
-  `EXAML_TRACE_DIR` enables it, with `jax.profiler.TraceAnnotation`
-  scopes so host spans line up with device profiles;
+* ONE **span primitive** (`obs.trace.span`): every span always feeds
+  the registry timer of its name (count, total and SELF seconds: its
+  duration less its child spans'); with annotations on
+  (`set_annotations`: the benchmark's `--trace 1`, the CLI's
+  `--profile`) it is a `jax.profiler.TraceAnnotation` on the profiler's
+  clock; with the JSONL writer on (`--trace-events` /
+  `EXAML_TRACE_DIR`) a Chrome-trace/Perfetto B/E pair naming its parent
+  and the dispatch's sequence number.  The span tree of the timed path
+  (`opt:` > `engine:<family>` > schedule / stage / launch / wait,
+  `compile:<family>` under launch) is drawn in `obs/trace.py`;
 * a **run ledger** (`obs.ledger`): append-only per-rank JSONL event
   stream (compiles, phases, faults, checkpoint cycles, supervisor
   decisions, probe verdicts), merged by rank 0 into one ordered gang
@@ -26,7 +32,7 @@ This module is the flat facade the rest of the runtime imports:
 
     from examl_tpu import obs
     obs.inc("engine.dispatch_count")
-    with obs.device_span("engine:traverse", args={"entries": n}):
+    with obs.span("engine:set_models"):
         ...
 """
 
@@ -47,7 +53,7 @@ from examl_tpu.obs.metrics import (  # noqa: F401
     maybe_autoflush, set_autoflush)
 from examl_tpu.obs.timing import time_dispatch  # noqa: F401
 from examl_tpu.obs.trace import (  # noqa: F401
-    device_span, enable as enable_tracing, enabled as tracing_enabled,
+    enable as enable_tracing, enabled as tracing_enabled,
     finalize as finalize_tracing, instant, merge_summary, read_events,
     set_annotations, span)
 

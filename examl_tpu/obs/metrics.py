@@ -17,7 +17,24 @@ Naming convention (dotted, lowercase):
   host_schedule                timer: host-side schedule building
                                (flat traversal + structure/z assembly,
                                scan-tier packing) — the host floor,
-                               split from device dispatch
+                               split from device dispatch; fed by every
+                               `engine:<family>/schedule` span
+  engine.staged_arrays         host arrays/scalars handed to jnp as
+                               device arguments (stage phases, set_models)
+  engine.grad_pass             timer: whole-tree gradient dispatches
+                               (fed by the `engine:grad_pass` span)
+
+Spans (obs/trace.py) feed timers of their own, colon-named, with self
+seconds (`self_s`: duration less child spans'):
+
+  opt:model_opt_round > opt:brent | opt:tree_evaluate >
+    opt:smooth_sweep > opt:newton_update     optimiser control
+  engine:tree/schedule, engine:set_models    engine work between dispatches
+  engine:<family>                            one dispatch, tiled by
+    engine:<family>/schedule|stage|launch|wait   its four phases
+  compile:<family>                           under launch, first calls
+  search:*, phase:*, fleet:*                 off the benchmark's timed path
+
   engine.compile_count, engine.compile_seconds[.family]
   engine.compile_count.bank_phase      first calls inside the bank phase
   engine.first_calls.banked/unbanked[.family]   post-bank first calls
@@ -52,7 +69,7 @@ Naming convention (dotted, lowercase):
   phase.<name>                 CLI wall-clock phases (timers)
 
 Counters accept float increments (compile_seconds accumulates wall
-seconds); timers record count/total/min/max of observed durations PLUS
+seconds); timers record count/total/self/min/max of observed durations PLUS
 a log-bucketed latency histogram (obs/hist.py), so every snapshot
 carries p50/p95/p99 per timer — one slow outlier (a launch-floor
 stall, a recompile) is visible instead of vanishing into a `total_s`
@@ -74,24 +91,28 @@ from examl_tpu.obs import hist as _hist
 
 
 class TimerStat:
-    __slots__ = ("count", "total", "min", "max", "hist")
+    __slots__ = ("count", "total", "self_total", "min", "max", "hist")
 
     def __init__(self) -> None:
         self.count = 0
         self.total = 0.0
+        self.self_total = 0.0    # total less the time inside child spans
         self.min: Optional[float] = None
         self.max: Optional[float] = None
         self.hist = _hist.Histogram()
 
-    def observe(self, seconds: float) -> None:
+    def observe(self, seconds: float,
+                self_seconds: Optional[float] = None) -> None:
         self.count += 1
         self.total += seconds
+        self.self_total += seconds if self_seconds is None else self_seconds
         self.min = seconds if self.min is None else min(self.min, seconds)
         self.max = seconds if self.max is None else max(self.max, seconds)
         self.hist.observe(seconds)
 
     def as_dict(self) -> dict:
         d = {"count": self.count, "total_s": self.total,
+             "self_s": self.self_total,
              "min_s": self.min, "max_s": self.max}
         # Quantiles + the raw sparse buckets: the buckets are what lets
         # two snapshots MERGE exactly (bench worker accumulation,
@@ -145,12 +166,15 @@ class MetricsRegistry:
         with self._lock:
             self._gauges[name] = value
 
-    def observe(self, name: str, seconds: float) -> None:
+    def observe(self, name: str, seconds: float,
+                self_seconds: Optional[float] = None) -> None:
+        """`self_seconds` is a span's duration less its child spans'
+        (obs/trace.py); a plain timer's self time is its duration."""
         with self._lock:
             stat = self._timers.get(name)
             if stat is None:
                 stat = self._timers[name] = TimerStat()
-            stat.observe(seconds)
+            stat.observe(seconds, self_seconds)
 
     def timer(self, name: str) -> _TimerContext:
         return _TimerContext(self, name)

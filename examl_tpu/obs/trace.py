@@ -1,12 +1,47 @@
-"""Span tracer: Chrome-trace / Perfetto-compatible JSONL event files.
+"""One span primitive: registry timer, profiler annotation, JSONL event.
 
-Off by default and zero-cost when off: `span()` returns a shared no-op
-context unless tracing was enabled (by `enable(dir, procid)`, the CLI's
-`--trace-events`, or the `EXAML_TRACE_DIR` environment variable, checked
-lazily on the first span so subprocesses inherit tracing for free).
+`span(name)` is the only way the program brackets a stretch of host
+work.  One `with` feeds up to three sinks:
 
-Design constraints, all from the round-4 postmortem (a compile wedged in
-`recv` with no visibility into which program or what had completed):
+* ALWAYS the registry timer of the span's name (`obs/metrics.py`), with
+  self seconds: a per-thread stack makes every span add its duration to
+  its parent's child time on exit, so the timer records `self_s`
+  (duration minus children) beside `total_s` and `count`.  With the
+  other sinks off this is two clock reads, a list push/pop and one
+  timer update.
+* when ANNOTATIONS are on (`set_annotations(True)`: the benchmark's
+  `--trace 1`, the CLI's `--profile`, or `enable()`): a
+  `jax.profiler.TraceAnnotation` of the same name, so the span sits on
+  the profiler's clock beside the device's operations and an idle gap
+  of the device can be given to the span the host was in.  A span
+  opened with `annotate=False` skips this sink: the dispatch-level
+  `engine:<family>` spans do, because their four phases tile them and
+  the trace reduction gives a gap to the `engine:*` annotation that
+  covers most of it (a parent would shadow its phases).
+* when the JSONL WRITER is on (`enable(dir, procid)`, the CLI's
+  `--trace-events`, or `EXAML_TRACE_DIR`, checked lazily on the first
+  span so subprocesses inherit tracing for free): Chrome-trace /
+  Perfetto B/E events whose `args` carry the enclosing span's name
+  (`parent`) and the dispatch sequence number (`seq`:
+  `engine.dispatch_count` at entry), so the phases of one dispatch
+  share an identifier.
+
+The span tree on the timed path (names are the timers' names too):
+
+    opt:model_opt_round > opt:brent | opt:tree_evaluate
+      opt:tree_evaluate > opt:smooth_sweep > opt:newton_update
+        engine:tree/schedule          the tree's flat traversal
+        engine:set_models             model push (once a Brent probe)
+        engine:<family>               one dispatch (not annotated)
+          engine:<family>/schedule    also feeds the `host_schedule` timer
+          engine:<family>/stage       host values -> device arguments
+          engine:<family>/launch      the jitted call until it returns
+            compile:<family>          a first call's compile
+          engine:<family>/wait        the blocking read-back
+
+Design constraints of the JSONL file, all from the round-4 postmortem
+(a compile wedged in `recv` with no visibility into which program or
+what had completed):
 
 * spans are B/E *pairs*, flushed per event — a wedged compile leaves an
   unmatched "B" naming the guilty program family as the file's last
@@ -20,12 +55,9 @@ Design constraints, all from the round-4 postmortem (a compile wedged in
   both the finalized file and a crash-truncated one (the format is
   specified to tolerate a missing terminator).
 
-Timestamps are epoch microseconds (`time.time_ns() // 1000`) so traces
-from different processes of one job line up on a shared axis.
-
-`device_span()` additionally enters a `jax.profiler.TraceAnnotation`
-named scope (when annotations are on: tracing enabled or `--profile`
-active) so host spans line up with device activity in xprof profiles.
+JSONL timestamps are epoch microseconds (`time.time_ns() // 1000`) so
+traces from different processes of one job line up on a shared axis;
+durations in the registry are `time.perf_counter` differences.
 """
 
 from __future__ import annotations
@@ -37,18 +69,8 @@ import threading
 import time
 from typing import Optional
 
+from examl_tpu.obs import metrics as _metrics
 
-class _NullContext:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullContext()
 
 _lock = threading.Lock()
 _writer: Optional["TraceWriter"] = None
@@ -101,57 +123,87 @@ class TraceWriter:
             self._f.close()
 
 
-class _Span:
-    __slots__ = ("_name", "_cat", "_args", "_writer")
+_clock = time.perf_counter    # tests put a fake clock here
+_tls = threading.local()      # .stack: this thread's open spans
+_annotation_cls = None        # jax.profiler.TraceAnnotation, on first use
 
-    def __init__(self, writer: TraceWriter, name: str, cat: str, args):
-        self._writer = writer
-        self._name = name
+
+def _open_annotation(name: str):
+    """Enter a profiler annotation; None where JAX cannot give one."""
+    global _annotation_cls
+    try:
+        if _annotation_cls is None:
+            import jax
+            _annotation_cls = jax.profiler.TraceAnnotation
+        ann = _annotation_cls(name)
+        ann.__enter__()
+        return ann
+    except Exception:            # noqa: BLE001 - tracing never fails a run
+        return None
+
+
+class span:
+    """The span primitive (module docstring): `with span(name): ...`.
+    `also` names a second registry timer that takes the same duration
+    (the schedule phases feed `host_schedule`, the gradient dispatch
+    `engine.grad_pass`); `.elapsed` holds the duration after exit, for
+    callers that need the one measurement again."""
+
+    __slots__ = ("name", "elapsed", "_cat", "_args", "_annotate", "_also",
+                 "_t0", "_child_s", "_ann", "_w")
+
+    def __init__(self, name: str, args: Optional[dict] = None, *,
+                 cat: str = "host", annotate: bool = True,
+                 also: Optional[str] = None) -> None:
+        self.name = name
+        self.elapsed = 0.0
         self._cat = cat
         self._args = args
+        self._annotate = annotate
+        self._also = also
 
     def __enter__(self):
-        w = self._writer
-        ev = {"ph": "B", "name": self._name, "cat": self._cat,
-              "pid": w.procid, "tid": w.tid(), "ts": _now_us()}
-        if self._args:
-            ev["args"] = self._args
-        w.event(ev)
+        try:
+            stack = _tls.stack
+        except AttributeError:
+            stack = _tls.stack = []
+        self._ann = (_open_annotation(self.name)
+                     if _annotate and self._annotate else None)
+        if not _env_checked:
+            _maybe_env_enable()
+        w = self._w = _writer
+        if w is not None:
+            args = dict(self._args) if self._args else {}
+            if stack:
+                args["parent"] = stack[-1].name
+            args["seq"] = _metrics.registry().counter(
+                "engine.dispatch_count")
+            w.event({"ph": "B", "name": self.name, "cat": self._cat,
+                     "pid": w.procid, "tid": w.tid(), "ts": _now_us(),
+                     "args": args})
+        stack.append(self)
+        self._child_s = 0.0
+        self._t0 = _clock()
         return self
 
     def __exit__(self, *exc):
-        w = self._writer
-        w.event({"ph": "E", "name": self._name, "cat": self._cat,
-                 "pid": w.procid, "tid": w.tid(), "ts": _now_us()})
-        return False
-
-
-class _DeviceSpan(_Span):
-    """Host span + jax.profiler.TraceAnnotation named scope, so the host
-    trace and an xprof device profile share span names."""
-
-    __slots__ = ("_tm",)
-
-    def __enter__(self):
-        self._tm = None
-        if _annotate:
+        dt = self.elapsed = _clock() - self._t0
+        stack = _tls.stack
+        stack.pop()
+        if stack:
+            stack[-1]._child_s += dt
+        reg = _metrics.registry()
+        reg.observe(self.name, dt, dt - self._child_s)
+        if self._also is not None:
+            reg.observe(self._also, dt)
+        w = self._w
+        if w is not None:
+            w.event({"ph": "E", "name": self.name, "cat": self._cat,
+                     "pid": w.procid, "tid": w.tid(), "ts": _now_us()})
+        if self._ann is not None:
             try:
-                import jax
-                self._tm = jax.profiler.TraceAnnotation(self._name)
-                self._tm.__enter__()
-            except Exception:
-                self._tm = None
-        if self._writer is not None:
-            super().__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        if self._writer is not None:
-            super().__exit__(*exc)
-        if self._tm is not None:
-            try:
-                self._tm.__exit__(*exc)
-            except Exception:
+                self._ann.__exit__(*exc)
+            except Exception:    # noqa: BLE001 - tracing never fails a run
                 pass
         return False
 
@@ -219,21 +271,6 @@ def _maybe_env_enable() -> bool:
         except OSError:
             pass
     return _writer is not None
-
-
-def span(name: str, cat: str = "host", args: Optional[dict] = None):
-    """A host-side span context manager; no-op unless tracing is on."""
-    if _writer is None and not _maybe_env_enable():
-        return _NULL
-    return _Span(_writer, name, cat, args)
-
-
-def device_span(name: str, args: Optional[dict] = None):
-    """A span around a device dispatch: host trace event + TraceAnnotation
-    (annotations may be on without the JSONL writer, under --profile)."""
-    if _writer is None and not _maybe_env_enable() and not _annotate:
-        return _NULL
-    return _DeviceSpan(_writer, name, "dispatch", args)
 
 
 def instant(name: str, args: Optional[dict] = None) -> None:

@@ -82,6 +82,8 @@ def _bucket_len(n: int) -> int:
 
 class LikelihoodEngine:
     _obs_seq = 0                 # gauge-name ordinal (see _register_obs)
+    _family = "direct"           # family of the dispatch in hand (_phase);
+    # "direct" until the first: a harness calling a schedule helper itself
 
     def __init__(self, bucket: PackedBucket, models: Sequence[ModelParams],
                  ntips: int, num_branch_slots: int = 1,
@@ -782,8 +784,53 @@ class LikelihoodEngine:
                                             shard_zeros)
 
     def set_models(self, models: Sequence[ModelParams]) -> None:
-        self.models = stack_models(models, self._branch_indices, self.dtype,
-                                   psr=self.psr)
+        with obs.span("engine:set_models"):
+            self.models = stack_models(models, self._branch_indices,
+                                       self.dtype, psr=self.psr)
+            obs.inc("engine.staged_arrays", len(DeviceModels._fields))
+
+    # -- spans of the timed path (obs/trace.py) -----------------------------
+    # Every blocking dispatch is one `engine:<family>` span tiled by up
+    # to four phases: schedule (host structures), stage (host values to
+    # device arguments), launch (the jitted call until it returns; a
+    # first call's compile nests here) and wait (the blocking read-back).
+
+    def _dispatch(self, family: str, args: Optional[dict] = None,
+                  also: Optional[str] = None):
+        """The span of one dispatch.  Not a profiler annotation: its
+        phases are, and benchmarks/tracereduce.py gives an idle gap to
+        the `engine:*` annotation covering most of it, so an annotated
+        parent would shadow them."""
+        self._family = family
+        return obs.span("engine:" + family, args, cat="dispatch",
+                        annotate=False, also=also)
+
+    def _phase(self, phase: str):
+        """A phase of the dispatch in hand.  The schedule phases also
+        feed the `host_schedule` timer (the host floor, whatever the
+        family)."""
+        return obs.span(f"engine:{self._family}/{phase}", cat="dispatch",
+                        also="host_schedule" if phase == "schedule"
+                        else None)
+
+    def _stage_root(self, sched, p_num: int, q_num: int, z):
+        """(p_idx, q_idx, z): the device arguments of a root evaluation
+        at branch (p, q), against `sched`'s not yet installed layout or,
+        with None, the installed one."""
+        if sched is None:
+            p, q = self._gidx(p_num), self._gidx(q_num)
+        else:
+            p, q = self._gidx_of(sched, p_num), self._gidx_of(sched, q_num)
+        return (self._stage(p, jnp.int32), self._stage(q, jnp.int32),
+                self._stage(_z_slots(z, self.num_branch_slots), self.dtype))
+
+    def _stage(self, value, dtype=None) -> jax.Array:
+        """One host array or scalar handed to jnp as a device argument
+        (`jnp.int32(v)` is `jnp.asarray(v, dtype=jnp.int32)`); counted,
+        because each is a transfer and often a one-scalar convert
+        program of its own."""
+        obs.inc("engine.staged_arrays")
+        return jnp.asarray(value, dtype=dtype)
 
     def invalidate_tips_changed(self) -> None:
         self.tips = self._build_tip_state()
@@ -831,7 +878,7 @@ class LikelihoodEngine:
                          zr=jnp.asarray(zr, dtype=self.dtype))
 
     def _traversal_arrays(self, entries: List[TraversalEntry]) -> Traversal:
-        with obs.timer("host_schedule"):
+        with self._phase("schedule"):
             tv = self._pack_traversal(
                 entries, lambda e: self.row_map[e.parent], self._gidx)
         # Sequential dependent steps of the scan-tier program = the wave
@@ -875,9 +922,9 @@ class LikelihoodEngine:
         self._record_traffic(self._traversal_traffic_bytes(entries),
                              self._tier_for(entries, full))
         flat = entries if isinstance(entries, FlatTraversal) else None
-        with obs.device_span("engine:traverse",
-                             args={"entries": len(entries),
-                                   "full": bool(full)}):
+        # No wait phase: this path does not block on its result.
+        with self._dispatch("traverse", {"entries": len(entries),
+                                         "full": bool(full)}):
             if flat is not None:
                 if full and self._fast_eligible_flat(flat):
                     self._run_fast_flat(flat)
@@ -890,9 +937,10 @@ class LikelihoodEngine:
                 self._sev_begin(entries)
             tv = self._traversal_arrays(entries)
             buf, aux = self._state()
-            buf, self.scaler = self._jit_traverse(
-                buf, self.scaler, aux, tv, self.models, self.block_part,
-                self.tips, self.site_rates)
+            with self._phase("launch"):
+                buf, self.scaler = self._jit_traverse(
+                    buf, self.scaler, aux, tv, self.models,
+                    self.block_part, self.tips, self.site_rates)
             self._set_buf(buf)
 
     def _guard_first_call(self, fn, family: str = "program", key=None):
@@ -931,7 +979,6 @@ class LikelihoodEngine:
             state["first"] = False
             import os as _os
             import threading
-            import time as _time
 
             from examl_tpu.ops import bank
 
@@ -965,13 +1012,17 @@ class LikelihoodEngine:
                         "depending on which program is compiling.")
 
             threading.Thread(target=bark, daemon=True).start()
-            t0 = _time.perf_counter()
             # Ledger bracketing mirrors the trace span: a wedged compile
             # leaves the unmatched "start" as the rank's last ledger
             # event, naming the guilty family in the merged timeline.
             obs.ledger_event("compile", family=family, status="start")
+            # Histogram-carrying timer alongside the counter sum: one
+            # pathological compile must be visible as a p99, not
+            # averaged into compile_seconds.
+            compiling = obs.span(f"compile:{family}", cat="compile",
+                                 also=f"engine.compile_seconds.{family}")
             try:
-                with obs.span(f"compile:{family}", cat="compile"):
+                with compiling:
                     # Fault seam: `compile.hang` sleeps here (default
                     # 3600 s), making the first call indistinguishable
                     # from a wedged remote compile — the watchdog bark,
@@ -982,16 +1033,12 @@ class LikelihoodEngine:
                     return fn(*args)
             finally:
                 done.set()
-                dt = _time.perf_counter() - t0
+                dt = compiling.elapsed
                 obs.ledger_event("compile", family=family, status="end",
                                  seconds=round(dt, 3))
                 obs.inc("engine.compile_count")
                 obs.inc("engine.compile_seconds", dt)
                 obs.inc(f"engine.compile_seconds.{family}", dt)
-                # Histogram-carrying timer alongside the counter sum:
-                # one pathological compile must be visible as a p99,
-                # not averaged into compile_seconds.
-                obs.observe(f"engine.compile_seconds.{family}", dt)
                 if bank.in_bank_phase():
                     # Banked run, bank phase: the designed place for
                     # every first call (compile time lives here, off
@@ -1113,10 +1160,11 @@ class LikelihoodEngine:
                 obs.inc("engine.universal_ineligible")
         self._note_fast_program(sched.profile)
         fn = self._fast_fn_flat(sched.profile, with_eval=False)
-        self.clv, self.scaler = fn(
-            self.clv, self.scaler, sched.base, sched.lidx, sched.ridx,
-            sched.lcode, sched.rcode, sched.zl, sched.zr, self.models,
-            self.block_part, self.tips)
+        with self._phase("launch"):
+            self.clv, self.scaler = fn(
+                self.clv, self.scaler, sched.base, sched.lidx, sched.ridx,
+                sched.lcode, sched.rcode, sched.zl, sched.zr, self.models,
+                self.block_part, self.tips)
         self._install_row_map(sched)
 
     # -- engine state: dense CLV buffer or SEV pool -------------------------
@@ -1174,7 +1222,7 @@ class LikelihoodEngine:
 
     def _fast_schedule(self, entries: List[TraversalEntry]):
         from examl_tpu.ops import fastpath
-        with obs.timer("host_schedule"):
+        with self._phase("schedule"):
             sched = fastpath.build_schedule(entries, self.ntips,
                                             self.num_branch_slots,
                                             self.dtype)
@@ -1290,7 +1338,7 @@ class LikelihoodEngine:
         from examl_tpu.ops import fastpath, universal
         if self.pallas_whole and not self.universal_force:
             return self._run_whole(flat.to_entries(), p_num, q_num, z)
-        with obs.timer("host_schedule"):
+        with self._phase("schedule"):
             st = self._fast_structure(flat)
         self._last_universal = False
         if self._universal_take(st.profile, p_num is not None):
@@ -1298,28 +1346,30 @@ class LikelihoodEngine:
                 return self._run_universal_flat(flat, st, p_num, q_num, z)
             except universal.UniversalIneligible:
                 obs.inc("engine.universal_ineligible")
-        with obs.timer("host_schedule"):
+        with self._phase("schedule"):
             zl, zr = fastpath.refresh_z(st, flat, self.num_branch_slots,
                                         self.dtype)
         self._note_fast_program(st.profile)
         if p_num is None:
             fn = self._fast_fn_flat(st.profile, with_eval=False)
-            self.clv, self.scaler = fn(
-                self.clv, self.scaler, st.base, st.lidx, st.ridx,
-                st.lcode, st.rcode, zl, zr, self.models, self.block_part,
-                self.tips)
+            with self._phase("launch"):
+                self.clv, self.scaler = fn(
+                    self.clv, self.scaler, st.base, st.lidx, st.ridx,
+                    st.lcode, st.rcode, zl, zr, self.models,
+                    self.block_part, self.tips)
             self._install_row_map(st)
             return None
         fn = self._fast_fn_flat(st.profile, with_eval=True)
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots),
-                         dtype=self.dtype)
-        self.clv, self.scaler, out = fn(
-            self.clv, self.scaler, st.base, st.lidx, st.ridx, st.lcode,
-            st.rcode, zl, zr, jnp.int32(self._gidx_of(st, p_num)),
-            jnp.int32(self._gidx_of(st, q_num)), zv, self.models,
-            self.block_part, self.weights, self.tips)
+        with self._phase("stage"):
+            root = self._stage_root(st, p_num, q_num, z)
+        with self._phase("launch"):
+            self.clv, self.scaler, out = fn(
+                self.clv, self.scaler, st.base, st.lidx, st.ridx, st.lcode,
+                st.rcode, zl, zr, *root, self.models, self.block_part,
+                self.weights, self.tips)
         self._install_row_map(st)
-        return np.asarray(out)
+        with self._phase("wait"):
+            return np.asarray(out)
 
     # -- universal interpreter tier (ops/universal.py) ----------------------
     # Topology-as-data: the bounded layout's packed arrays ship as
@@ -1415,7 +1465,7 @@ class LikelihoodEngine:
         only the z arrays (padded to the slot bucket) are fresh."""
         from examl_tpu.ops import fastpath
         with_eval = p_num is not None
-        with obs.timer("host_schedule"):
+        with self._phase("schedule"):
             ent = self._universal_entry(
                 st.profile, np.asarray(st.base),
                 (st.lidx, st.ridx, st.lcode, st.rcode),
@@ -1434,7 +1484,7 @@ class LikelihoodEngine:
         from examl_tpu.ops import universal
         with_eval = p_num is not None
         base_h, li, ri, lc, rc, zl_h, zr_h = sched._host
-        with obs.timer("host_schedule"):
+        with self._phase("schedule"):
             ent = self._universal_entry(sched.profile, base_h,
                                         (li, ri, lc, rc))
             npad, ppad, desc, idx = self._universal_args(ent, with_eval)
@@ -1464,20 +1514,22 @@ class LikelihoodEngine:
         cls, slot, cbase = desc
         li, ri, lc, rc = idx
         if not with_eval:
-            self.clv, self.scaler = fn(
-                self.clv, self.scaler, cls, slot, cbase, li, ri, lc, rc,
-                zl, zr, self.models, self.block_part, self.tips)
+            with self._phase("launch"):
+                self.clv, self.scaler = fn(
+                    self.clv, self.scaler, cls, slot, cbase, li, ri, lc,
+                    rc, zl, zr, self.models, self.block_part, self.tips)
             self._install_row_map(sched)
             return None
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots),
-                         dtype=self.dtype)
-        self.clv, self.scaler, out = fn(
-            self.clv, self.scaler, cls, slot, cbase, li, ri, lc, rc, zl,
-            zr, jnp.int32(self._gidx_of(sched, p_num)),
-            jnp.int32(self._gidx_of(sched, q_num)), zv, self.models,
-            self.block_part, self.weights, self.tips)
+        with self._phase("stage"):
+            root = self._stage_root(sched, p_num, q_num, z)
+        with self._phase("launch"):
+            self.clv, self.scaler, out = fn(
+                self.clv, self.scaler, cls, slot, cbase, li, ri, lc, rc,
+                zl, zr, *root, self.models, self.block_part, self.weights,
+                self.tips)
         self._install_row_map(sched)
-        return np.asarray(out)
+        with self._phase("wait"):
+            return np.asarray(out)
 
     def _universal_fn(self, npad: int, ppad: int, with_eval: bool):
         """The ONE jitted interpreter program per (alphabet, buckets,
@@ -1609,13 +1661,15 @@ class LikelihoodEngine:
 
     def _whole_args(self, entries):
         from examl_tpu.ops import pallas_whole
-        sched = pallas_whole.build_flat(entries, self.ntips,
-                                        self.num_branch_slots)
-        return sched, (jnp.asarray(sched.meta),
-                       jnp.asarray(sched.l_code),
-                       jnp.asarray(sched.r_code),
-                       jnp.asarray(sched.zl, dtype=self.dtype),
-                       jnp.asarray(sched.zr, dtype=self.dtype))
+        with self._phase("schedule"):
+            sched = pallas_whole.build_flat(entries, self.ntips,
+                                            self.num_branch_slots)
+        with self._phase("stage"):
+            return sched, (self._stage(sched.meta),
+                           self._stage(sched.l_code),
+                           self._stage(sched.r_code),
+                           self._stage(sched.zl, self.dtype),
+                           self._stage(sched.zr, self.dtype))
 
     def _run_whole(self, entries, p_num=None, q_num=None, z=None):
         # One fused Mosaic program = one sequential device op: the
@@ -1627,21 +1681,22 @@ class LikelihoodEngine:
         sched, args = self._whole_args(entries)
         if p_num is None:
             fn = self._whole_fn(sched.e_real, with_eval=False)
-            self.clv, self.scaler = fn(self.clv, self.scaler, *args,
-                                       self.models, self.block_part,
-                                       self.tips)
+            with self._phase("launch"):
+                self.clv, self.scaler = fn(self.clv, self.scaler, *args,
+                                           self.models, self.block_part,
+                                           self.tips)
             self._install_row_map(sched)
             return None
         fn = self._whole_fn(sched.e_real, with_eval=True)
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots),
-                         dtype=self.dtype)
-        self.clv, self.scaler, out = fn(
-            self.clv, self.scaler, *args,
-            jnp.int32(self._gidx_of(sched, p_num)),
-            jnp.int32(self._gidx_of(sched, q_num)), zv, self.models,
-            self.block_part, self.weights, self.tips)
+        with self._phase("stage"):
+            root = self._stage_root(sched, p_num, q_num, z)
+        with self._phase("launch"):
+            self.clv, self.scaler, out = fn(
+                self.clv, self.scaler, *args, *root, self.models,
+                self.block_part, self.weights, self.tips)
         self._install_row_map(sched)
-        return np.asarray(out)
+        with self._phase("wait"):
+            return np.asarray(out)
 
     def run_whole_traced(self, clv, scaler, sched):
         """Traceable whole-traversal execution for external harnesses
@@ -1730,8 +1785,7 @@ class LikelihoodEngine:
                 return self.ntips + base + (ident - SLOT0)
             return self._gidx(ident)
 
-        with obs.timer("host_schedule"):
-            return self._pack_traversal(pseudo, parent_row, gidx)
+        return self._pack_traversal(pseudo, parent_row, gidx)
 
     def _scan_dispatch_arrays(self, plan, base: int, T: int):
         """Shared padding/chunk plumbing for the scan programs: gather
@@ -1762,30 +1816,34 @@ class LikelihoodEngine:
         if self.save_memory:
             self.sev.update_for_entries(plan.down_entries)
         base = self.ensure_scan_rows(len(plan.up_entries))
-        tv = self._scan_traversal_arrays(plan.down_entries,
-                                         plan.up_entries, base)
         T = batchscan.CAND_CHUNK
-        n_chunks, npad, qg, upg = self._scan_dispatch_arrays(plan, base, T)
         C = self.num_branch_slots
-        zc = np.ones((npad, C), dtype=np.float64)
-        for i, c in enumerate(plan.candidates):
-            zc[i] = _z_slots(c.z, C)
-        fn = batchscan.scan_program(self, n_chunks)
-        zp = jnp.asarray(_z_slots(plan.zp, C), dtype=self.dtype)
-        buf, aux = self._state()
-        with obs.device_span("engine:spr_scan",
-                             args={"candidates": len(plan.candidates),
-                                   "chunks": n_chunks}):
-            buf, self.scaler, lnls = fn(
-                buf, self.scaler, aux, tv,
-                jnp.asarray(qg.reshape(n_chunks, T)),
-                jnp.asarray(upg.reshape(n_chunks, T)),
-                jnp.asarray(zc.reshape(n_chunks, T, C), dtype=self.dtype),
-                jnp.int32(self._gidx(plan.s_num)), zp,
-                self.models, self.block_part, self.weights, self.tips,
-                self.site_rates)
-        self._set_buf(buf)
-        return np.asarray(lnls)[:len(plan.candidates)]
+        with self._dispatch("spr_scan",
+                            {"candidates": len(plan.candidates)}):
+            with self._phase("schedule"):
+                tv = self._scan_traversal_arrays(plan.down_entries,
+                                                 plan.up_entries, base)
+                n_chunks, npad, qg, upg = self._scan_dispatch_arrays(
+                    plan, base, T)
+                zc = np.ones((npad, C), dtype=np.float64)
+                for i, c in enumerate(plan.candidates):
+                    zc[i] = _z_slots(c.z, C)
+            fn = batchscan.scan_program(self, n_chunks)
+            buf, aux = self._state()
+            with self._phase("stage"):
+                cand = (self._stage(qg.reshape(n_chunks, T)),
+                        self._stage(upg.reshape(n_chunks, T)),
+                        self._stage(zc.reshape(n_chunks, T, C), self.dtype),
+                        self._stage(self._gidx(plan.s_num), jnp.int32),
+                        self._stage(_z_slots(plan.zp, C), self.dtype))
+            with self._phase("launch"):
+                buf, self.scaler, lnls = fn(
+                    buf, self.scaler, aux, tv, *cand, self.models,
+                    self.block_part, self.weights, self.tips,
+                    self.site_rates)
+            self._set_buf(buf)
+            with self._phase("wait"):
+                return np.asarray(lnls)[:len(plan.candidates)]
 
     def batched_thorough(self, plan):
         """Thorough-arm companion of `batched_scan`: triangle Newton,
@@ -1802,28 +1860,33 @@ class LikelihoodEngine:
         if self.save_memory:
             self.sev.update_for_entries(plan.down_entries)
         base = self.ensure_scan_rows(len(plan.up_entries))
-        tv = self._scan_traversal_arrays(plan.down_entries,
-                                         plan.up_entries, base)
         T = batchscan.TH_CHUNK
-        n_chunks, npad, qg, upg = self._scan_dispatch_arrays(plan, base, T)
-        zq0 = np.full(npad, float(np.asarray(plan.zp, np.float64)[0]))
-        for i, c in enumerate(plan.candidates):
-            zq0[i] = float(np.asarray(c.q_slot.z, np.float64)[0])
-        fn = batchscan.thorough_program(self, n_chunks)
-        buf, aux = self._state()
-        with obs.device_span("engine:spr_thorough",
-                             args={"candidates": len(plan.candidates),
-                                   "chunks": n_chunks}):
-            buf, self.scaler, lnls, es = fn(
-                buf, self.scaler, aux, tv,
-                jnp.asarray(qg.reshape(n_chunks, T)),
-                jnp.asarray(upg.reshape(n_chunks, T)),
-                jnp.asarray(zq0.reshape(n_chunks, T), dtype=self.dtype),
-                jnp.int32(self._gidx(plan.s_num)), self.models,
-                self.block_part, self.weights, self.tips, self.site_rates)
-        self._set_buf(buf)
         N = len(plan.candidates)
-        return np.asarray(lnls)[:N], np.asarray(es)[:N]
+        with self._dispatch("spr_thorough", {"candidates": N}):
+            with self._phase("schedule"):
+                tv = self._scan_traversal_arrays(plan.down_entries,
+                                                 plan.up_entries, base)
+                n_chunks, npad, qg, upg = self._scan_dispatch_arrays(
+                    plan, base, T)
+                zq0 = np.full(npad,
+                              float(np.asarray(plan.zp, np.float64)[0]))
+                for i, c in enumerate(plan.candidates):
+                    zq0[i] = float(np.asarray(c.q_slot.z, np.float64)[0])
+            fn = batchscan.thorough_program(self, n_chunks)
+            buf, aux = self._state()
+            with self._phase("stage"):
+                cand = (self._stage(qg.reshape(n_chunks, T)),
+                        self._stage(upg.reshape(n_chunks, T)),
+                        self._stage(zq0.reshape(n_chunks, T), self.dtype),
+                        self._stage(self._gidx(plan.s_num), jnp.int32))
+            with self._phase("launch"):
+                buf, self.scaler, lnls, es = fn(
+                    buf, self.scaler, aux, tv, *cand, self.models,
+                    self.block_part, self.weights, self.tips,
+                    self.site_rates)
+            self._set_buf(buf)
+            with self._phase("wait"):
+                return np.asarray(lnls)[:N], np.asarray(es)[:N]
 
     # -- evaluation --------------------------------------------------------
 
@@ -1838,16 +1901,17 @@ class LikelihoodEngine:
     def evaluate(self, p_num: int, q_num: int, z: Sequence[float]) -> np.ndarray:
         """Per-partition lnL [M] at branch (p,q); CLVs must be current."""
         obs.inc("engine.dispatch_count")
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots), dtype=self.dtype)
-        buf, aux = self._state()
-        with obs.device_span("engine:evaluate"):
-            out = self._jit_evaluate(buf, self.scaler, aux,
-                                     jnp.int32(self._gidx(p_num)),
-                                     jnp.int32(self._gidx(q_num)),
-                                     zv, self.models, self.block_part,
-                                     self.weights, self.tips,
-                                     self.site_rates)
-        return np.asarray(out)
+        with self._dispatch("evaluate"):
+            with self._phase("stage"):
+                root = self._stage_root(None, p_num, q_num, z)
+            buf, aux = self._state()
+            with self._phase("launch"):
+                out = self._jit_evaluate(buf, self.scaler, aux, *root,
+                                         self.models, self.block_part,
+                                         self.weights, self.tips,
+                                         self.site_rates)
+            with self._phase("wait"):
+                return np.asarray(out)
 
     # -- fused single-dispatch entry points ---------------------------------
     # Traversal + root evaluation (resp. + sumtable + the whole NR loop) in
@@ -1870,10 +1934,8 @@ class LikelihoodEngine:
         obs.inc("engine.traversal_entries", len(entries))
         nbytes = self._traversal_traffic_bytes(entries)
         compiles0 = obs.registry().counter("engine.compile_count")
-        t0 = time.perf_counter()
-        with obs.device_span("engine:trav_eval",
-                             args={"entries": len(entries),
-                                   "full": bool(full)}):
+        with self._dispatch("trav_eval", {"entries": len(entries),
+                                          "full": bool(full)}) as disp:
             out = self._traverse_evaluate(entries, p_num, q_num, z, full)
         # This path BLOCKS (np.asarray on the lnL), so the elapsed wall
         # covers the whole traversal: full traversals feed the windowed
@@ -1884,8 +1946,7 @@ class LikelihoodEngine:
         # observation but is excluded from the bandwidth window.
         self._record_traffic(
             nbytes, self._tier_for(entries, full),
-            wall_s=(time.perf_counter() - t0) if full and len(entries)
-            else None,
+            wall_s=disp.elapsed if full and len(entries) else None,
             window=(obs.registry().counter("engine.compile_count")
                     == compiles0))
         return out
@@ -1903,14 +1964,16 @@ class LikelihoodEngine:
         if self.save_memory:
             self._sev_begin(entries)
         tv = self._traversal_arrays(entries)
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots), dtype=self.dtype)
+        with self._phase("stage"):
+            root = self._stage_root(None, p_num, q_num, z)
         buf, aux = self._state()
-        buf, self.scaler, out = self._jit_trav_eval(
-            buf, self.scaler, aux, tv, jnp.int32(self._gidx(p_num)),
-            jnp.int32(self._gidx(q_num)), zv, self.models, self.block_part,
-            self.weights, self.tips, self.site_rates)
+        with self._phase("launch"):
+            buf, self.scaler, out = self._jit_trav_eval(
+                buf, self.scaler, aux, tv, *root, self.models,
+                self.block_part, self.weights, self.tips, self.site_rates)
         self._set_buf(buf)
-        return np.asarray(out)
+        with self._phase("wait"):
+            return np.asarray(out)
 
     def _trav_eval_fast(self, entries, p_num, q_num, z) -> np.ndarray:
         from examl_tpu.ops import universal
@@ -1925,16 +1988,16 @@ class LikelihoodEngine:
                 obs.inc("engine.universal_ineligible")
         self._note_fast_program(sched.profile)
         fn = self._fast_fn_flat(sched.profile, with_eval=True)
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots),
-                         dtype=self.dtype)
-        self.clv, self.scaler, out = fn(
-            self.clv, self.scaler, sched.base, sched.lidx, sched.ridx,
-            sched.lcode, sched.rcode, sched.zl, sched.zr,
-            jnp.int32(self._gidx_of(sched, p_num)),
-            jnp.int32(self._gidx_of(sched, q_num)), zv, self.models,
-            self.block_part, self.weights, self.tips)
+        with self._phase("stage"):
+            root = self._stage_root(sched, p_num, q_num, z)
+        with self._phase("launch"):
+            self.clv, self.scaler, out = fn(
+                self.clv, self.scaler, sched.base, sched.lidx, sched.ridx,
+                sched.lcode, sched.rcode, sched.zl, sched.zr, *root,
+                self.models, self.block_part, self.weights, self.tips)
         self._install_row_map(sched)
-        return np.asarray(out)
+        with self._phase("wait"):
+            return np.asarray(out)
 
     def _gidx_of(self, sched, num: int) -> int:
         """gather_child index of a node against a schedule's NEW layout
@@ -1970,22 +2033,27 @@ class LikelihoodEngine:
                              "scan")
         if self.save_memory:
             self._sev_begin(entries)
-        tv = self._traversal_arrays(entries)
         C = self.num_branch_slots
         if conv_mask is None:
             conv_mask = np.zeros(C, dtype=bool)
-        buf, aux = self._state()
-        with obs.device_span("engine:newton",
-                             args={"entries": len(entries),
-                                   "maxiter": int(maxiter)}):
-            buf, self.scaler, z = self._jit_newton(
-                buf, self.scaler, aux, tv, jnp.int32(self._gidx(p_num)),
-                jnp.int32(self._gidx(q_num)), jnp.asarray(z0),
-                jnp.full(C, maxiter, dtype=jnp.int32),
-                jnp.asarray(conv_mask), self.models, self.block_part,
-                self.weights, self.tips, self.site_rates)
-        self._set_buf(buf)
-        return np.asarray(z, dtype=np.float64)
+        with self._dispatch("newton", {"entries": len(entries),
+                                       "maxiter": int(maxiter)}):
+            tv = self._traversal_arrays(entries)
+            buf, aux = self._state()
+            with self._phase("stage"):
+                branch = (self._stage(self._gidx(p_num), jnp.int32),
+                          self._stage(self._gidx(q_num), jnp.int32),
+                          self._stage(z0),
+                          jnp.full(C, maxiter, dtype=jnp.int32),
+                          self._stage(conv_mask))
+            with self._phase("launch"):
+                buf, self.scaler, z = self._jit_newton(
+                    buf, self.scaler, aux, tv, *branch, self.models,
+                    self.block_part, self.weights, self.tips,
+                    self.site_rates)
+            self._set_buf(buf)
+            with self._phase("wait"):
+                return np.asarray(z, dtype=np.float64)
 
     # -- PSR rate-grid scan -------------------------------------------------
 
@@ -2017,32 +2085,36 @@ class LikelihoodEngine:
         assert self.psr
         obs.inc("engine.dispatch_count")
         obs.inc("engine.traversal_entries", len(entries))
-        tv = self._traversal_arrays(entries)
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots), dtype=self.dtype)
-        # `grid` is GLOBAL [B, lane, G] (every process builds the same
-        # one from the host-global patrat); a selective-loading process
-        # contributes only its block window to the sharded device array.
-        grid_dev = self._put_blocks(
-            self._local_block_window(np.asarray(grid, dtype=self.dtype)),
-            lambda s: s.sites)
-        with obs.device_span("engine:rate_scan",
-                             args={"grid": int(grid.shape[-1])}):
-            out = self._jit_rate_scan(
-                self.tips, tv, jnp.int32(self._gidx(p_num)),
-                jnp.int32(self._gidx(q_num)), zv, grid_dev, self.models,
-                self.block_part)
-        if self.sharding is not None and jax.process_count() > 1:
-            # Multi-host: the per-site scan result is block-sharded
-            # across processes; the host-side PSR crawl/categorization
-            # needs the global view on EVERY process (deterministic, so
-            # all processes categorize identically — the reference
-            # gathers to rank 0 and scatters back instead,
-            # `optimizeModel.c:2135-2254`; an allgather of the same
-            # payload replaces both legs).
-            from jax.experimental import multihost_utils
-            return np.asarray(
-                multihost_utils.process_allgather(out, tiled=True))
-        return np.asarray(out)
+        with self._dispatch("rate_scan", {"grid": int(grid.shape[-1])}):
+            tv = self._traversal_arrays(entries)
+            with self._phase("stage"):
+                root = self._stage_root(None, p_num, q_num, z)
+                # `grid` is GLOBAL [B, lane, G] (every process builds the
+                # same one from the host-global patrat); a selective-
+                # loading process contributes only its block window to
+                # the sharded device array.
+                obs.inc("engine.staged_arrays")
+                grid_dev = self._put_blocks(
+                    self._local_block_window(
+                        np.asarray(grid, dtype=self.dtype)),
+                    lambda s: s.sites)
+            with self._phase("launch"):
+                out = self._jit_rate_scan(self.tips, tv, *root, grid_dev,
+                                          self.models, self.block_part)
+            with self._phase("wait"):
+                if self.sharding is not None and jax.process_count() > 1:
+                    # Multi-host: the per-site scan result is block-
+                    # sharded across processes; the host-side PSR
+                    # crawl/categorization needs the global view on EVERY
+                    # process (deterministic, so all processes categorize
+                    # identically — the reference gathers to rank 0 and
+                    # scatters back instead, `optimizeModel.c:2135-2254`;
+                    # an allgather of the same payload replaces both
+                    # legs).
+                    from jax.experimental import multihost_utils
+                    return np.asarray(
+                        multihost_utils.process_allgather(out, tiled=True))
+                return np.asarray(out)
 
     # -- branch derivatives ------------------------------------------------
 
@@ -2060,20 +2132,28 @@ class LikelihoodEngine:
     def make_sumtable(self, p_num: int, q_num: int) -> jax.Array:
         obs.inc("engine.dispatch_count")
         buf, aux = self._state()
-        with obs.device_span("engine:sumtable"):
-            return self._jit_sumtable(buf, self.scaler, aux,
-                                      jnp.int32(self._gidx(p_num)),
-                                      jnp.int32(self._gidx(q_num)),
-                                      self.models, self.block_part,
-                                      self.tips)
+        # No wait phase: the table stays on the device.
+        with self._dispatch("sumtable"):
+            with self._phase("stage"):
+                p_idx = self._stage(self._gidx(p_num), jnp.int32)
+                q_idx = self._stage(self._gidx(q_num), jnp.int32)
+            with self._phase("launch"):
+                return self._jit_sumtable(buf, self.scaler, aux, p_idx,
+                                          q_idx, self.models,
+                                          self.block_part, self.tips)
 
     def branch_derivatives(self, st: jax.Array, z: Sequence[float]):
         obs.inc("engine.dispatch_count")
-        zv = jnp.asarray(_z_slots(z, self.num_branch_slots), dtype=self.dtype)
-        with obs.device_span("engine:derivs"):
-            d1, d2 = self._jit_derivs(st, zv, self.models, self.block_part,
-                                      self.weights, self.site_rates)
-        return np.asarray(d1), np.asarray(d2)
+        with self._dispatch("derivs"):
+            with self._phase("stage"):
+                zv = self._stage(_z_slots(z, self.num_branch_slots),
+                                 self.dtype)
+            with self._phase("launch"):
+                d1, d2 = self._jit_derivs(st, zv, self.models,
+                                          self.block_part, self.weights,
+                                          self.site_rates)
+            with self._phase("wait"):
+                return np.asarray(d1), np.asarray(d2)
 
     # -- whole-tree analytic gradients (ops/gradient.py) --------------------
     # One pre-order (outroot) pass over the reversed wave schedule plus
@@ -2140,56 +2220,59 @@ class LikelihoodEngine:
             raise RuntimeError("whole-tree gradients need the dense CLV "
                                "arena (-S SEV pools keep the per-branch "
                                "Newton path)")
-        gs = self._grad_structure(flat)
-        with obs.timer("host_schedule"):
-            pre, ex_rows, ey_gidx, ez = gradient.grad_arrays(
-                gs, flat, self.row_map, self.num_branch_slots, root_z)
-        key = ("grad", _bucket_len(gs.n_steps), _next_pow2(gs.wave_w),
-               _next_pow2(gs.n_chunks))
-        fn = self.cache_get(key)
-        if fn is None:
-            fn = self.cache_put(key, jax.jit(self._grad_impl))
         obs.inc("engine.dispatch_count")
         obs.inc("engine.grad_pass_dispatches")
+        compiles0 = obs.registry().counter("engine.compile_count")
+        # The dispatch's wall also feeds the `engine.grad_pass` timer,
+        # whose count is the gradient passes made.
+        with self._dispatch("grad_pass", also="engine.grad_pass") as disp:
+            with self._phase("schedule"):
+                gs = self._grad_structure(flat)
+                pre, ex_rows, ey_gidx, ez = gradient.grad_arrays(
+                    gs, flat, self.row_map, self.num_branch_slots, root_z)
+            key = ("grad", _bucket_len(gs.n_steps), _next_pow2(gs.wave_w),
+                   _next_pow2(gs.n_chunks))
+            fn = self.cache_get(key)
+            if fn is None:
+                fn = self.cache_put(key, jax.jit(self._grad_impl))
+            p, q = gs.roots
+            up_row, lrow, rrow, lg, rg, zu, zl, zr = pre
+            with self._phase("stage"):
+                edges = (
+                    self._stage(p - 1, jnp.int32),
+                    self._stage(q - 1, jnp.int32),
+                    self._stage(self._gidx(p), jnp.int32),
+                    self._stage(self._gidx(q), jnp.int32),
+                    OutrootTraversal(
+                        up_row=self._stage(up_row), lrow=self._stage(lrow),
+                        rrow=self._stage(rrow), left=self._stage(lg),
+                        right=self._stage(rg),
+                        zu=self._stage(zu, self.dtype),
+                        zl=self._stage(zl, self.dtype),
+                        zr=self._stage(zr, self.dtype)),
+                    self._stage(ex_rows), self._stage(ey_gidx),
+                    self._stage(ez, self.dtype))
+            with self._phase("launch"):
+                d1, d2 = fn(self.clv, self.scaler, *edges, self.models,
+                            self.block_part, self.weights, self.tips,
+                            self.site_rates)
+            # Blocking by contract: the host-side batched Newton update
+            # consumes d1/d2 — this sync IS the gradient measurement
+            # (the registered seam, like the trav-eval family).
+            with self._phase("wait"):
+                d1 = np.asarray(d1, dtype=np.float64)
+                d2 = np.asarray(d2, dtype=np.float64)
         itemsize = np.dtype(self.storage_dtype).itemsize
         tip_children = int((np.asarray(flat.left) <= self.ntips).sum()
                            + (np.asarray(flat.right) <= self.ntips).sum())
         nbytes = _traffic.bytes_per_grad_pass(
             gs.n, tip_children, gs.n_edges, self._patterns_true, self.R,
             self.K, itemsize)
-        compiles0 = obs.registry().counter("engine.compile_count")
-        p, q = gs.roots
-        up_row, lrow, rrow, lg, rg, zu, zl, zr = pre
-        tvp = OutrootTraversal(
-            up_row=jnp.asarray(up_row), lrow=jnp.asarray(lrow),
-            rrow=jnp.asarray(rrow), left=jnp.asarray(lg),
-            right=jnp.asarray(rg),
-            zu=jnp.asarray(zu, dtype=self.dtype),
-            zl=jnp.asarray(zl, dtype=self.dtype),
-            zr=jnp.asarray(zr, dtype=self.dtype))
-        t0 = time.perf_counter()
-        with obs.device_span("engine:grad_pass",
-                             args={"edges": gs.n_edges,
-                                   "steps": gs.n_steps}):
-            d1, d2 = fn(self.clv, self.scaler,
-                        jnp.int32(p - 1), jnp.int32(q - 1),
-                        jnp.int32(self._gidx(p)), jnp.int32(self._gidx(q)),
-                        tvp, jnp.asarray(ex_rows), jnp.asarray(ey_gidx),
-                        jnp.asarray(ez, dtype=self.dtype), self.models,
-                        self.block_part, self.weights, self.tips,
-                        self.site_rates)
-            # Blocking by contract: the host-side batched Newton update
-            # consumes d1/d2 — this sync IS the gradient measurement
-            # (the registered seam, like the trav-eval family).
-            d1 = np.asarray(d1, dtype=np.float64)
-            d2 = np.asarray(d2, dtype=np.float64)
-        dt = time.perf_counter() - t0
-        obs.observe("engine.grad_pass", dt)
         # The gradient program is one device op whose scan walks
         # n_steps + n_chunks dependent steps — the launch-floor term.
         self._last_dispatch_ops = gs.n_steps + gs.n_chunks
         self._record_traffic(
-            nbytes, "grad", wall_s=dt,
+            nbytes, "grad", wall_s=disp.elapsed,
             window=(obs.registry().counter("engine.compile_count")
                     == compiles0))
         return d1[:gs.n_edges], d2[:gs.n_edges]

@@ -743,6 +743,7 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
                                    (((3,), (2,)), ((0, 1), (0, 1))),
                                    precision=precision)
 
+    @jax.named_scope("examl/newview")
     def values(clv, scaler, ch: FastChunk):
         """The chunk's COMPUTED rows, no write: (v [W, B, lane, R, K]
         in the compute dtype, sc [W, B, lane]).  Split out of `apply`
@@ -774,6 +775,7 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
         sc = sc + needs.astype(jnp.int32)
         return v.reshape(W, B, lane, R_, K), sc
 
+    @jax.named_scope("examl/newview")
     def apply(clv, scaler, ch: FastChunk):
         v, sc = values(clv, scaler, ch)
         z0 = jnp.zeros((), ch.base.dtype if hasattr(ch.base, "dtype")
@@ -806,6 +808,7 @@ def run_chunks(models: kernels.DeviceModels, block_part: jax.Array,
     return clv, scaler
 
 
+@jax.named_scope("examl/newview")
 def run_segments(profile, base, lidx, ridx, lcode, rcode, zl, zr,
                  clv, scaler, apply) -> Tuple[jax.Array, jax.Array]:
     """Execute the bounded program over the PACKED 7-leaf layout:

@@ -222,6 +222,7 @@ def grad_arrays(gs: GradStructure, flat, row_map: np.ndarray,
             ez.reshape(gs.n_chunks, T, num_slots))
 
 
+@jax.named_scope("examl/edge_grad")
 def edge_gradients(models, block_part, weights, tips, clv, scaler, out,
                    ex_rows, ey_gidx, ez, num_slots: int, ntips: int,
                    site_rates=None):

@@ -12,6 +12,12 @@ Index conventions (einsum letters):
   b block, l lane, r rate category, j eigen index, a/k state, m partition,
   n CLV row, e traversal entry, c branch slot (per-partition branch lengths).
 
+Every kernel traces under a `jax.named_scope` that survives XLA's
+renumbering: `examl/newview`, `examl/evaluate`, `examl/sumtable`,
+`examl/derivs`, `examl/outroot` here, `examl/edge_grad` in
+ops/gradient.py.  Metadata only (an operation's `op_name`); where scopes
+nest, the outermost names the kernel an operation belongs to.
+
 CLV scaling follows the reference scheme (`newviewGenericSpecial.c:604-616`):
 when every entry of a site's CLV drops below 2^-E the site is multiplied by
 2^E and an integer per-(node, site) scaler increments; lnL adds
@@ -207,6 +213,7 @@ def apply_p_factorized(models: DeviceModels, block_part: jax.Array,
     return einsum("baj,...blrj->...blra", evb, u)
 
 
+@jax.named_scope("examl/newview")
 def newview_wave(models: DeviceModels, block_part: jax.Array,
                  xl: jax.Array, xr: jax.Array,
                  zl: jax.Array, zr: jax.Array, scale_exp: int,
@@ -239,6 +246,7 @@ def newview_wave(models: DeviceModels, block_part: jax.Array,
     return v, needs.astype(jnp.int32)
 
 
+@jax.named_scope("examl/newview")
 def traverse(models: DeviceModels, block_part: jax.Array, tips: TipState,
              clv: jax.Array, scaler: jax.Array, tv: Traversal,
              scale_exp: int, ntips: int, site_rates=None):
@@ -299,6 +307,7 @@ def gather_child_pooled(tips: TipState, pool: jax.Array,
     return x, sc
 
 
+@jax.named_scope("examl/newview")
 def traverse_pooled(models: DeviceModels, block_part: jax.Array,
                     tips: TipState, pool: jax.Array, slot_read: jax.Array,
                     slot_write: jax.Array, scaler: jax.Array,
@@ -403,6 +412,7 @@ def outroot_wave(models: DeviceModels, block_part: jax.Array,
     return rescale(yu * yr), rescale(yu * yl)
 
 
+@jax.named_scope("examl/outroot")
 def outroot_pass(models: DeviceModels, block_part: jax.Array,
                  tips: TipState, clv: jax.Array, scaler: jax.Array,
                  out: jax.Array, tv: OutrootTraversal, scale_exp: int,
@@ -475,6 +485,7 @@ def per_rate_site_lnls(models: DeviceModels, block_part: jax.Array,
     return jnp.log(lsite).astype(acc) + sc[:, :, None] * log_min
 
 
+@jax.named_scope("examl/evaluate")
 def root_log_likelihood(models: DeviceModels, block_part: jax.Array,
                         weights: jax.Array, tips: TipState,
                         clv: jax.Array, scaler: jax.Array,
@@ -495,6 +506,7 @@ def root_log_likelihood(models: DeviceModels, block_part: jax.Array,
                                     site_rates)
 
 
+@jax.named_scope("examl/evaluate")
 def root_log_likelihood_from(models: DeviceModels, block_part: jax.Array,
                              weights: jax.Array, xp, sp, xq, sq,
                              z: jax.Array, num_parts: int, scale_exp: int,
@@ -521,6 +533,7 @@ def root_log_likelihood_from(models: DeviceModels, block_part: jax.Array,
     return out
 
 
+@jax.named_scope("examl/derivs")
 def newton_raphson_branch(models: DeviceModels, block_part: jax.Array,
                           weights: jax.Array, st: jax.Array, z0: jax.Array,
                           maxiters0: jax.Array, conv0: jax.Array,
@@ -591,6 +604,7 @@ def newton_raphson_branch(models: DeviceModels, block_part: jax.Array,
     return z
 
 
+@jax.named_scope("examl/sumtable")
 def sumtable(models: DeviceModels, block_part: jax.Array,
              xp: jax.Array, xq: jax.Array) -> jax.Array:
     """st[b,l,r,j] = (sum_k f_rk xp_k ev_r[k,j]) * (sum_k ei_r[j,k] xq_k).
@@ -608,6 +622,7 @@ def sumtable(models: DeviceModels, block_part: jax.Array,
     return ap * bq
 
 
+@jax.named_scope("examl/derivs")
 def nr_derivatives(models: DeviceModels, block_part: jax.Array,
                    weights: jax.Array, st: jax.Array, z: jax.Array,
                    num_slots: int, site_rates=None, axis_name=None):

@@ -269,6 +269,7 @@ def run_universal(alpha, cls, slot, cbase, lidx, ridx, lcode, rcode,
         s = jax.lax.dynamic_update_slice(s, sc, (b, z0, z0))
         return (c, s), None
 
-    (clv, scaler), _ = jax.lax.scan(body, (clv, scaler),
-                                    (cls, slot, cbase))
+    with jax.named_scope("examl/newview"):
+        (clv, scaler), _ = jax.lax.scan(body, (clv, scaler),
+                                        (cls, slot, cbase))
     return clv, scaler

@@ -135,7 +135,9 @@ def tree_gradients(inst: PhyloInstance, tree: Tree):
     cross-engine reduction `makenewz` performs per NR iteration)."""
     from examl_tpu.utils import z_slots
     p = tree.centroid_branch()
-    with obs.timer("host_schedule"):
+    # The tree's own schedule, shared by the traversal and the gradient
+    # dispatch of every engine: a span of its own, outside both.
+    with obs.span("engine:tree/schedule", also="host_schedule"):
         flat = tree.flat_full_traversal(p)
     C = inst.num_branch_slots
     root_z = z_slots(p.z, C)
@@ -178,36 +180,42 @@ def gradient_smooth_tree(inst: PhyloInstance, tree: Tree,
     C = inst.num_branch_slots
     scale = prev_step = None
     for _ in range(max(1, 4 * maxtimes)):
-        d0 = obs.counter("engine.dispatch_count")
-        inst.partition_smoothed[:] = True
-        slots, d1, d2 = tree_gradients(inst, tree)
-        z0 = np.clip(np.stack([z_slots(s.z, C) for s in slots]),
-                     ZMIN, ZMAX)
-        znew = gradient.newton_step(z0, d1, d2)
-        step = np.log(znew) - np.log(z0)
-        if scale is None:
-            scale = np.full_like(step, damping)
-        else:
-            flip = prev_step * step < 0.0
-            scale = np.maximum(
-                np.where(flip, scale * 0.5,
-                         np.minimum(scale * 1.2, damping)), 1.0 / 64)
-        prev_step = step
-        zapp = np.clip(z0 * np.exp(step * scale), ZMIN, ZMAX)
-        upd = ~inst.partition_converged
-        zapp = np.where(upd[None, :], zapp, z0)
-        moved = np.abs(zapp - z0) > DELTAZ
-        inst.partition_smoothed &= ~(upd & moved.any(axis=0))
-        for i, s in enumerate(slots):
-            s.z[:] = zapp[i].tolist()
-        # The ROADMAP §5 acceptance gauge: device dispatches this sweep
-        # cost — O(1) per engine here vs O(n) on the per-branch path
-        # (which publishes the same gauge from its own loop).
-        obs.gauge("engine.dispatches_per_smoothing_round",
-                  obs.counter("engine.dispatch_count") - d0)
-        obs.inc("optimize.grad_smooth_sweeps")
-        if _all_smoothed(inst):
-            return True
+        with obs.span("opt:smooth_sweep"):
+            d0 = obs.counter("engine.dispatch_count")
+            inst.partition_smoothed[:] = True
+            slots, d1, d2 = tree_gradients(inst, tree)
+            # The host's half of a sweep: the batched Newton/Rprop
+            # update and the write-back of z into the tree.
+            with obs.span("opt:newton_update"):
+                z0 = np.clip(np.stack([z_slots(s.z, C) for s in slots]),
+                             ZMIN, ZMAX)
+                znew = gradient.newton_step(z0, d1, d2)
+                step = np.log(znew) - np.log(z0)
+                if scale is None:
+                    scale = np.full_like(step, damping)
+                else:
+                    flip = prev_step * step < 0.0
+                    scale = np.maximum(
+                        np.where(flip, scale * 0.5,
+                                 np.minimum(scale * 1.2, damping)),
+                        1.0 / 64)
+                prev_step = step
+                zapp = np.clip(z0 * np.exp(step * scale), ZMIN, ZMAX)
+                upd = ~inst.partition_converged
+                zapp = np.where(upd[None, :], zapp, z0)
+                moved = np.abs(zapp - z0) > DELTAZ
+                inst.partition_smoothed &= ~(upd & moved.any(axis=0))
+                for i, s in enumerate(slots):
+                    s.z[:] = zapp[i].tolist()
+            # The ROADMAP §5 acceptance gauge: device dispatches this
+            # sweep cost — O(1) per engine here vs O(n) on the
+            # per-branch path (which publishes the same gauge from its
+            # own loop).
+            obs.gauge("engine.dispatches_per_smoothing_round",
+                      obs.counter("engine.dispatch_count") - d0)
+            obs.inc("optimize.grad_smooth_sweeps")
+            if _all_smoothed(inst):
+                return True
     return False
 
 
@@ -306,5 +314,7 @@ def region_smooth(inst: PhyloInstance, tree: Tree, p: Node, region: int,
 def tree_evaluate(inst: PhyloInstance, tree: Tree,
                   smooth_factor: float = 1.0) -> float:
     """Smooth all branches then evaluate (ref `treeEvaluate`)."""
-    smooth_tree(inst, tree, int(SMOOTHINGS * smooth_factor))
-    return inst.evaluate(tree, tree.start, full=True)
+    with obs.span("opt:tree_evaluate",
+                  args={"smooth_factor": smooth_factor}):
+        smooth_tree(inst, tree, int(SMOOTHINGS * smooth_factor))
+        return inst.evaluate(tree, tree.start, full=True)

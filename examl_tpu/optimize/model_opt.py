@@ -40,7 +40,7 @@ def _opt_param(inst: PhyloInstance, tree: Tree, groups: Sequence[List[int]],
                setv: Callable[[int, float], None],
                lim_inf: float, lim_sup: float,
                tol: float = MODEL_EPSILON, only_states=None,
-               coherent: bool = False) -> None:
+               coherent: bool = False, param: str = "") -> None:
     """Optimize one scalar parameter per linkage group by batched Brent.
 
     get0(gid) reads the current value from partition gid; setv(gid, v)
@@ -49,32 +49,35 @@ def _opt_param(inst: PhyloInstance, tree: Tree, groups: Sequence[List[int]],
     Brent probes touch only the affected state buckets (only_states);
     the final evaluate is unrestricted so all engines end coherent.
     coherent=True promises per_partition_lnl already matches the current
-    models+tree (skips the leading full evaluate).
+    models+tree (skips the leading full evaluate).  `param` names the
+    parameter in the `opt:brent` span.
     """
     if not groups:
         return
-    if not coherent:
-        inst.evaluate(tree, full=True)
-    start_lnl = _group_lnl(inst, groups)
-    x0 = np.array([get0(grp[0]) for grp in groups])
+    with obs.span("opt:brent", args={"param": param,
+                                     "groups": len(groups)}):
+        if not coherent:
+            inst.evaluate(tree, full=True)
+        start_lnl = _group_lnl(inst, groups)
+        x0 = np.array([get0(grp[0]) for grp in groups])
 
-    def fn(xs: np.ndarray) -> np.ndarray:
-        for grp, v in zip(groups, xs):
+        def fn(xs: np.ndarray) -> np.ndarray:
+            for grp, v in zip(groups, xs):
+                for gid in grp:
+                    setv(gid, float(v))
+            inst.push_models(only_states)
+            inst.evaluate(tree, full=True, only_states=only_states)
+            return -_group_lnl(inst, groups)
+
+        xb, fb = minimize_vector(x0, np.full(len(groups), lim_inf),
+                                 np.full(len(groups), lim_sup), fn, tol)
+        # Accept per group only if improved; otherwise restore.
+        for grp, v0, v1, f1, l0 in zip(groups, x0, xb, fb, start_lnl):
+            v = v1 if -f1 > l0 else v0
             for gid in grp:
                 setv(gid, float(v))
-        inst.push_models(only_states)
-        inst.evaluate(tree, full=True, only_states=only_states)
-        return -_group_lnl(inst, groups)
-
-    xb, fb = minimize_vector(x0, np.full(len(groups), lim_inf),
-                             np.full(len(groups), lim_sup), fn, tol)
-    # Accept per group only if improved; otherwise restore.
-    for grp, v0, v1, f1, l0 in zip(groups, x0, xb, fb, start_lnl):
-        v = v1 if -f1 > l0 else v0
-        for gid in grp:
-            setv(gid, float(v))
-    inst.push_models()
-    inst.evaluate(tree, full=True)
+        inst.push_models()
+        inst.evaluate(tree, full=True)
 
 
 def _rate_groups(inst: PhyloInstance, states: int) -> List[List[int]]:
@@ -115,7 +118,8 @@ def opt_rates(inst: PhyloInstance, tree: Tree,
                 inst.models[gid] = with_rates(m, rates)
 
             _opt_param(inst, tree, groups, get0, setv, RATE_MIN, RATE_MAX,
-                       tol, only_states={states}, coherent=k > 0)
+                       tol, only_states={states}, coherent=k > 0,
+                       param=f"rate{k}")
 
 
 def opt_alphas(inst: PhyloInstance, tree: Tree,
@@ -138,7 +142,8 @@ def opt_alphas(inst: PhyloInstance, tree: Tree,
         inst.models[gid] = (lg4_with_alpha(m, v)
                             if isinstance(m, LG4Params) else with_alpha(m, v))
 
-    _opt_param(inst, tree, groups, get0, setv, ALPHA_MIN, ALPHA_MAX, tol)
+    _opt_param(inst, tree, groups, get0, setv, ALPHA_MIN, ALPHA_MAX, tol,
+               param="alpha")
 
 
 def opt_lg4x(inst: PhyloInstance, tree: Tree,
@@ -174,7 +179,8 @@ def opt_lg4x(inst: PhyloInstance, tree: Tree,
             inst.models[gid] = lg4x_with_rates(inst.models[gid], rates)
 
         _opt_param(inst, tree, groups, get0, setv, LG4X_RATE_MIN,
-                   LG4X_RATE_MAX, tol, only_states={20}, coherent=k > 0)
+                   LG4X_RATE_MAX, tol, only_states={20}, coherent=k > 0,
+                   param=f"lg4x_rate{k}")
 
     exponents = {g: np.log(np.maximum(inst.models[g].rate_weights, 1e-12))
                  for g in gids}
@@ -189,7 +195,8 @@ def opt_lg4x(inst: PhyloInstance, tree: Tree,
                                                  np.exp(e))
 
         _opt_param(inst, tree, groups, get0, setv, FREQ_EXP_MIN,
-                   FREQ_EXP_MAX, tol, only_states={20}, coherent=True)
+                   FREQ_EXP_MAX, tol, only_states={20}, coherent=True,
+                   param=f"lg4x_weight{k}")
 
 
 def opt_freqs(inst: PhyloInstance, tree: Tree,
@@ -215,7 +222,7 @@ def opt_freqs(inst: PhyloInstance, tree: Tree,
 
             _opt_param(inst, tree, groups, get0, setv,
                        FREQ_EXP_MIN, FREQ_EXP_MAX, tol, only_states={states},
-                       coherent=k > 0)
+                       coherent=k > 0, param=f"freq{k}")
 
 
 def mod_opt(inst: PhyloInstance, tree: Tree, likelihood_epsilon: float,

@@ -102,7 +102,7 @@ def test_trace_jsonl_wellformed_and_balanced(tmp_path):
         with obs.span("outer", args={"k": 1}):
             with obs.span("inner"):
                 pass
-        with obs.device_span("engine:fake"):
+        with obs.span("engine:fake", cat="dispatch"):
             pass
         obs.instant("marker", args={"why": "test"})
     finally:

@@ -89,6 +89,9 @@ DISPATCH_FN_SOURCES = frozenset({"cache_get", "cache_put", "jit"})
 # -- GL005: obs-name drift ---------------------------------------------------
 # Emitters: obs facade methods whose first argument is a metric name.
 OBS_EMIT_METHODS = frozenset({"inc", "gauge", "observe", "timer"})
+# Span openers: `also=` names a second registry timer the span feeds
+# (obs/trace.py; the engine's `_dispatch` passes it through).
+OBS_SPAN_METHODS = frozenset({"span", "_dispatch"})
 # Ledger event emitters (first argument is the event kind).
 LEDGER_EMIT_METHODS = frozenset({"ledger_event", "event"})
 # Consumers inside runtime code (reading back a counter by name).
