@@ -657,7 +657,8 @@ class LikelihoodEngine:
             # GAMMA passes the literal None leaf, whose spec must be
             # None for the pytrees to match.
             "sr": P(AX) if self.psr else None,
-            "tips": kernels.TipState(codes=P(None, AX), table=REP),
+            "tips": kernels.TipState(codes=P(None, AX), masks=P(None, AX),
+                                     table=REP),
             "models": DeviceModels(*(REP,) * len(DeviceModels._fields)),
             "traversal": Traversal(*(REP,) * len(Traversal._fields)),
             "wrap": wrap,
@@ -730,8 +731,11 @@ class LikelihoodEngine:
             np.asarray(dt.tip_indicator_table(), dtype=self.dtype))
         codes = self.bucket.tip_codes.astype(np.uint8).reshape(
             self.ntips, self.bucket.local_num_blocks, self.lane)
+        masks = dt.code_bitmasks[codes].astype(
+            kernels.tip_mask_dtype(self.K))
         return kernels.TipState(
-            codes=self._put_blocks(codes, lambda s: s.scaler), table=table)
+            codes=self._put_blocks(codes, lambda s: s.scaler),
+            masks=self._put_blocks(masks, lambda s: s.scaler), table=table)
 
     # -- tensor placement ---------------------------------------------------
     # Single-device: plain jnp arrays.  Sharded, global bucket: device_put

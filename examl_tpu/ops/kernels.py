@@ -32,6 +32,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # All contractions run at full input precision: on TPU the MXU otherwise
 # truncates f32 operands to bf16, which costs ~4 decimal digits of CLV
@@ -75,7 +76,7 @@ class Traversal(NamedTuple):
     dependency waves executed sequentially, axis 1 over the independent
     entries of a wave executed as one batched newview.  `parent` indexes
     INNER CLV rows (node number - ntips - 1); `left`/`right` are 0-based
-    node indices (tips < ntips resolve against the tip-code table, the
+    node indices (tips < ntips resolve against `TipState`, the
     reference's yVector+tipVector scheme — tip CLVs are never stored).
     Padding entries point children at node 0 and the parent at the
     scratch row.
@@ -88,38 +89,68 @@ class Traversal(NamedTuple):
 
 
 class TipState(NamedTuple):
-    """Device-resident tip data: packed codes + indicator lookup table."""
-    codes: jax.Array        # [ntips, B, lane] uint8/int32 state codes
+    """Device-resident tip data, [ntips, B, lane] a field: the packed
+    state codes (the chunk and Pallas tiers contract their one-hot with
+    `table`) and, for the jnp kernels, each site's state BITMASK
+    (`DataType.code_bitmasks[codes]`, bit k set where state k is
+    compatible), from which `tip_partials` makes the 0/1 partial row by
+    arithmetic.  `table`'s dtype is the compute dtype."""
+    codes: jax.Array        # [ntips, B, lane] uint8 state codes
+    masks: jax.Array        # [ntips, B, lane] uint8/uint32 state bitmasks
     table: jax.Array        # [num_codes, K] 0/1 indicator vectors
+
+
+def tip_mask_dtype(K: int) -> np.dtype:
+    """Narrowest unsigned type holding a K-bit state mask (uint8 for DNA
+    and binary, uint32 for protein)."""
+    return np.min_scalar_type((1 << K) - 1)
+
+
+def tip_partials(tips: TipState, tip_idx: jax.Array) -> jax.Array:
+    """0/1 partials [..., B, lane, K] of tip rows tip_idx [...], in the
+    compute dtype: bit k of each site's state mask.
+
+    The one place the jnp kernels turn tip data into indicator vectors.
+    The values are `tips.table[tips.codes[tip_idx]]` exactly, made by
+    elementwise arithmetic because that lookup is an XLA gather of
+    K-element rows, which a v5e ran at ~2 ns a site: two thirds of the
+    gradient program's loops (PERF.md §6, PR 29).  `masks[tip_idx]` is a
+    gather of whole contiguous rows, the cheap kind `clv[row]` is."""
+    K = tips.table.shape[1]
+    masks = tips.masks[tip_idx]                      # [..., B, lane]
+    bits = (masks[..., None] >> jnp.arange(K, dtype=masks.dtype)) & 1
+    return bits.astype(tips.table.dtype)
+
+
+def _select_tip(tips: TipState, idx: jax.Array, ntips: int,
+                inner_clv: jax.Array, inner_sc: jax.Array):
+    """Per child of idx [...]: the tip's partials (broadcast over the R
+    rate categories) with scaler 0 where idx < ntips, else the inner
+    node's row inner_clv [..., B, lane, R, K] and scaler inner_sc."""
+    is_tip = idx < ntips
+    tip_clv = tip_partials(tips, jnp.clip(idx, 0, ntips - 1))
+    tip_clv = jnp.broadcast_to(tip_clv[..., None, :], inner_clv.shape)
+    x = jnp.where(is_tip[..., None, None, None, None], tip_clv, inner_clv)
+    sc = jnp.where(is_tip[..., None, None], 0, inner_sc)
+    return x, sc
 
 
 def gather_child(tips: TipState, clv: jax.Array, scaler: jax.Array,
                  idx: jax.Array, ntips: int):
     """CLV + scaler of child nodes given 0-based node indices idx [...].
 
-    Tips (idx < ntips) materialize their indicator vectors from the code
-    table on the fly (scaler 0); inner nodes read the stored CLV row
-    (idx - ntips).  Both gathers run and a select picks — the tip gather
-    is a uint8 lookup, negligible next to the CLV read it replaces.
+    Tips (idx < ntips) materialize their indicator vectors from their
+    state masks on the fly (`tip_partials`, scaler 0); inner nodes read
+    the stored CLV row (idx - ntips).  Both sides are computed for every
+    child and a select picks.
     """
-    R = clv.shape[3]
     idx = jnp.asarray(idx)          # plain ints (static callers) included
-    is_tip = idx < ntips
-    tip_idx = jnp.clip(idx, 0, ntips - 1)
-    codes = tips.codes[tip_idx]                      # [..., B, lane]
-    tip_clv = tips.table[codes]                      # [..., B, lane, K]
-    tip_clv = jnp.broadcast_to(
-        tip_clv[..., :, :, None, :],
-        tip_clv.shape[:-1] + (R, tip_clv.shape[-1]))
     inner_idx = jnp.clip(idx - ntips, 0, clv.shape[0] - 1)
     # astype: the arena may store CLVs in a narrower dtype (bf16 storage
     # tier, EXAML_CLV_DTYPE) — the cast happens after the (halved) HBM
     # read and is a no-op when storage == compute.
     inner_clv = clv[inner_idx].astype(tips.table.dtype)
-    sel = is_tip[..., None, None, None, None]
-    x = jnp.where(sel, tip_clv, inner_clv)
-    sc = jnp.where(is_tip[..., None, None], 0, scaler[inner_idx])
-    return x, sc
+    return _select_tip(tips, idx, ntips, inner_clv, scaler[inner_idx])
 
 
 def default_scale_exponent(dtype, backend: str | None = None) -> int:
@@ -289,22 +320,11 @@ def gather_child_pooled(tips: TipState, pool: jax.Array,
     all-ones cell 0 — the TPU-native form of the reference's single shared
     `gapColumn` CLV per node (`newviewGenericSpecial.c:139-160`).
     """
-    R = pool.shape[2]
     idx = jnp.asarray(idx)
-    is_tip = idx < ntips
-    tip_idx = jnp.clip(idx, 0, ntips - 1)
-    codes = tips.codes[tip_idx]                      # [..., B, lane]
-    tip_clv = tips.table[codes]                      # [..., B, lane, K]
-    tip_clv = jnp.broadcast_to(
-        tip_clv[..., :, :, None, :],
-        tip_clv.shape[:-1] + (R, tip_clv.shape[-1]))
     row = jnp.clip(idx - ntips, 0, slot_read.shape[0] - 1)
     cells = slot_read[row]                           # [..., B]
     inner_clv = pool[cells].astype(tips.table.dtype)  # [..., B, lane, R, K]
-    sel = is_tip[..., None, None, None, None]
-    x = jnp.where(sel, tip_clv, inner_clv)
-    sc = jnp.where(is_tip[..., None, None], 0, scaler[row])
-    return x, sc
+    return _select_tip(tips, idx, ntips, inner_clv, scaler[row])
 
 
 @jax.named_scope("examl/newview")

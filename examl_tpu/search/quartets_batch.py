@@ -63,7 +63,7 @@ def _program(eng, n_jobs: int):
     NNI_SMOOTHINGS = 16                       # ref quartets.c:254
 
     def one_job(codes4, dm, block_part, weights, tips):
-        tipv = tips.table[tips.codes[codes4]]          # [4, B, lane, K]
+        tipv = kernels.tip_partials(tips, codes4)      # [4, B, lane, K]
         tipv = jnp.broadcast_to(tipv[:, :, :, None, :],
                                 tipv.shape[:3] + (R,) + tipv.shape[-1:])
         ta, tb, tc, td = (tipv[i] for i in range(4))

@@ -168,6 +168,92 @@ def test_gradient_bitwise_stable_across_invalidation():
     assert np.array_equal(d2a, d2b)
 
 
+def _walk_data(datatype_name, ntaxa=12, nsites=300, seed=11):
+    """A shared mutation walk over the datatype's concrete states with
+    a sprinkle of ambiguity and gap characters (so tips carry multi-bit
+    state masks too)."""
+    alphabet, extras = {"DNA": ("ACGT", "RYN-"),
+                        "AA": ("ARNDCQEGHILKMFPSTWYV", "BZX-")}[datatype_name]
+    rng = np.random.default_rng(seed)
+    cur = rng.integers(0, len(alphabet), nsites)
+    seqs = []
+    for _ in range(ntaxa):
+        flip = rng.random(nsites) < 0.15
+        cur = np.where(flip, rng.integers(0, len(alphabet), nsites), cur)
+        row = np.array(list(alphabet))[cur]
+        amb = rng.random(nsites) < 0.05
+        row[amb] = rng.choice(list(extras), int(amb.sum()))
+        seqs.append("".join(row))
+    return build_alignment_data([f"t{i}" for i in range(ntaxa)], seqs,
+                                datatype_name=datatype_name)
+
+
+@pytest.mark.parametrize("datatype_name", ["DNA", "AA"])
+def test_whole_tree_gradient_bitwise_equals_table_lookup(datatype_name,
+                                                         monkeypatch):
+    """`tip_partials` changed where a tip's 0/1 row comes from, not its
+    value: d1 and d2 of every branch equal, bit for bit, those of an
+    engine whose tip side is the old `tips.table[codes]` gather."""
+    from examl_tpu.ops import kernels
+    from examl_tpu.optimize.branch import tree_gradients
+    from tests.test_tip_partials import table_lookup
+    data = _walk_data(datatype_name)
+
+    def grads():
+        inst = PhyloInstance(data)
+        tree = inst.random_tree(seed=4)
+        inst.evaluate(tree, full=True)
+        _, d1, d2 = tree_gradients(inst, tree)
+        return d1, d2
+
+    d1, d2 = grads()
+    # a fresh instance traces its programs anew, through the old lookup
+    monkeypatch.setattr(kernels, "tip_partials", table_lookup)
+    d1_old, d2_old = grads()
+    assert np.isfinite(d1).all() and np.abs(d1).max() > 0
+    assert np.array_equal(d1, d1_old)
+    assert np.array_equal(d2, d2_old)
+
+
+def _gathers(jaxpr):
+    """Every `gather` equation of a jaxpr, sub-jaxprs (scan bodies,
+    pjit calls, custom rules) included."""
+    from jax.extend import core as jex_core
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jex_core.Jaxpr):
+                    yield from _gathers(sub)
+
+
+def test_grad_program_has_no_tip_table_gather():
+    """Structural: the gradient program, walked through its scans, holds
+    no gather whose operand is the [num_codes, K] indicator table."""
+    import jax
+
+    from examl_tpu.ops import gradient
+    from examl_tpu.ops.kernels import OutrootTraversal
+    inst = PhyloInstance(correlated_dna(12, 200))
+    tree = inst.random_tree(seed=2)
+    inst.evaluate(tree, full=True)
+    eng = next(iter(inst.engines.values()))
+    flat = tree.flat_full_traversal(tree.centroid_branch())
+    gs = eng._grad_structure(flat)
+    pre, ex_rows, ey_gidx, ez = gradient.grad_arrays(
+        gs, flat, eng.row_map, eng.num_branch_slots, [0.9])
+    jaxpr = jax.make_jaxpr(eng._grad_impl)(
+        eng.clv, eng.scaler, 0, 1, 0, 1, OutrootTraversal(*pre), ex_rows,
+        ey_gidx, ez, eng.models, eng.block_part, eng.weights, eng.tips,
+        eng.site_rates)
+    shapes = [tuple(e.invars[0].aval.shape) for e in _gathers(jaxpr.jaxpr)]
+    assert shapes, "the walk found no gather at all (CLV rows are gathers)"
+    assert tuple(eng.tips.table.shape) not in shapes, shapes
+
+
 def test_grad_smooth_reaches_nr_endpoint(grad_on):
     """Gradient-mode tree_evaluate vs the per-branch-NR endpoint from
     a COMMON near-optimal start, plus the O(n)->O(1) dispatch gauge.

@@ -131,11 +131,12 @@ def _grad_call(eng, p, flat, st):
 
 def _as_shapes(eng, args, blocks: int, place):
     """`args` as ShapeDtypeStructs with the block axis scaled from 1 to
-    `blocks` (axis 1 of clv / scaler / tips.codes, axis 0 of block_part
-    / weights); `place(kind)` gives each leaf's sharding, kind being the
+    `blocks` (axis 1 of clv / scaler / tips.codes / tips.masks, axis 0
+    of block_part / weights); `place(kind)` gives each leaf's sharding, kind being the
     SiteSharding attribute the engine would place it with."""
     block_axis = {id(eng.clv): (1, "clv"), id(eng.scaler): (1, "scaler"),
                   id(eng.tips.codes): (1, "scaler"),
+                  id(eng.tips.masks): (1, "scaler"),
                   id(eng.block_part): (0, "blocks"),
                   id(eng.weights): (0, "sites")}
 
