@@ -78,8 +78,9 @@ OUT_DIR = os.path.join(ROOT, "chiprun_out")
 # is twice what the chip showed on realistic data; the f32 lnL's own
 # last place (1.0 at 1.3e7) is 8e-8.
 RTOL = 2e-5
-# Sharded against single-device `-f e`: two optimisers (per-branch Newton
-# against whole-tree gradients) stopping at the same optimum.
+# Sharded against single-device `-f e`: whole-tree gradient passes on
+# both since PR 31 (before it, sharded arenas smoothed branch by branch),
+# the site sums in another order.
 AGREE_RTOL = 1e-5
 
 # (taxa, patterns) per phase: real sizes, and the --rehearse toys.

@@ -23,6 +23,14 @@ Naming convention (dotted, lowercase):
                                device arguments (stage phases, set_models)
   engine.grad_pass             timer: whole-tree gradient dispatches
                                (fed by the `engine:grad_pass` span)
+  engine.collectives           cross-chip collectives dispatched: every
+                               call of a guarded program adds the count
+                               in its compiled text (read once, at its
+                               first call: obs/programs.py, deep mode
+                               only).  Never raised on one chip or where
+                               the text was not read: absent, not 0.  It
+                               is the executed count while no collective
+                               sits in a loop (`collectives_in_loops`)
 
 Spans (obs/trace.py) feed timers of their own, colon-named, with self
 seconds (`self_s`: duration less child spans'):
@@ -53,7 +61,10 @@ seconds (`self_s`: duration less child spans'):
   checkpoint.corrupt_skipped   unreadable checkpoints skipped at restore
   engine.traffic_bytes         modeled HBM bytes moved by traversal
                                dispatches (obs/traffic.py — the ONE
-                               bytes-per-traversal model bench.py uses)
+                               bytes-per-traversal model bench.py uses).
+                               Under a mesh the whole alignment's, a
+                               sum over chips: a chip's part is that
+                               over the gauge engine.mesh_site_shards
   engine.achieved_gbps.<tier>.<engine-tag>   windowed achieved GB/s
                                gauge per tier (scan/chunk/pallas/
                                whole) and engine, from the timed

@@ -267,6 +267,40 @@ def collective_census(compiled) -> Optional[Dict[str, int]]:
     return census
 
 
+def collectives_in_loops(text: str) -> int:
+    """How many of an optimized HLO text's collective ops sit in a
+    `while` loop: in a loop's body or condition, or in a computation
+    one of those calls.  The census counts ops in the text, and
+    `engine.collectives` adds it a dispatch, so both are the EXECUTED
+    count only while this is 0: an all-reduce the partitioner moved
+    into the gradient pass's chunk loop would still read one a pass
+    and run once a chunk.  tests/test_tpu_compile.py and
+    tests/test_sharding.py hold the mesh programs to 0."""
+    import re
+    name = r"%?([\w.\-]+)"
+    bodies: Dict[str, str] = {}
+    for m in re.finditer(rf"^(?:ENTRY\s+)?{name}\s*\(.*?\{{\s*$(.*?)^\}}",
+                         text, re.M | re.S):
+        bodies[m.group(1)] = m.group(2)
+    calls = {c: set(re.findall(
+        rf"(?:body|condition|to_apply|calls)={name}", b)) | {
+            n.strip().lstrip("%") for g in re.findall(
+                r"(?:branch|called)_computations=\{([^}]*)\}", b)
+            for n in g.split(",") if n.strip()}
+        for c, b in bodies.items()}
+    todo = [n for b in bodies.values()
+            for n in re.findall(rf"(?:body|condition)={name}", b)]
+    looped = set()
+    while todo:
+        c = todo.pop()
+        if c in bodies and c not in looped:
+            looped.add(c)
+            todo.extend(calls[c])
+    kinds = "|".join(_COLLECTIVE_KINDS)
+    return sum(len(re.findall(rf"\b(?:{kinds})(?:-start)?\(", bodies[c]))
+               for c in looped)
+
+
 def _collectives(compiled, row: dict) -> None:
     census = collective_census(compiled)
     if census is None:
@@ -274,6 +308,7 @@ def _collectives(compiled, row: dict) -> None:
         return
     row["collectives"] = census
     row["collective_total"] = sum(census.values())
+    row["collectives_in_loops"] = collectives_in_loops(compiled.as_text())
 
 
 def _analyze(compiled, row: dict) -> None:

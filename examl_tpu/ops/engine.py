@@ -243,8 +243,8 @@ class LikelihoodEngine:
         if branch_indices is None:
             branch_indices = [0] * self.num_parts
         self._branch_indices = list(branch_indices)
-        self.models = stack_models(models, branch_indices, self.dtype,
-                                   psr=psr)
+        self.models = self._replicated(stack_models(
+            models, branch_indices, self.dtype, psr=psr))
         # Per-site rate multipliers (PSR/CAT model); None selects the
         # GAMMA path in every kernel.  Placed like every per-site tensor
         # (block axis sharded) so multi-process jobs hold a global
@@ -577,7 +577,13 @@ class LikelihoodEngine:
         Callers pass window=False when the measured wall contains a
         first-call COMPILE: the histogram must keep it (that p99 is the
         point), but a compile-dominated window would publish a
-        near-zero GB/s wrongly tagged bandwidth-meaningful."""
+        near-zero GB/s wrongly tagged bandwidth-meaningful.
+
+        `nbytes` is the WHOLE alignment's (`_patterns_true`), so under a
+        mesh `engine.traffic_bytes` and the achieved-GB/s gauges are sums
+        over the chips: one chip's part, the figure its own roofline is
+        held to (the benchmark's `*_chip_roofline`), is that over the
+        gauge `engine.mesh_site_shards`."""
         obs.inc("engine.traffic_bytes", nbytes)
         # Drift gate (obs/programs.py): reconcile this dispatch's
         # analytic bytes with the serving program's XLA bytes-accessed
@@ -630,10 +636,11 @@ class LikelihoodEngine:
                              gbps=round(gbps, 3), dispatches=n,
                              source=src, **regime)
 
-    def _sev_spec_vocab(self) -> dict:
-        """PartitionSpec vocabulary + shard_map wrapper for the SEV x
-        sharding programs — shared by the engine's core programs and the
-        batched-scan program (search/batchscan.py)."""
+    def _site_spec_vocab(self) -> dict:
+        """PartitionSpec vocabulary + shard_map wrapper for the programs
+        mapped over the site axis — shared by the SEV x sharding core
+        programs, the batched-scan program (search/batchscan.py) and
+        the site-sharded gradient pass (`_grad_program`)."""
         from jax.sharding import PartitionSpec as P
 
         from examl_tpu.parallel.sharding import SITE_AXIS as AX
@@ -677,7 +684,7 @@ class LikelihoodEngine:
         emit when axis_name is set (the reference's MPI Allreduces,
         `evaluateGenericSpecial.c:968-973`,
         `makenewzGenericSpecial.c:1241-1248`)."""
-        v = self._sev_spec_vocab()
+        v = self._site_spec_vocab()
         (REP, pool_s, sc_s, aux_s, b_s, bl_s, tips_s, dm_s, tv_s, sr_s,
          wrap) = (v["rep"], v["pool"], v["scaler"], v["aux"], v["blocks"],
                   v["sites"], v["tips"], v["models"], v["traversal"],
@@ -727,8 +734,8 @@ class LikelihoodEngine:
 
     def _build_tip_state(self) -> kernels.TipState:
         dt = self._datatype()
-        table = self._put_replicated(
-            np.asarray(dt.tip_indicator_table(), dtype=self.dtype))
+        table = self._replicated(
+            jnp.asarray(dt.tip_indicator_table(), dtype=self.dtype))
         codes = self.bucket.tip_codes.astype(np.uint8).reshape(
             self.ntips, self.bucket.local_num_blocks, self.lane)
         masks = dt.code_bitmasks[codes].astype(
@@ -766,10 +773,14 @@ class LikelihoodEngine:
             return jax.make_array_from_process_local_data(sh, host)
         return jax.device_put(jnp.asarray(host), sh)
 
-    def _put_replicated(self, host: np.ndarray):
+    def _replicated(self, tree):
+        """A pytree of small arrays (models, the tip table, schedule
+        structures) placed once on every device of the mesh, so that no
+        sharded call re-lays them from device 0; the tree itself without
+        a mesh."""
         if self.sharding is None:
-            return jnp.asarray(host)
-        return jax.device_put(jnp.asarray(host), self.sharding.replicated)
+            return tree
+        return jax.device_put(tree, self.sharding.replicated)
 
     def _zeros_sharded(self, shape, dtype, pick):
         """A zero array born with its final sharding: no single-device
@@ -789,8 +800,8 @@ class LikelihoodEngine:
 
     def set_models(self, models: Sequence[ModelParams]) -> None:
         with obs.span("engine:set_models"):
-            self.models = stack_models(models, self._branch_indices,
-                                       self.dtype, psr=self.psr)
+            self.models = self._replicated(stack_models(
+                models, self._branch_indices, self.dtype, psr=self.psr))
             obs.inc("engine.staged_arrays", len(DeviceModels._fields))
 
     # -- spans of the timed path (obs/trace.py) -----------------------------
@@ -834,6 +845,11 @@ class LikelihoodEngine:
         because each is a transfer and often a one-scalar convert
         program of its own."""
         obs.inc("engine.staged_arrays")
+        if self.sharding is not None:
+            # Born replicated over the mesh: `jnp.asarray` would commit
+            # it to device 0 and every sharded call would re-lay it.
+            return jax.device_put(np.asarray(value, dtype=dtype),
+                                  self.sharding.replicated)
         return jnp.asarray(value, dtype=dtype)
 
     def invalidate_tips_changed(self) -> None:
@@ -975,10 +991,18 @@ class LikelihoodEngine:
         (engine.compile_count.bank_phase vs
         engine.first_calls.banked/unbanked) so the run artifacts prove
         where compile time was actually paid."""
-        state = {"first": True}
+        # "collectives": the cross-chip collectives in the compiled
+        # program's own text, read once by the observatory (deep mode;
+        # else unknown and never counted: the counter stays absent);
+        # every dispatch then adds them to `engine.collectives` (a
+        # one-device program holds none).  The executed count while
+        # none sits in a loop: the row's `collectives_in_loops`.
+        state = {"first": True, "collectives": 0}
 
         def call(*args):
             if not state["first"]:
+                if state["collectives"]:
+                    obs.inc("engine.collectives", state["collectives"])
                 return fn(*args)
             state["first"] = False
             import os as _os
@@ -1084,12 +1108,15 @@ class LikelihoodEngine:
                     else:
                         obs.inc("engine.first_calls.unbanked")
                         obs.inc(f"engine.first_calls.unbanked.{family}")
-                _programs.record(
+                row = _programs.record(
                     family, key if key is not None else family,
                     ("xla-cache"
                      if _programs.xla_cache_hits() > cache_hits0
                      else "fresh"),
                     dt, lowered=lowered)
+                state["collectives"] = (row or {}).get("collective_total", 0)
+                if state["collectives"]:
+                    obs.inc("engine.collectives", state["collectives"])
 
         return call
 
@@ -1268,6 +1295,10 @@ class LikelihoodEngine:
         st = fastpath.build_structure(flat, self.ntips)
         assert st.max_write <= self.num_rows - 1, \
             (st.max_write, self.num_rows)
+        if self.sharding is not None:
+            st = st._replace(**self._replicated(
+                {f: getattr(st, f)
+                 for f in ("base", "lidx", "ridx", "lcode", "rcode")}))
         self._sched_cache[flat.topo_key] = st
         while len(self._sched_cache) > self._sched_cache_cap:
             self._sched_cache.popitem(last=False)
@@ -1351,8 +1382,9 @@ class LikelihoodEngine:
             except universal.UniversalIneligible:
                 obs.inc("engine.universal_ineligible")
         with self._phase("schedule"):
-            zl, zr = fastpath.refresh_z(st, flat, self.num_branch_slots,
-                                        self.dtype)
+            zl, zr = fastpath.refresh_z(
+                st, flat, self.num_branch_slots, self.dtype,
+                placement=self.sharding and self.sharding.replicated)
         self._note_fast_program(st.profile)
         if p_num is None:
             fn = self._fast_fn_flat(st.profile, with_eval=False)
@@ -2192,8 +2224,8 @@ class LikelihoodEngine:
         outroot arena lives only inside this program; clv/scaler are
         read-only (NOT donated — the engine keeps serving them)."""
         from examl_tpu.ops import gradient
-        out = jnp.zeros((2 * self.ntips - 1, self.B, self.lane, self.R,
-                         self.K), dtype=self.dtype)
+        out = jnp.zeros((2 * self.ntips - 1,) + clv.shape[1:],
+                        dtype=self.dtype)
         dq, _ = kernels.gather_child(tips, clv, scaler, q_gidx, self.ntips)
         dp, _ = kernels.gather_child(tips, clv, scaler, p_gidx, self.ntips)
         out = out.at[p_row].set(dq.astype(out.dtype))
@@ -2203,6 +2235,38 @@ class LikelihoodEngine:
         return gradient.edge_gradients(
             dm, block_part, weights, tips, clv, scaler, out, ex_rows,
             ey_gidx, ez, self.num_branch_slots, self.ntips, sr)
+
+    def _grad_program(self):
+        """The jitted gradient pass.  Without a mesh, `_grad_impl` as it
+        is.  Site-sharded, the same body under `jax.shard_map` over the
+        site axis: every chip runs the one-chip program on its own
+        blocks (its shard of `clv`, an outroot arena born at its shard's
+        size, row gathers and scatters that never leave the chip) and
+        the two [E, C] site sums meet in ONE `psum` after the chunk
+        loop: ExaML's derivative Allreduce
+        (`makenewzGenericSpecial.c:1241-1248`), once a pass.  Left to
+        GSPMD the segment sums would reduce across chips inside every
+        chunk body.  The mapped function keeps the name: traces and the
+        benchmark find the program as `jit__grad_impl`."""
+        if self.sharding is None:
+            return jax.jit(self._grad_impl)
+        from examl_tpu.parallel.sharding import SITE_AXIS
+        v = self._site_spec_vocab()
+        rep, arena = v["rep"], v["scaler"]
+
+        def _grad_impl(*args):
+            d1, d2 = self._grad_impl(*args)
+            both = jax.lax.psum(jnp.stack([d1, d2]), SITE_AXIS)
+            return both[0], both[1]
+
+        return v["wrap"](
+            _grad_impl,
+            (arena, arena, rep, rep, rep, rep,
+             kernels.OutrootTraversal(
+                 *(rep,) * len(kernels.OutrootTraversal._fields)),
+             rep, rep, rep, v["models"], v["blocks"], v["sites"],
+             v["tips"], v["sr"]),
+            (rep, rep))
 
     def whole_tree_gradients(self, flat, root_z):
         """(d1, d2) [E, C]: lnL gradient and curvature w.r.t. lz = log z
@@ -2238,7 +2302,7 @@ class LikelihoodEngine:
                    _next_pow2(gs.n_chunks))
             fn = self.cache_get(key)
             if fn is None:
-                fn = self.cache_put(key, jax.jit(self._grad_impl))
+                fn = self.cache_put(key, self._grad_program())
             p, q = gs.roots
             up_row, lrow, rrow, lg, rg, zu, zl, zr = pre
             with self._phase("stage"):

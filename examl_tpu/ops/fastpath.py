@@ -602,7 +602,7 @@ def build_structure(flat, ntips: int,
 
 
 def refresh_z(st: FastStructure, flat, num_slots: int, dtype,
-              total_slots: Optional[int] = None):
+              total_slots: Optional[int] = None, placement=None):
     """The DYNAMIC half of a cached schedule: permute the traversal's
     branch-length vectors into packed chunk-slot order (canonical swap
     applied; padding slots at z=1, replay slots repeating their source
@@ -610,7 +610,9 @@ def refresh_z(st: FastStructure, flat, num_slots: int, dtype,
     on a schedule-cache hit.  `total_slots` (>= the structure's packed
     slot count) pads the result with z=1 rows for the universal
     interpreter's bucketed slot axis (ops/universal.py); the padding
-    rows are never read."""
+    rows are never read.  `placement` is the sharding the two arrays
+    are born with (a mesh's replicated one; the default device
+    without)."""
     zl_f = flat.zl
     zr_f = flat.zr
     if zl_f.shape[1] != num_slots:
@@ -627,7 +629,8 @@ def refresh_z(st: FastStructure, flat, num_slots: int, dtype,
     zr = np.ones((Pout, num_slots))
     zl[:P][ok] = np.where(sw, zr_f[src], zl_f[src])
     zr[:P][ok] = np.where(sw, zl_f[src], zr_f[src])
-    return jax.device_put([np.asarray(zl, dtype), np.asarray(zr, dtype)])
+    return jax.device_put([np.asarray(zl, dtype), np.asarray(zr, dtype)],
+                          placement)
 
 
 def _z_matrix(zs: List[tuple], num_slots: int) -> np.ndarray:

@@ -12,8 +12,9 @@ Two execution modes for the FULL-tree pass (`smooth_tree`):
   dispatch per branch per sweep — O(n) sequential dispatches per sweep,
   the dispatch storm BENCH r03/r04 measured at `newton_branch_ms` ~10x
   `evaluate_ms`.  Retained verbatim for `local_smooth`/`region_smooth`
-  (a handful of branches), for -S/sharded instances, and as the
-  fallback ladder rung (`EXAML_GRAD_SMOOTH=0` restores it exactly).
+  (a handful of branches), for -S pools, multi-process meshes and
+  fabrics with tree slices, and as the fallback ladder rung
+  (`EXAML_GRAD_SMOOTH=0` restores it exactly).
 * WHOLE-TREE GRADIENT (default where eligible): per sweep, ONE
   post-order traversal dispatch plus ONE analytic gradient dispatch
   per engine yield (d1, d2) for all 2n-3 branches at once
@@ -30,6 +31,7 @@ from __future__ import annotations
 import os
 from typing import List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from examl_tpu import obs
@@ -98,8 +100,17 @@ def grad_smooth_ineligible(inst: PhyloInstance) -> Optional[str]:
     if inst.save_memory:
         return "-S SEV pools keep the per-branch Newton path"
     for eng in inst.engines.values():
-        if eng.sharding is not None:
-            return "sharded arenas keep the per-branch Newton path"
+        if eng.sharding is None:
+            continue
+        # Site-sharded arenas of one process take the pass (the 1-D
+        # mesh and the Sx1 fabric: `LikelihoodEngine._grad_program`).
+        if jax.process_count() > 1:
+            return ("multi-process meshes keep the per-branch Newton "
+                    "path (no multi-host run of the gradient pass yet)")
+        if eng.sharding.tree_shards > 1:
+            return ("a fabric with tree slices keeps the per-branch "
+                    "Newton path (the gradient pass is mapped over the "
+                    "site axis only)")
     return None
 
 

@@ -302,9 +302,9 @@ def scan_program(eng, n_chunks: int):
 
     if eng._axis_name is not None:
         # SEV x sharding: same shard_map treatment as the engine's core
-        # programs (engine._sev_spec_vocab) — each device scans its pool
+        # programs (engine._site_spec_vocab) — each device scans its pool
         # region / block range, candidate lnLs psum across the mesh.
-        v = eng._sev_spec_vocab()
+        v = eng._site_spec_vocab()
         REP = v["rep"]
         fn = v["wrap"](
             impl,
@@ -471,7 +471,7 @@ def thorough_program(eng, n_chunks: int):
                            e3.reshape(-1)], axis=1))
 
     if eng._axis_name is not None:
-        v = eng._sev_spec_vocab()
+        v = eng._site_spec_vocab()
         REP = v["rep"]
         fn = v["wrap"](
             impl,

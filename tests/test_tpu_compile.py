@@ -28,6 +28,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from examl_tpu.instance import PhyloInstance  # noqa: E402
 from examl_tpu.io.alignment import build_alignment_data  # noqa: E402
+from examl_tpu.obs.programs import collectives_in_loops  # noqa: E402
 from examl_tpu.ops import fastpath  # noqa: E402
 
 HBM_BYTES = 16 * 1000 ** 3        # one v5e chip: 16 GB (Cloud TPU docs)
@@ -219,13 +220,50 @@ def test_site_sharded_chunk_program_one_all_reduce(topo, chip_compile):
     text = compiled.as_text()
     n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
     assert n_reduce == 1, n_reduce
+    assert collectives_in_loops(text) == 0       # and it runs once a call
     for other in ("all-gather", "all-to-all", "collective-permute"):
+        assert f" {other}(" not in text and f" {other}-start(" not in text
+
+
+def test_site_sharded_gradient_program_262144_one_all_reduce(
+        topo, chip_compile):
+    """The four-chip deployment (benchmarks/configs/dna140x262k.json):
+    the whole-tree gradient pass at 140 x 262,144 DNA, four site shards
+    of 65,536 patterns.  One chip's compiler refuses this width; a shard
+    fits.  The pass is mapped over the site axis
+    (`LikelihoodEngine._grad_program`), so no arena row is gathered or
+    scattered across chips, and the derivative sums (d1 and d2 stacked)
+    meet in ONE all-reduce after the chunk loop, not one a chunk."""
+    from examl_tpu.parallel.sharding import make_mesh, site_sharding
+    sh = site_sharding(make_mesh(devices=topo.devices[:4]))
+    _, eng, _, p, flat, st = _one_block_engine("DNA")
+    _, args = _grad_call(eng, p, flat, st)
+    eng.sharding = sh                  # what select_sharding gives there
+    blocks = 262144 // 128
+    compiled = eng._grad_program().lower(*_as_shapes(
+        eng, args, blocks, lambda kind: getattr(sh, kind))).compile()
+    sizes = _fits(compiled)
+    arena = eng.num_rows * blocks * 128 * 16 * 4
+    assert sizes["arguments"] < arena // 2       # a quarter and the tips
+    # the outroot arena is born at the shard's size: 2n-1 rows a chip
+    outroot = (2 * NTAXA - 1) * (blocks // 4) * 128 * 16 * 4
+    assert outroot <= sizes["temporaries"] < 4 * outroot
+    text = compiled.as_text()
+    assert "jit__grad_impl" in text.split("\n", 1)[0]   # the trace's name
+    n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
+    assert n_reduce == 1, n_reduce
+    # one in the text is one a pass only outside every loop: moved into
+    # the chunk loop it would still count one here and run once a chunk
+    assert " while(" in text and collectives_in_loops(text) == 0
+    for other in ("all-gather", "all-to-all", "collective-permute",
+                  "reduce-scatter"):
         assert f" {other}(" not in text and f" {other}-start(" not in text
 
 
 def test_newton_program_compiles(one_chip, chip_compile):
     """`_newton_impl` (fused partial traversal + per-branch Newton: what
-    sharded arenas smooth with) at 140 x 16,384 DNA."""
+    `local_smooth`, -S pools and multi-process meshes smooth with) at
+    140 x 16,384 DNA."""
     inst, eng, tree, p, flat, st = _one_block_engine("DNA")
     eng._install_row_map(st)
     tv = eng._traversal_arrays(flat.to_entries()[-4:])
