@@ -267,15 +267,10 @@ def collective_census(compiled) -> Optional[Dict[str, int]]:
     return census
 
 
-def collectives_in_loops(text: str) -> int:
-    """How many of an optimized HLO text's collective ops sit in a
-    `while` loop: in a loop's body or condition, or in a computation
-    one of those calls.  The census counts ops in the text, and
-    `engine.collectives` adds it a dispatch, so both are the EXECUTED
-    count only while this is 0: an all-reduce the partitioner moved
-    into the gradient pass's chunk loop would still read one a pass
-    and run once a chunk.  tests/test_tpu_compile.py and
-    tests/test_sharding.py hold the mesh programs to 0."""
+def loop_bodies(text: str) -> Dict[str, str]:
+    """The computations of an optimized HLO text that run inside a
+    `while` loop, {name: body text}: a loop's body or condition, or a
+    computation one of those calls (a fusion's, a nested loop's)."""
     import re
     name = r"%?([\w.\-]+)"
     bodies: Dict[str, str] = {}
@@ -296,9 +291,49 @@ def collectives_in_loops(text: str) -> int:
         if c in bodies and c not in looped:
             looped.add(c)
             todo.extend(calls[c])
+    return {c: bodies[c] for c in sorted(looped)}
+
+
+def collectives_in_loops(text: str) -> int:
+    """How many of an optimized HLO text's collective ops sit in a
+    `while` loop (`loop_bodies`).  The census counts ops in the text,
+    and `engine.collectives` adds it a dispatch, so both are the
+    EXECUTED count only while this is 0: an all-reduce the partitioner
+    moved into the gradient pass's chunk loop would still read one a
+    pass and run once a chunk.  tests/test_tpu_compile.py and
+    tests/test_sharding.py hold the mesh programs to 0."""
+    import re
     kinds = "|".join(_COLLECTIVE_KINDS)
-    return sum(len(re.findall(rf"\b(?:{kinds})(?:-start)?\(", bodies[c]))
-               for c in looped)
+    return sum(len(re.findall(rf"\b(?:{kinds})(?:-start)?\(", b))
+               for b in loop_bodies(text).values())
+
+
+def operand_slices(text: str) -> int:
+    """How many `mini-gather-slice` instructions an optimized HLO text
+    holds.  The TPU compiler gathers rows wider than 128 blocks in
+    pieces, and makes the pieces by slicing the gather's OPERAND: each
+    such slice copies a share of a whole arena to take a few rows of it
+    (PERF.md §6, PR 32).  0 on other backends and where every row is
+    read by index (`kernels.take_rows`)."""
+    import re
+    return len(re.findall(r"^\s*(?:ROOT\s+)?%?mini-gather-slice[\w.\-]*\s*=",
+                          text, re.M))
+
+
+def arena_gathers(text: str) -> int:
+    """How many gathers of a program text read whole rows of a CLV or
+    outroot arena [rows, B, lane, R, K]: a rank-5 operand whose slices
+    span the lane axis.  In a lowering's StableHLO and in optimized HLO
+    alike the gather states its `slice_sizes`.  With `operand_slices`
+    it names the form `kernels.take_rows` took and what the compiler
+    made of it: a lowering that holds such gathers reads rows by
+    gather, one that holds none by index."""
+    import re
+    from examl_tpu.constants import TPU_LANE
+    sizes = re.findall(r"gather.*?slice_sizes\s*=\s*(?:array<i64:|\{)"
+                       r"\s*([\d,\s]+)[>}]", text)
+    return sum(len(d) == 5 and d[2] == TPU_LANE
+               for d in ([int(x) for x in t.split(",")] for t in sizes))
 
 
 def _collectives(compiled, row: dict) -> None:
@@ -308,7 +343,9 @@ def _collectives(compiled, row: dict) -> None:
         return
     row["collectives"] = census
     row["collective_total"] = sum(census.values())
-    row["collectives_in_loops"] = collectives_in_loops(compiled.as_text())
+    text = compiled.as_text()
+    row["collectives_in_loops"] = collectives_in_loops(text)
+    row["operand_slices"] = operand_slices(text)
 
 
 def _analyze(compiled, row: dict) -> None:
@@ -351,6 +388,11 @@ def _record(family, key, source, compile_s, lowered, compiled):
                     time.perf_counter() - t0)
     if compiled is not None:
         _analyze(compiled, row)
+    if lowered is not None:
+        try:
+            row["arena_gathers"] = arena_gathers(lowered.as_text())
+        except Exception:                    # noqa: BLE001 — ladder rung
+            _missing("arena_gathers", row)
     with _lock:
         rows = _STATE["rows"]
         rows[(family, row["key"])] = row

@@ -232,7 +232,7 @@ def edge_gradients(models, block_part, weights, tips, clv, scaler, out,
     Newton path's `sumtable`/`nr_derivatives`)."""
     def body(carry, x):
         xr, yg, z = x
-        X = out[xr]                               # [T, B, lane, R, K]
+        X = kernels.take_rows(out, xr)            # [T, B, lane, R, K]
         Y, _sc = kernels.gather_child(tips, clv, scaler, yg, ntips)
         st = jax.vmap(
             lambda a, b: kernels.sumtable(models, block_part, a, b))(X, Y)

@@ -115,7 +115,9 @@ def tip_partials(tips: TipState, tip_idx: jax.Array) -> jax.Array:
     elementwise arithmetic because that lookup is an XLA gather of
     K-element rows, which a v5e ran at ~2 ns a site: two thirds of the
     gradient program's loops (PERF.md §6, PR 29).  `masks[tip_idx]` is a
-    gather of whole contiguous rows, the cheap kind `clv[row]` is."""
+    gather of whole contiguous rows of a byte a site: cheap at every
+    width (131 KB a row at 131,072 patterns, never split), as a CLV
+    row's gather is only up to 128 blocks (`take_rows`)."""
     K = tips.table.shape[1]
     masks = tips.masks[tip_idx]                      # [..., B, lane]
     bits = (masks[..., None] >> jnp.arange(K, dtype=masks.dtype)) & 1
@@ -135,22 +137,54 @@ def _select_tip(tips: TipState, idx: jax.Array, ntips: int,
     return x, sc
 
 
+# Widest row, in sites (blocks x lanes), that the TPU compiler gathers
+# in one piece: 128 blocks of 128 lanes, whatever R, K and the dtype.
+ONE_PIECE_SITES = 128 * 128
+
+
+def take_rows(arena: jax.Array, idx: jax.Array) -> jax.Array:
+    """`arena[idx]`, bit for bit: whole rows [B, lane, ...] of an arena
+    [rows, B, lane, ...] at in-range row indices idx [...].
+
+    Up to `ONE_PIECE_SITES` sites a row it IS `arena[idx]`, a gather
+    the compiler runs near the HBM roofline.  A wider gather the v5e
+    compiler cuts into B/128 pieces by slicing its OPERAND: every piece
+    copies its share of the whole arena, in every iteration of the loop
+    the read sits in, to take 8 or 32 rows of it.  A row is a
+    contiguous block of HBM and its index a scalar, so there a loop
+    over idx reads each row by a dynamic slice into a
+    [len(idx), B, lane, ...] block: the rows' bytes and no more.  The
+    form follows from the arena's shape alone.  (PERF.md §6, PR 32:
+    the loop costs a 1 MiB row 2 us more than its gather; unrolled,
+    the slices cost a relayout copy of each whole arena.)"""
+    idx = jnp.asarray(idx)
+    if idx.ndim == 0 or arena.shape[1] * arena.shape[2] <= ONE_PIECE_SITES:
+        return arena[idx]
+    rows = jax.lax.map(
+        lambda i: jax.lax.dynamic_index_in_dim(arena, i, 0, keepdims=False),
+        idx.reshape(-1))
+    return rows.reshape(idx.shape + arena.shape[1:])
+
+
 def gather_child(tips: TipState, clv: jax.Array, scaler: jax.Array,
                  idx: jax.Array, ntips: int):
     """CLV + scaler of child nodes given 0-based node indices idx [...].
 
     Tips (idx < ntips) materialize their indicator vectors from their
     state masks on the fly (`tip_partials`, scaler 0); inner nodes read
-    the stored CLV row (idx - ntips).  Both sides are computed for every
-    child and a select picks.
+    the stored CLV row (idx - ntips) through `take_rows`: a gather up
+    to 128 blocks, a dynamic slice a row above, where the gather would
+    copy the arena.  Both sides are computed for every child and a
+    select picks.
     """
     idx = jnp.asarray(idx)          # plain ints (static callers) included
     inner_idx = jnp.clip(idx - ntips, 0, clv.shape[0] - 1)
     # astype: the arena may store CLVs in a narrower dtype (bf16 storage
     # tier, EXAML_CLV_DTYPE) — the cast happens after the (halved) HBM
     # read and is a no-op when storage == compute.
-    inner_clv = clv[inner_idx].astype(tips.table.dtype)
-    return _select_tip(tips, idx, ntips, inner_clv, scaler[inner_idx])
+    inner_clv = take_rows(clv, inner_idx).astype(tips.table.dtype)
+    return _select_tip(tips, idx, ntips, inner_clv,
+                       take_rows(scaler, inner_idx))
 
 
 def default_scale_exponent(dtype, backend: str | None = None) -> int:
@@ -447,7 +481,7 @@ def outroot_pass(models: DeviceModels, block_part: jax.Array,
     def body(carry, e):
         out = carry
         up_row, lrow, rrow, left, right, zu, zl, zr = e
-        xu = out[up_row]
+        xu = take_rows(out, up_row)
         xl, _ = gather_child(tips, clv, scaler, left, ntips)
         xr, _ = gather_child(tips, clv, scaler, right, ntips)
         ol, orr = outroot_wave(models, block_part, xu, xl, xr,

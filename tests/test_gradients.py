@@ -215,19 +215,22 @@ def test_whole_tree_gradient_bitwise_equals_table_lookup(datatype_name,
     assert np.array_equal(d2, d2_old)
 
 
-def _gathers(jaxpr):
-    """Every `gather` equation of a jaxpr, sub-jaxprs (scan bodies,
-    pjit calls, custom rules) included."""
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (scan bodies, pjit calls,
+    custom rules) included."""
     from jax.extend import core as jex_core
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "gather":
-            yield eqn
+        yield eqn
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 if isinstance(sub, jex_core.ClosedJaxpr):
                     sub = sub.jaxpr
                 if isinstance(sub, jex_core.Jaxpr):
-                    yield from _gathers(sub)
+                    yield from _eqns(sub)
+
+
+def _gathers(jaxpr):
+    return (e for e in _eqns(jaxpr) if e.primitive.name == "gather")
 
 
 def test_grad_program_has_no_tip_table_gather():
@@ -252,6 +255,156 @@ def test_grad_program_has_no_tip_table_gather():
     shapes = [tuple(e.invars[0].aval.shape) for e in _gathers(jaxpr.jaxpr)]
     assert shapes, "the walk found no gather at all (CLV rows are gathers)"
     assert tuple(eng.tips.table.shape) not in shapes, shapes
+
+
+# -- arena rows read by index (kernels.take_rows) ------------------------------
+
+
+@pytest.fixture
+def indexed_rows(monkeypatch):
+    """Every arena, however narrow, read by index: the form arenas wider
+    than `ONE_PIECE_SITES` sites a row take."""
+    from examl_tpu.ops import kernels
+    monkeypatch.setattr(kernels, "ONE_PIECE_SITES", 0)
+
+
+def _prims(jaxpr):
+    return [e.primitive.name for e in _eqns(jaxpr)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("idx_shape", [(), (8,), (3, 4)],
+                         ids=["scalar", "W", "LxW"])
+@pytest.mark.parametrize("form", ["gather", "indexed"])
+def test_take_rows_equals_fancy_index(form, idx_shape, dtype, monkeypatch):
+    """The helper alone: `arena[idx]` to the last bit in both forms, for
+    a scalar, a wave's [W] and a schedule's [L, W] indices, repeats and
+    the scratch row included."""
+    import jax
+    import jax.numpy as jnp
+
+    from examl_tpu.ops import kernels
+    if form == "indexed":
+        monkeypatch.setattr(kernels, "ONE_PIECE_SITES", 0)
+    rng = np.random.default_rng(5)
+    arena = jnp.asarray(rng.standard_normal((7, 2, 128, 4, 4)), dtype=dtype)
+    idx = jnp.asarray(rng.integers(0, 7, idx_shape), dtype=jnp.int32)
+    def take(a, i):          # traced anew: jax caches a trace by function
+        return kernels.take_rows(a, i)
+
+    got = jax.jit(take)(arena, idx)
+    want = arena[idx]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(np.asarray(got.astype(jnp.float32)),
+                          np.asarray(want.astype(jnp.float32)))
+    prims = _prims(jax.make_jaxpr(take)(arena, idx).jaxpr)
+    assert ("gather" in prims) == (form == "gather" and idx_shape != ())
+
+
+@pytest.mark.parametrize("blocks, lane_k, gathered", [
+    (128, (128, 4, 4), True),          # 140 x 16,384 DNA: one piece
+    (128, (128, 4, 20), True),         # protein: bytes do not count
+    (128, (128,), True),               # the scaler's rows
+    (129, (128, 4, 4), False),
+    (512, (128, 4, 4), False),         # a shard of the four-chip cell
+    (1024, (128, 4, 4), False)])       # 140 x 131,072
+def test_take_rows_form_follows_row_width(blocks, lane_k, gathered):
+    """Which width takes which: up to 128 blocks a row the gather the
+    compiler runs in one piece, above it a loop of dynamic slices and
+    no gather; chosen from the arena's shape, nothing else."""
+    import jax
+    import jax.numpy as jnp
+
+    from examl_tpu.ops import kernels
+    arena = jax.ShapeDtypeStruct((9, blocks) + lane_k, jnp.float32)
+    idx = jax.ShapeDtypeStruct((8,), jnp.int32)
+    prims = _prims(jax.make_jaxpr(kernels.take_rows)(arena, idx).jaxpr)
+    if gathered:
+        assert "gather" in prims and "scan" not in prims
+    else:
+        assert "gather" not in prims
+        assert "scan" in prims and "dynamic_slice" in prims
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("nsites, blocks", [(100, 1), (500, 4)])
+@pytest.mark.parametrize("datatype_name", ["DNA", "AA"])
+def test_whole_tree_gradient_bitwise_equals_row_gather(
+        datatype_name, nsites, blocks, dtype, indexed_rows, monkeypatch):
+    """Reading rows by index changed how a row reaches the kernels, not
+    the row: d1 and d2 of every branch equal, bit for bit, those of an
+    engine whose helper is `arena[idx]` again."""
+    import jax.numpy as jnp
+
+    from examl_tpu.ops import kernels
+    from examl_tpu.optimize.branch import tree_gradients
+    data = _walk_data(datatype_name, nsites=nsites)
+
+    def grads():
+        inst = PhyloInstance(data, dtype=jnp.dtype(dtype))
+        (eng,) = inst.engines.values()
+        assert eng.B == blocks and eng.dtype == jnp.dtype(dtype)
+        tree = inst.random_tree(seed=4)
+        inst.evaluate(tree, full=True)
+        _, d1, d2 = tree_gradients(inst, tree)
+        return d1, d2
+
+    d1, d2 = grads()
+    # a fresh instance traces its programs anew, through the gather
+    monkeypatch.setattr(kernels, "take_rows", lambda arena, idx: arena[idx])
+    d1_old, d2_old = grads()
+    assert np.isfinite(d1).all() and np.abs(d1).max() > 0
+    assert np.array_equal(d1, d1_old)
+    assert np.array_equal(d2, d2_old)
+
+
+def test_scan_tier_lnl_bitwise_equals_row_gather(indexed_rows, monkeypatch):
+    """`gather_child` is shared: the scan tier's `traverse` reads a
+    wave's children through the same helper, scalers too, and its lnL
+    is the gathering engine's bit for bit."""
+    from examl_tpu.ops import kernels
+    data = _walk_data("DNA", nsites=500)
+
+    def lnl():
+        inst = PhyloInstance(data)
+        for eng in inst.engines.values():
+            eng.force_scan = True
+        return inst.evaluate(inst.random_tree(seed=4), full=True)
+
+    got = lnl()
+    monkeypatch.setattr(kernels, "take_rows", lambda arena, idx: arena[idx])
+    assert np.isfinite(got) and got == lnl()
+
+
+@pytest.mark.parametrize("form", ["gather", "indexed"])
+def test_grad_program_gathers_no_wide_arena(form, monkeypatch):
+    """Structural: read by index, the gradient program walked through its
+    scans holds no gather whose operand is a rank-5 arena (CLV or
+    outroot: `xu`, `xl`, `xr`, `X`, `Y`); the narrow form holds those
+    five, which the compiler runs in one piece each."""
+    import jax
+
+    from examl_tpu.ops import gradient, kernels
+    from examl_tpu.ops.kernels import OutrootTraversal
+    if form == "indexed":
+        monkeypatch.setattr(kernels, "ONE_PIECE_SITES", 0)
+    inst = PhyloInstance(correlated_dna(12, 200))
+    tree = inst.random_tree(seed=2)
+    inst.evaluate(tree, full=True)
+    eng = next(iter(inst.engines.values()))
+    flat = tree.flat_full_traversal(tree.centroid_branch())
+    gs = eng._grad_structure(flat)
+    pre, ex_rows, ey_gidx, ez = gradient.grad_arrays(
+        gs, flat, eng.row_map, eng.num_branch_slots, [0.9])
+    jaxpr = jax.make_jaxpr(eng._grad_impl)(
+        eng.clv, eng.scaler, 0, 1, 0, 1, OutrootTraversal(*pre), ex_rows,
+        ey_gidx, ez, eng.models, eng.block_part, eng.weights, eng.tips,
+        eng.site_rates)
+    shapes = [tuple(e.invars[0].aval.shape) for e in _gathers(jaxpr.jaxpr)]
+    assert tuple(eng.tips.masks.shape) in shapes, "the walk lost the tips"
+    rows = tuple(eng.clv.shape[1:])              # [B, lane, R, K]
+    arenas = [s for s in shapes if len(s) == 5 and s[1:] == rows]
+    assert len(arenas) == (5 if form == "gather" else 0), shapes
 
 
 def test_grad_smooth_reaches_nr_endpoint(grad_on):

@@ -170,6 +170,31 @@ def test_partial_analyses_count_each_missing_field():
     assert set(row["missing"]) >= {"bytes_accessed", "temp_bytes"}
 
 
+# What the v5e compiler writes where it splits a wide row gather by
+# slicing the gather's operand (names and shapes of PERF.md, PR 32).
+_SPLIT_GATHER = """
+%fused_computation.294 (param_0.1: f32[279,1024,128,4,4]) -> (f32[279,128,128,4,4], f32[279,128,128,4,4]) {
+  %param_0.1 = f32[279,1024,128,4,4]{2,4,3,1,0:T(4,128)} parameter(0)
+  %mini-gather-slice.8 = f32[279,128,128,4,4]{2,4,3,1,0:T(4,128)} slice(%param_0.1), slice={[0:279], [0:128], [0:128], [0:4], [0:4]}
+  %mini-gather-slice.9 = f32[279,128,128,4,4]{2,4,3,1,0:T(4,128)} slice(%param_0.1), slice={[0:279], [128:256], [0:128], [0:4], [0:4]}
+  ROOT %tuple.7 = (f32[279,128,128,4,4], f32[279,128,128,4,4]) tuple(%mini-gather-slice.8, %mini-gather-slice.9)
+}
+"""
+
+
+@pytest.mark.parametrize("text, n", [
+    (_SPLIT_GATHER, 2),          # uses of a slice's name are not slices
+    ("  %slice.3 = f32[8,128]{1,0} slice(%p), slice={[0:8], [0:128]}\n"
+     "  %gather.1 = f32[8,128,4]{2,1,0} gather(%a, %i), "
+     "slice_sizes={1,128,4}\n", 0),
+    (FakeCompiled._HLO, 0)])
+def test_operand_slices_counts_the_split_gathers_pieces(text, n):
+    assert programs.operand_slices(text) == n
+    row = programs.record("grad", ("grad", n), "fresh", 0.1,
+                          compiled=FakeCompiled(text=text))
+    assert row["operand_slices"] == n
+
+
 def test_record_never_raises_on_hostile_compiled():
     class Hostile:
         def __getattr__(self, name):
@@ -409,6 +434,26 @@ def test_engine_dispatches_populate_observatory_with_drift(monkeypatch):
     src = {k: v for k, v in snap["gauges"].items()
            if k.startswith("engine.traffic_source_xla.")}
     assert src and all(v in (0.0, 1.0) for v in src.values())
+
+
+@pytest.mark.parametrize("one_piece_sites, gathers", [(None, 5), (0, 0)])
+def test_grad_row_names_how_its_rows_are_read(one_piece_sites, gathers,
+                                              monkeypatch):
+    """The gradient program's row, written at its first call: how many
+    arena gathers its lowering handed the compiler (the five row reads
+    of a narrow arena, none where rows are read by index) and how many
+    operand slices the compiler made of them."""
+    from examl_tpu.ops import kernels
+    from examl_tpu.optimize.branch import tree_gradients
+    if one_piece_sites is not None:
+        monkeypatch.setattr(kernels, "ONE_PIECE_SITES", one_piece_sites)
+    inst, tree = _tiny_instance()
+    inst.evaluate(tree, full=True)
+    tree_gradients(inst, tree)
+    (row,) = [r for r in programs.table() if r["family"] == "grad"]
+    assert row["arena_gathers"] == gathers
+    assert row["operand_slices"] == 0        # no TPU compiler here
+    assert "missing" not in row
 
 
 @pytest.fixture

@@ -265,6 +265,27 @@ def test_sharded_whole_tree_gradients_match(sharded):
                                atol=1e-6 * np.abs(r2).max())
 
 
+def test_sharded_gradients_read_by_index_bitwise(problem, monkeypatch):
+    """The mapped pass reads a shard's wide rows by index
+    (`kernels.take_rows`, a loop of dynamic slices inside the
+    `shard_map`): d1 and d2 after the all-reduce equal, bit for bit,
+    those of a sharded engine that gathers them."""
+    from examl_tpu.ops import kernels
+    from examl_tpu.optimize.branch import tree_gradients
+    data, _, newick, _ = problem
+    got = []
+    for one_piece in (0, kernels.ONE_PIECE_SITES):
+        monkeypatch.setattr(kernels, "ONE_PIECE_SITES", one_piece)
+        inst = PhyloInstance(data, block_multiple=4,
+                             sharding=MESHES["mesh4"]())
+        tree = inst.tree_from_newick(newick)
+        inst.evaluate(tree, full=True)
+        got.append(tree_gradients(inst, tree)[1:])
+    (a1, a2), (b1, b2) = got
+    assert np.isfinite(a1).all() and np.abs(a1).max() > 0
+    assert np.array_equal(a1, b1) and np.array_equal(a2, b2)
+
+
 def test_sharded_tree_evaluate_makes_gradient_passes(sharded):
     """`tree_evaluate` on a site-sharded instance smooths with
     whole-tree gradient passes (O(1) dispatches a sweep, no fallback, no

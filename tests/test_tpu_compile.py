@@ -28,7 +28,9 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from examl_tpu.instance import PhyloInstance  # noqa: E402
 from examl_tpu.io.alignment import build_alignment_data  # noqa: E402
-from examl_tpu.obs.programs import collectives_in_loops  # noqa: E402
+from examl_tpu.obs.programs import (arena_gathers,  # noqa: E402
+                                    collectives_in_loops, loop_bodies,
+                                    operand_slices)
 from examl_tpu.ops import fastpath  # noqa: E402
 
 HBM_BYTES = 16 * 1000 ** 3        # one v5e chip: 16 GB (Cloud TPU docs)
@@ -165,6 +167,49 @@ def _fits(compiled) -> dict:
     return sizes
 
 
+def _arena_sized_in_loops(text: str, elems: int) -> list:
+    """Instructions inside a `while` loop (`loop_bodies`) whose result
+    holds at least `elems` elements, an arena's, other than the in-place
+    row writes: a `scatter` or `dynamic-update-slice`, or a fusion whose
+    computation ends in one.  Parameters, tuples and bitcasts move
+    nothing."""
+    import re
+    looped = loop_bodies(text)
+    in_place = ("scatter", "dynamic-update-slice")
+    inst = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\((.*)$", re.M)
+
+    def root_op(comp):
+        return next((m.group(4) for m in inst.finditer(looped.get(comp, ""))
+                     if m.group(1)), None)
+
+    found = []
+    for body in looped.values():
+        for m in inst.finditer(body):
+            _, name, dims, op, rest = m.groups()
+            n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            if n < elems or op in in_place + (
+                    "parameter", "get-tuple-element", "bitcast"):
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", rest)
+            if op == "fusion" and called and root_op(called.group(1)) \
+                    in in_place:
+                continue
+            found.append((op, name))
+    return found
+
+
+def _reads_rows_by_index(compiled, arena_elems: int) -> None:
+    """What `kernels.take_rows` is for: the compiler was handed no
+    gather of an arena, so it cut none into pieces by slicing the
+    arena, and no loop holds a copy of one."""
+    text = compiled.as_text()
+    assert " while(" in text                     # the walk has loops to see
+    assert operand_slices(text) == 0
+    assert arena_gathers(text) == 0
+    assert _arena_sized_in_loops(text, arena_elems) == []
+
+
 def test_chunk_evaluate_program_140x131072_dna(one_chip, chip_compile):
     """The fullwidth phase's first program: full traversal (XLA chunk
     tier) + root evaluation at 140 x 131,072 DNA patterns, f32."""
@@ -188,7 +233,36 @@ def test_gradient_pass_140x131072_dna(one_chip, chip_compile):
                                     lambda kind: one_chip)).compile()
     sizes = _fits(compiled)
     outroot = (2 * NTAXA - 1) * 1024 * 128 * 16 * 4
-    assert sizes["temporaries"] >= outroot
+    # 5.19 GB while the rows were gathered (32 operand slices), 3.95 GB
+    # read by index
+    assert outroot <= sizes["temporaries"] < 5.0e9
+    _reads_rows_by_index(compiled, eng.num_rows * 1024 * 128 * 16)
+
+
+def test_gradient_pass_140x16384_protein(one_chip, chip_compile):
+    """K = 20 at 128 blocks: `take_rows` hands the compiler the row
+    gathers (a row is one piece), and the compiler lowers them to loops
+    of dynamic slices itself: the same three findings."""
+    _, eng, _, p, flat, st = _one_block_engine("AA")
+    fn, args = _grad_call(eng, p, flat, st)
+    compiled = fn.lower(*_as_shapes(eng, args, 128,
+                                    lambda kind: one_chip)).compile()
+    _fits(compiled)
+    _reads_rows_by_index(compiled, eng.num_rows * 128 * 128 * 80)
+
+
+@pytest.mark.xfail(
+    strict=True, raises=AssertionError,
+    reason="fastpath.inner_child reads clv[idx] by gather: 56 operand "
+           "slices in the chunk+evaluate program at 131,072 patterns, a "
+           "copy of the arena a chunk (PERF.md section 7); the PR that "
+           "reads them through kernels.take_rows flips this")
+def test_chunk_evaluate_program_reads_rows_by_index(one_chip, chip_compile):
+    _, eng, _, p, flat, st = _one_block_engine("DNA")
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    compiled = fn.lower(*_as_shapes(eng, args, 1024,
+                                    lambda kind: one_chip)).compile()
+    _reads_rows_by_index(compiled, eng.num_rows * 1024 * 128 * 16)
 
 
 def test_chunk_evaluate_program_140x16384_protein(one_chip, chip_compile):
@@ -247,7 +321,10 @@ def test_site_sharded_gradient_program_262144_one_all_reduce(
     assert sizes["arguments"] < arena // 2       # a quarter and the tips
     # the outroot arena is born at the shard's size: 2n-1 rows a chip
     outroot = (2 * NTAXA - 1) * (blocks // 4) * 128 * 16 * 4
-    assert outroot <= sizes["temporaries"] < 4 * outroot
+    # 3.75 GB a chip while the rows were gathered (16 operand slices),
+    # 3.24 GB read by index
+    assert outroot <= sizes["temporaries"] < 3.5e9
+    _reads_rows_by_index(compiled, eng.num_rows * (blocks // 4) * 128 * 16)
     text = compiled.as_text()
     assert "jit__grad_impl" in text.split("\n", 1)[0]   # the trace's name
     n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
