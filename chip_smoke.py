@@ -412,8 +412,7 @@ def engine_fields(inst) -> dict:
     (eng,) = inst.engines.values()
     return {"dtype": str(eng.dtype), "states": eng.K, "rate_cats": eng.R,
             "blocks": eng.B, "lane": eng.lane, "clv_rows": eng.num_rows,
-            "clv_arena_bytes": int(eng.clv.nbytes),
-            "use_pallas": bool(eng.use_pallas)}
+            "clv_arena_bytes": int(eng.clv.nbytes)}
 
 
 def phase_fullwidth(ctx) -> None:
@@ -458,8 +457,6 @@ def phase_fullwidth(ctx) -> None:
           f"fullwidth: lnL fell over a smoothing sweep "
           f"({fields['lnl_engine']} -> {lnl_after})")
     check(not dem, f"fullwidth: demotions {dem}")
-    check(not eng["use_pallas"],
-          "fullwidth: default tier is not the XLA chunk tier")
 
 
 def evaluate_run(name: str, bytefile, tree_path, extra=()):

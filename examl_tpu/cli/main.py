@@ -177,7 +177,7 @@ def build_argparser() -> argparse.ArgumentParser:
                          "heartbeat, and on crash/stall restart from "
                          "the newest checkpoint with capped retries, "
                          "backoff and escalating degradation pins "
-                         "(pallas->chunk->scan); SIGTERM/SIGINT "
+                         "(chunk->universal->scan); SIGTERM/SIGINT "
                          "preemptions resume without consuming a retry")
     ap.add_argument("--supervise-retries", dest="supervise_retries",
                     type=int, default=3,

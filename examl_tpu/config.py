@@ -29,6 +29,27 @@ def enable_x64() -> None:
     jax.config.update("jax_enable_x64", True)
 
 
+# Switches whose kernels or layout were deleted, each with the values
+# that asked for them: a run that asks for a kernel gets it or an error.
+_REMOVED_SWITCHES = (("EXAML_PALLAS", None),          # anything but ""/"0"
+                     ("EXAML_PALLAS_INTERPRET", "1"),
+                     ("EXAML_BOUNDED_CHUNKS", "0"))
+
+
+def refuse_removed_switches() -> None:
+    """Raise where the environment still selects a deleted traversal
+    program (every engine passes here before its first dispatch)."""
+    import os
+
+    for name, asked in _REMOVED_SWITCHES:
+        v = os.environ.get(name, "")
+        refused = v not in ("", "0") if asked is None else v == asked
+        if refused:
+            raise ValueError(
+                f"{name}={v!r}: the code this switch selected was removed "
+                "(one fast traversal program is left); unset it")
+
+
 def host_feature_fingerprint() -> str | None:
     """Short hex fingerprint of THIS host's CPU feature set, or None when
     it cannot be determined.

@@ -519,21 +519,17 @@ class BatchEvaluator:
         """The mixed-profile batch-group key for a novel-profile job:
         ("uni", alphabet, table_bucket, slot_bucket) — a pure function
         of the job's BUCKETED universal-table shape, so topologies
-        with entirely different profiles group together.  None when
-        the layout cannot run through the interpreter (legacy
-        unbounded chunks) — the driver falls back to solo routing."""
+        with entirely different profiles group together.  None for a
+        job without a fast-path structure — the driver falls back to
+        solo routing."""
         from examl_tpu.ops import universal
         if prep.st is None:
             return None
         eng = self.engines[0]
-        try:
-            ent = eng._universal_entry(
-                prep.st.profile, np.asarray(prep.st.base),
-                (prep.st.lidx, prep.st.ridx, prep.st.lcode,
-                 prep.st.rcode),
-                cache_key=prep.flat.topo_key)
-        except universal.UniversalIneligible:
-            return None
+        ent = eng._universal_entry(
+            prep.st.profile, np.asarray(prep.st.base),
+            (prep.st.lidx, prep.st.ridx, prep.st.lcode, prep.st.rcode),
+            cache_key=prep.flat.topo_key)
         table = ent["table"]
         return ("uni", universal.alphabet_key(),
                 bucket_len(table.n_chunks), bucket_len(table.slots))

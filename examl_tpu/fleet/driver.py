@@ -116,17 +116,10 @@ class FleetDriver:
         # the ~1.3x-faster specialized batched program after
         # EXAML_FLEET_SPECIALIZE_AFTER sightings (0 = never promote:
         # the pure interpreter-serving default).
-        from examl_tpu.ops import fastpath
         engines = list(inst.engines.values())
-        # The legacy unbounded layout (EXAML_BOUNDED_CHUNKS=0) has no
-        # ladder alphabet: routing would strip batching AND still pay
-        # the per-profile compile after the interpreter declines —
-        # strictly worse than not routing (the same gate
-        # bank._applicability applies to the universal family).
         self.route_universal = (
             route_universal and self.evaluator is not None
             and self.evaluator.fast and bool(engines)
-            and fastpath.bounded_default()
             and not any(e.universal_off for e in engines))
         try:
             self._specialize_after = max(0, int(os.environ.get(

@@ -22,11 +22,10 @@ instruments are gettime() deltas and ExaML_info prints):
   decisions, probe verdicts), merged by rank 0 into one ordered gang
   timeline at exit;
 * the shared **roofline traffic model** (`obs.traffic`): the one
-  bytes-per-traversal definition bench.py and the engine both use,
-  plus the dispatch-bound vs bandwidth-meaningful regime classifier;
-* a shared **dispatch-timing helper** (`obs.timing`) so bench.py and
-  tools/perf_lab.py measure "dispatch time" identically (every rep
-  lands in the histogram; windows are ledger-audited).
+  bytes-per-traversal definition the engine uses, plus the
+  dispatch-bound vs bandwidth-meaningful regime classifier;
+* a **dispatch-timing helper** (`obs.timing`): every rep lands in the
+  histogram; windows are ledger-audited.
 
 This module is the flat facade the rest of the runtime imports:
 
@@ -90,8 +89,7 @@ def add_collector(fn: Callable[[], bool]) -> None:
 
 def snapshot() -> dict:
     snap = _metrics.registry().snapshot()
-    # The program observatory's registry rows ride in every snapshot
-    # (and, via bench worker merging, every BENCH artifact) so
+    # The program observatory's registry rows ride in every snapshot so
     # tools/run_report.py can render the Programs table from the same
     # artifact that carries the gauges.
     from examl_tpu.obs import programs as _programs

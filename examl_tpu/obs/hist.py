@@ -7,7 +7,7 @@ invisible.  Every timer therefore carries one of these: durations land
 in geometrically-spaced buckets (20 per decade, ~12% relative width)
 spanning 100 ns .. ~10^4 s, so p50/p95/p99 are readable from any
 `--metrics` snapshot and two snapshots MERGE exactly (bucket counts
-add; quantiles recompute) — the property bench.py's worker-snapshot
+add; quantiles recompute) — the property the bank's worker-snapshot
 accumulation and the supervisor's attempt merging rely on, and the one
 min/max/avg fundamentally lacks.
 
@@ -106,8 +106,7 @@ def quantile_from_buckets(buckets: Dict, q: float) -> Optional[float]:
 
 
 def merge_bucket_dicts(*dicts: Dict) -> Dict[str, int]:
-    """Sum serialized bucket dicts (the snapshot-merge primitive used by
-    bench.py's worker accumulation)."""
+    """Sum serialized bucket dicts (the snapshot-merge primitive)."""
     out: Dict[int, int] = {}
     for d in dicts:
         for k, c in (d or {}).items():

@@ -60,14 +60,14 @@ seconds (`self_s`: duration less child spans'):
   resilience.preempt_checkpoints   emergency checkpoints before exit 75
   checkpoint.corrupt_skipped   unreadable checkpoints skipped at restore
   engine.traffic_bytes         modeled HBM bytes moved by traversal
-                               dispatches (obs/traffic.py — the ONE
-                               bytes-per-traversal model bench.py uses).
+                               dispatches (obs/traffic.py: the ONE
+                               bytes-per-traversal model).
                                Under a mesh the whole alignment's, a
                                sum over chips: a chip's part is that
                                over the gauge engine.mesh_site_shards
   engine.achieved_gbps.<tier>.<engine-tag>   windowed achieved GB/s
-                               gauge per tier (scan/chunk/pallas/
-                               whole) and engine, from the timed
+                               gauge per tier (scan/chunk/universal/
+                               grad) and engine, from the timed
                                blocking dispatch path
   engine.regime_dispatch_bound.<tier>.<engine-tag>   1.0 = the
                                window's wall time sits at the
@@ -126,7 +126,7 @@ class TimerStat:
              "self_s": self.self_total,
              "min_s": self.min, "max_s": self.max}
         # Quantiles + the raw sparse buckets: the buckets are what lets
-        # two snapshots MERGE exactly (bench worker accumulation,
+        # two snapshots MERGE exactly (bank worker accumulation,
         # supervisor attempt merging) — merged quantiles recompute from
         # summed buckets, never from quantiles.
         d.update(self.hist.quantiles())

@@ -70,8 +70,6 @@ TIER_FAMILIES: Dict[str, Tuple[str, ...]] = {
     "scan": ("trav_eval", "traverse", "newton", "scan", "thscan",
              "sumtable", "derivs"),
     "chunk": ("fast",),
-    "pallas": ("fast",),
-    "whole": ("whole", "fast"),
     "universal": ("universal",),
     "grad": ("grad",),
 }

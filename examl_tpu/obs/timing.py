@@ -1,7 +1,6 @@
-"""The one definition of "dispatch time" shared by bench.py and the perf
-lab: warm-up calls, then best-of-N wall seconds around a blocking call,
-every repetition observed into the metrics registry so lab and bench
-numbers are the same measurement with different report formats.
+"""One definition of "dispatch time": warm-up calls, then best-of-N wall
+seconds around a blocking call, every repetition observed into the
+metrics registry.
 
 The callable must itself block until the device work is done (wrap the
 dispatch in `jax.block_until_ready`); this module stays jax-free so the
@@ -24,7 +23,7 @@ def time_dispatch(call: Callable[[], object], *, reps: int = 1,
     registry timer `name` — with the timer's log-bucketed histogram
     that means the full rep distribution survives, not just the
     best-of-N headline — and the window's parameters land as one
-    `dispatch.window` ledger event (reps/warmup/best/total) so a bench
+    `dispatch.window` ledger event (reps/warmup/best/total) so a
     measurement is auditable from the run artifacts alone."""
     reg = _metrics.registry()
     for _ in range(warmup):

@@ -274,7 +274,7 @@ def _maybe_env_enable() -> bool:
 
 
 def instant(name: str, args: Optional[dict] = None) -> None:
-    """A zero-duration marker event (Pallas fallback, watchdog bark)."""
+    """A zero-duration marker event (a watchdog bark)."""
     if _writer is None and not _maybe_env_enable():
         return
     ev = {"ph": "i", "s": "p", "name": name, "cat": "event",

@@ -1,16 +1,11 @@
 """The ONE bytes-per-traversal HBM-traffic model + regime classifier.
 
-Roofline accounting (ROOFLINE.md) lived only in `bench.py`
-(`_bytes_per_traversal`), so a CLI or supervised run could never state
-its own achieved GB/s against the 306 GB/s target — and a bench row's
-number could silently drift from any in-engine estimate.  This module
-is the single shared definition: bench.py delegates here verbatim and
-`ops/engine.py` uses the same model for its per-dispatch
-`engine.traffic_bytes` counter and windowed `engine.achieved_gbps.<tier>`
-gauges, so the two agree bit-for-bit by construction
-(tests/test_flightrec.py pins it).
+The closed form of the roofline accounting (ROOFLINE.md): `ops/engine.py`
+uses it for its per-dispatch `engine.traffic_bytes` counter and windowed
+`engine.achieved_gbps.<tier>` gauges (tests/test_flightrec.py pins it
+against a hand count).
 
-Model (unchanged from the r05 bench): per traversal entry one CLV row
+Model: per traversal entry one CLV row
 written, each non-tip child's CLV row read, scaler rows alongside
 (int32/lane), tip children read 1-byte code rows; P matrices / tip
 tables are O(states^2) noise.
@@ -22,8 +17,8 @@ measurement (r02's 23 GB/s on testData/140 was exactly this).  Every
 achieved_gbps this runtime reports carries the verdict so a chip round
 can never mistake a floor for a roofline.
 
-stdlib+numpy only — the bench parent and report tools import this with
-no backend on the path.
+stdlib+numpy only — report tools import this with no backend on the
+path.
 """
 
 from __future__ import annotations
@@ -88,7 +83,7 @@ def bytes_per_traversal_counts(n_entries: int, n_tip_children: int,
 
 def count_tip_children(entries, ntips: int) -> int:
     """Tip children of a TraversalEntry list (node numbers 1..ntips are
-    tips — the `ch <= ntips` test bench.py has always used)."""
+    tips)."""
     n = 0
     for e in entries:
         for ch in (e.left, e.right):
@@ -121,8 +116,7 @@ def bytes_per_grad_pass(n_entries: int, n_tip_children: int,
 
 def bytes_per_traversal(entries, ntips: int, patterns: int, R: int,
                         K: int, itemsize: int) -> int:
-    """Entry-list form — the exact historical bench.py signature, now a
-    thin wrapper over the shared closed form."""
+    """Entry-list form: a thin wrapper over the closed form."""
     return bytes_per_traversal_counts(
         len(entries), count_tip_children(entries, ntips), patterns, R,
         K, itemsize)

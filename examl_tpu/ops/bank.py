@@ -14,8 +14,8 @@ critical path.  Banking makes the watchdog's advice *action*:
   program families the run will dispatch — the same labels
   `_guard_first_call` stamps on compile spans/counters (`traverse`,
   `trav_eval`, `evaluate`, `newton`, `sumtable`, `derivs`, the `fast`
-  chunk tier, the Pallas `whole` tier, the batched-SPR `scan`/`thscan`
-  programs, PSR's `rate_scan`).
+  chunk tier, the batched-SPR `scan`/`thscan` programs, PSR's
+  `rate_scan`).
 * `run_bank()` compiles them in PARALLEL KILLABLE SUBPROCESS workers
   against the persistent compilation cache (keyed by a host-feature
   fingerprint, `config.enable_persistent_compilation_cache`), with a
@@ -23,14 +23,13 @@ critical path.  Banking makes the watchdog's advice *action*:
   `--compile-timeout` gets its worker killed, is recorded as degraded,
   and the run falls back to the scan-tier program (the one family
   hardware-proven on every backend) via the existing escape-hatch envs
-  (`EXAML_FAST_TRAVERSAL=0`, `EXAML_PALLAS=0`, `EXAML_BATCH_SCAN=0`).
+  (`EXAML_FAST_TRAVERSAL=0`, `EXAML_BATCH_SCAN=0`).
 * `warm_instance()` then first-calls every banked family in the MAIN
   process inside the CLI's bank phase — now disk-cache hits — so the
   search phase performs ZERO first-call compiles and a wedge-prone
   compile can never run unmonitored on the hot path.
 * the per-host **bank manifest** (stored next to the persistent cache
-  entries) records banked/degraded verdicts; `bench.py` workers consult
-  it so bench stages never dispatch a family that wedged this host.
+  entries) records banked/degraded verdicts.
 
 Multi-host runs bank per process before the collective barrier
 (`parallel/launch.bank_barrier`): each host's cache is local disk, so
@@ -40,7 +39,7 @@ mesh-sharded program variants may still compile at first dispatch in
 the main process — those compiles remain watchdogged and their families
 still carry the bank's degradation verdicts.
 
-Worker protocol (mirrors bench.py's staged workers): one `##start
+Worker protocol: one `##start
 <family>` marker line per family, then one JSON result line
 (`{"family", "seconds", "ok"}`) or a `##skip <family> <reason>` line;
 a final `{"family": "__metrics__", ...}` line ships the worker's obs
@@ -79,9 +78,6 @@ FALLBACK_ENV = {
     "universal": (("EXAML_UNIVERSAL", "0"),
                   "universal interpreter disabled (specialized chunk "
                   "programs or scan tier)"),
-    "whole": (("EXAML_PALLAS", "0"),
-              "whole-traversal Pallas kernel not used: the default XLA "
-              "chunk tier (or scan tier)"),
     "grad": (("EXAML_GRAD_SMOOTH", "0"),
              "whole-tree gradient smoothing disabled (per-branch "
              "Newton path)"),
@@ -206,9 +202,9 @@ def enumerate_families(mode: str = "d", psr: bool = False,
     fams = list(CORE_FAMILIES)
     if e.get("EXAML_FAST_TRAVERSAL") != "0" and not psr and not save_memory:
         # The universal interpreter banks BEFORE the specialized chunk
-        # family (degradation order pallas -> chunk -> universal ->
-        # scan: the fallback target must be warm before anything that
-        # can degrade onto it).  Its family set is tiny and CLOSED —
+        # family (degradation order chunk -> universal -> scan: the
+        # fallback target must be warm before anything that can
+        # degrade onto it).  Its family set is tiny and CLOSED —
         # one program per (alphabet, table bucket, slot bucket,
         # with_eval), none per topology — which is what converts the
         # bank from "pre-compile everything you might meet" to
@@ -216,8 +212,6 @@ def enumerate_families(mode: str = "d", psr: bool = False,
         if e.get("EXAML_UNIVERSAL") != "0":
             fams.append("universal")
         fams.append("fast")
-        if e.get("EXAML_PALLAS") == "whole":
-            fams.append("whole")
     if not save_memory and e.get("EXAML_GRAD_SMOOTH") != "0":
         # Whole-tree gradient smoothing (ops/gradient.py): one program
         # per bucketed (steps, width, chunks) shape — like the scan
@@ -233,14 +227,14 @@ def enumerate_families(mode: str = "d", psr: bool = False,
 
 
 def chunk_layout_info() -> dict:
-    """The bounded-chunk-layout constants in effect — recorded in the
-    bank manifest so a cache whose layout knobs differ from the current
-    run's is visibly stale (the knobs change the profile alphabet and
-    therefore every `fast`-family program shape)."""
+    """The chunk-layout constants in effect — recorded in the bank
+    manifest so a cache written under other constants is visibly stale
+    (they set the profile alphabet and therefore every `fast`-family
+    program shape)."""
     from examl_tpu.ops import fastpath, universal
-    mw, cap, tail = fastpath._knobs()
-    info = {"bounded": fastpath.bounded_default(), "min_width": mw,
-            "chunk_cap": cap, "tail_width": tail}
+    mw, cap = fastpath.MIN_WIDTH, fastpath.CHUNK_CAP
+    info = {"min_width": mw, "chunk_cap": cap,
+            "tail_width": fastpath.TAIL_WIDTH}
     # Universal-interpreter coverage: whether the zero-recompile tier
     # is on and how big its closed class alphabet is — a manifest
     # reader can tell at a glance that this cache serves ANY topology
@@ -297,19 +291,12 @@ def _applicability(inst, family: str) -> Optional[str]:
             return "fast path disabled (EXAML_FAST_TRAVERSAL=0)"
         return None
     if family == "universal":
-        from examl_tpu.ops import fastpath
         if inst.psr or inst.save_memory:
             return "universal interpreter is GAMMA/dense-only"
         if all(e.force_scan or e.fast_slack == 0 for e in engines):
             return "fast path disabled (EXAML_FAST_TRAVERSAL=0)"
         if all(getattr(e, "universal_off", True) for e in engines):
             return "universal interpreter disabled (EXAML_UNIVERSAL=0)"
-        if not fastpath.bounded_default():
-            return "legacy unbounded layout (EXAML_BOUNDED_CHUNKS=0)"
-        return None
-    if family == "whole":
-        if not any(e.pallas_whole for e in engines):
-            return "whole-traversal kernel needs EXAML_PALLAS=whole on TPU"
         return None
     if family == "grad":
         if inst.save_memory:
@@ -355,6 +342,11 @@ def warm_family(inst, tree, family: str) -> None:
                     e.force_scan = p
         return cm()
 
+    def run_flat():
+        """The full traversal in the form a run hands the engine."""
+        inst.run_traversal(
+            tree.flat_full_traversal(tree.centroid_branch()), full=True)
+
     def inner_node():
         for n in tree.inner_numbers():
             nd = tree.nodep[n]
@@ -364,11 +356,7 @@ def warm_family(inst, tree, family: str) -> None:
 
     if family == "traverse":
         with scan_tier():
-            tree.invalidate_all()
-            p = tree.centroid_branch()
-            entries = (inst._collect(tree, p, True)
-                       + inst._collect(tree, p.back, True))
-            inst.run_traversal(entries, full=True)
+            run_flat()
             inst.new_view(tree, inner_node())      # small-L partial bucket
         return
     if family == "trav_eval":
@@ -397,16 +385,10 @@ def warm_family(inst, tree, family: str) -> None:
                 st = eng.make_sumtable(p.number, p.back.number)
                 eng.branch_derivatives(st, p.z)
         return
-    if family in ("fast", "whole"):
-        # The engine's full-traversal tier (XLA chunks by default,
-        # Pallas chunks when EXAML_PALLAS=1 on a TPU, `whole` when
-        # EXAML_PALLAS=whole): both
-        # the traverse-only and fused traverse+evaluate variants.
-        tree.invalidate_all()
-        p = tree.centroid_branch()
-        entries = (inst._collect(tree, p, True)
-                   + inst._collect(tree, p.back, True))
-        inst.run_traversal(entries, full=True)
+    if family == "fast":
+        # The chunk tier's two programs: traverse-only and fused
+        # traverse+evaluate.
+        run_flat()
         inst.evaluate(tree, full=True)
         return
     if family == "universal":
@@ -419,11 +401,7 @@ def warm_family(inst, tree, family: str) -> None:
         for e in engines:
             e.universal_force = True
         try:
-            tree.invalidate_all()
-            p = tree.centroid_branch()
-            entries = (inst._collect(tree, p, True)
-                       + inst._collect(tree, p.back, True))
-            inst.run_traversal(entries, full=True)
+            run_flat()
             inst.evaluate(tree, full=True)
         finally:
             for e, v in zip(engines, prior):
@@ -848,8 +826,7 @@ def run_bank(args, log=lambda msg: None, timeout: Optional[float] = None,
                     # SIGSEGV/OOM-kill): that family alone carries the
                     # verdict; the never-attempted rest requeues into a
                     # fresh worker — branding untried families as
-                    # wedged would gate healthy bench stages for no
-                    # reason.
+                    # wedged would degrade healthy ones for no reason.
                     report[died] = {"status": "error",
                                     "error": "worker died mid-compile "
                                              + _exit_desc(rc)}
@@ -1018,9 +995,8 @@ def _save_manifest(cache_path: Optional[str], report: Dict[str, dict],
                    log) -> None:
     """Write this run's verdicts, MERGED over the existing manifest: a
     config that does not enumerate some family (e.g. a PSR run, which
-    has no 'fast') must not erase a prior run's wedge verdict for it —
-    bench gating depends on those surviving until a bank re-proves the
-    family healthy."""
+    has no 'fast') must not erase a prior run's wedge verdict for it:
+    it stands until a bank re-proves the family healthy."""
     if not cache_path:
         return
     path = os.path.join(cache_path, MANIFEST_NAME)
@@ -1080,8 +1056,8 @@ def load_manifest(cache_path: Optional[str] = None) -> Optional[dict]:
 
 def manifest_degraded_families(manifest: Optional[dict]) -> set:
     """Families a previous bank on this host recorded as WEDGED
-    (deadline kill or death-by-signal, `_is_wedge`) — dispatchers
-    (bench.py stages) must route around them.  Plain environment errors
+    (deadline kill or death-by-signal, `_is_wedge`) — what a
+    dispatcher should route around.  Plain environment errors
     do not gate: they say nothing about the program."""
     if not manifest:
         return set()

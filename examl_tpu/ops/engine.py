@@ -91,6 +91,8 @@ class LikelihoodEngine:
                  dtype=jnp.float64, sharding=None,
                  scale_exp: Optional[int] = None, wave_width: int = 8,
                  psr: bool = False, save_memory: bool = False):
+        from examl_tpu.config import refuse_removed_switches
+        refuse_removed_switches()
         self.bucket = bucket
         self.ntips = ntips
         self.psr = psr
@@ -114,7 +116,7 @@ class LikelihoodEngine:
         # EXAML_FAST_TRAVERSAL=0 forces the wave-batched scan tier for
         # full traversals too (escape hatch: the chunk pipeline is the
         # faster program, but the scan program is the one whose compile
-        # is proven on every backend; see bench.py stage isolation).
+        # is proven on every backend).
         # Runtime-togglable via `force_scan` (the arena keeps its slack).
         import os as _fos
         self.force_scan = _fos.environ.get("EXAML_FAST_TRAVERSAL",
@@ -219,16 +221,6 @@ class LikelihoodEngine:
         self._grad_structs = OrderedDict()
         self._grad_structs_cap = 8
         self.sharding = sharding
-        self.pallas_interpret = _pos.environ.get(
-            "EXAML_PALLAS_INTERPRET", "") == "1"
-        # EXAML_PALLAS: unset/0 = the XLA chunk tier (default on every
-        # platform), 1 = per-chunk kernels, whole = one kernel per full
-        # traversal (ops/pallas_whole.py).  The kernels are something a
-        # run asks for: a Mosaic refusal then propagates as an error.
-        self._pallas_env = _pos.environ.get("EXAML_PALLAS", "0")
-        self._want_pallas = self._pallas_env != "0"
-        self.use_pallas = False        # decided once tensors are placed
-        self.pallas_whole = False
 
         lane = bucket.lane
         B = bucket.num_blocks              # GLOBAL (jit program shapes)
@@ -348,27 +340,6 @@ class LikelihoodEngine:
                 self.storage_dtype, lambda s: s.clv)
         self.scaler = self._zeros_sharded((self.num_rows, B, lane),
                                           jnp.int32, lambda s: s.scaler)
-        # Fused Pallas chunk kernels, only when the run asks for them
-        # (EXAML_PALLAS=1|whole) and gated on where the CLV arena actually
-        # LIVES: lowering Mosaic kernels onto CPU devices crashes, so the
-        # platform comes from the placed tensor, not the default backend.
-        # The plain-XLA chunk tier is the default everywhere.
-        # EXAML_PALLAS_INTERPRET=1 forces interpreted kernels on CPU
-        # (tests only); on a TPU placement it is refused outright — an
-        # interpreted kernel there would be a silent slow path.
-        if self.clv is not None:
-            platform = next(iter(self.clv.devices())).platform
-            if self.pallas_interpret and platform == "tpu":
-                raise RuntimeError(
-                    "EXAML_PALLAS_INTERPRET=1 is a CPU test switch; it "
-                    "cannot be used with tensors placed on a TPU")
-            self.use_pallas = (
-                self._want_pallas and self.dtype == jnp.float32
-                and self.storage_dtype == self.dtype
-                and sharding is None
-                and (self.pallas_interpret or platform == "tpu"))
-            self.pallas_whole = (self.use_pallas
-                                 and self._pallas_env == "whole")
 
         # One jitted traversal program; jax recompiles per padded entry-count
         # shape (powers of two, so only a handful of variants exist).  The
@@ -410,7 +381,8 @@ class LikelihoodEngine:
             "prog-v1", self.K, str(self.dtype), str(self.storage_dtype),
             int(self.scale_exp), str(self.fast_precision),
             self.num_parts, self.num_branch_slots, self.ntips,
-            bool(self.psr), _fastpath._knobs(), self.wave_width,
+            bool(self.psr), (_fastpath.MIN_WIDTH, _fastpath.CHUNK_CAP,
+                             _fastpath.TAIL_WIDTH), self.wave_width,
             mesh_term)
         self._exportable = (self.sharding is None and not save_memory
                             and self.clv is not None
@@ -461,9 +433,8 @@ class LikelihoodEngine:
         import weakref
 
         obs.inc("engine.instances")
-        # Unique per engine: two same-state engines (bench builds several
-        # K=4 instances in one process) must not alias each other's
-        # gauges — the ordinal disambiguates.
+        # Unique per engine: two same-state engines in one process must
+        # not alias each other's gauges — the ordinal disambiguates.
         seq = LikelihoodEngine._obs_seq
         LikelihoodEngine._obs_seq += 1
         self._obs_tag = f"s{self.K}.e{seq}"
@@ -511,35 +482,25 @@ class LikelihoodEngine:
 
     def _dispatch_tier(self, fast: bool) -> str:
         """Tier label for the traffic gauges: which program family moved
-        the bytes (scan = the wave-batched fallback; chunk = XLA fast
-        path; pallas / whole = the Mosaic tiers; universal = the
-        topology-as-data interpreter)."""
+        the bytes (scan = the wave-batched fallback; chunk = the fast
+        path; universal = the topology-as-data interpreter)."""
         if not fast:
             return "scan"
-        if self._last_universal:
-            return "universal"
-        if self.pallas_whole:
-            return "whole"
-        if self.use_pallas:
-            return "pallas"
-        return "chunk"
+        return "universal" if self._last_universal else "chunk"
 
     def _tier_for(self, entries, full: bool) -> str:
         """Tier a traversal over `entries` will actually dispatch on
-        (full + fast-eligible -> the engine's fast tier; everything
-        else — partial, PSR, -S, force_scan — runs the scan tier)."""
-        if full and len(entries):
-            if isinstance(entries, FlatTraversal):
-                fast = self._fast_eligible_flat(entries)
-            else:
-                fast = self._fast_eligible(entries)
-            return self._dispatch_tier(fast)
-        return "scan"
+        (a full, fast-eligible FlatTraversal -> the fast tier;
+        everything else — an entry list, partial, PSR, -S, force_scan
+        — runs the scan tier)."""
+        return self._dispatch_tier(
+            full and isinstance(entries, FlatTraversal)
+            and self._fast_eligible_flat(entries))
 
     def _traversal_traffic_bytes(self, entries) -> int:
         """Modeled HBM bytes of one traversal over `entries` (a
-        TraversalEntry list or a FlatTraversal) — the SAME closed form
-        bench.py's byte accounting delegates to."""
+        TraversalEntry list or a FlatTraversal): obs/traffic.py's
+        closed form."""
         itemsize = np.dtype(self.storage_dtype).itemsize
         if isinstance(entries, FlatTraversal):
             tips = int((np.asarray(entries.left) <= self.ntips).sum()
@@ -595,7 +556,7 @@ class LikelihoodEngine:
         src = _programs.model_vs_xla(tier, nbytes)
         if wall_s is None:
             return
-        # The `dispatch` timer the ISSUE/bench share: wall of one
+        # The `dispatch` timer: wall of one
         # BLOCKING traversal dispatch — its p99 is where a launch-floor
         # stall or surprise recompile shows up in any CLI snapshot.
         obs.observe("dispatch", wall_s)
@@ -928,10 +889,10 @@ class LikelihoodEngine:
 
     def run_traversal(self, entries: List[TraversalEntry],
                       full: bool = False) -> None:
-        """Recompute CLVs for `entries` — a TraversalEntry list, or (for
-        full traversals) a `FlatTraversal`, which takes the cached-
-        structure fast path and falls back to the legacy list form for
-        the scan/PSR/SEV tiers."""
+        """Recompute CLVs for `entries`: a full, fast-eligible
+        `FlatTraversal` takes the cached-structure fast path; anything
+        else (a TraversalEntry list, a partial traversal, PSR, -S) the
+        scan tier."""
         if not len(entries):
             return
         obs.inc("engine.dispatch_count")
@@ -950,9 +911,6 @@ class LikelihoodEngine:
                     self._run_fast_flat(flat)
                     return
                 entries = flat.to_entries()
-            if full and self._fast_eligible(entries):
-                self._run_fast_traversal(entries)
-                return
             if self.save_memory:
                 self._sev_begin(entries)
             tv = self._traversal_arrays(entries)
@@ -1123,7 +1081,7 @@ class LikelihoodEngine:
     @staticmethod
     def _cache_family(key) -> str:
         """Program family of a shared-cache key: external builders prefix
-        their keys with a string tag ("scan"/"thscan"/"whole"/...); the
+        their keys with a string tag ("scan"/"thscan"/...); the
         engine's own chunk-profile keys are the "fast" family."""
         if isinstance(key, tuple) and key and isinstance(key[0], str):
             return key[0]
@@ -1176,28 +1134,6 @@ class LikelihoodEngine:
             obs.inc("engine.cache_evictions")
         return fn
 
-    def _run_fast_traversal(self, entries: List[TraversalEntry]) -> None:
-        from examl_tpu.ops import universal
-        if self.pallas_whole and not self.universal_force:
-            self._run_whole(entries)
-            return
-        sched = self._fast_schedule(entries)
-        self._last_universal = False
-        if self._universal_take(sched.profile, with_eval=False):
-            try:
-                self._run_universal_sched(sched)
-                return
-            except universal.UniversalIneligible:
-                obs.inc("engine.universal_ineligible")
-        self._note_fast_program(sched.profile)
-        fn = self._fast_fn_flat(sched.profile, with_eval=False)
-        with self._phase("launch"):
-            self.clv, self.scaler = fn(
-                self.clv, self.scaler, sched.base, sched.lidx, sched.ridx,
-                sched.lcode, sched.rcode, sched.zl, sched.zr, self.models,
-                self.block_part, self.tips)
-        self._install_row_map(sched)
-
     # -- engine state: dense CLV buffer or SEV pool -------------------------
     # Every device program takes (buf, scaler, aux): dense aux = (),
     # SEV aux = (slot_read, slot_write).  buf and scaler are donated; aux
@@ -1243,32 +1179,8 @@ class LikelihoodEngine:
 
     # -- fast full-traversal path (ops/fastpath.py) ------------------------
 
-    def _fast_eligible(self, entries: List[TraversalEntry]) -> bool:
-        """The fast path relayouts the whole arena, so it requires a
-        traversal covering every inner node (full=True callers after
-        invalidate_all) and the GAMMA kernels (PSR keeps the scan path)."""
-        return (not self.psr and not self.force_scan
-                and self.fast_slack > 0
-                and len(entries) == self.n_inner)
-
-    def _fast_schedule(self, entries: List[TraversalEntry]):
-        from examl_tpu.ops import fastpath
-        with self._phase("schedule"):
-            sched = fastpath.build_schedule(entries, self.ntips,
-                                            self.num_branch_slots,
-                                            self.dtype)
-        assert sched.max_write <= self.num_rows - 1, \
-            (sched.max_write, self.num_rows)
-        return sched
-
-    def _install_row_map(self, sched) -> None:
-        ro = sched.row_of
-        if isinstance(ro, dict):
-            self.row_map[:] = -1
-            for num, row in ro.items():
-                self.row_map[num] = row
-        else:                       # FastStructure: vectorized array copy
-            self.row_map[:ro.shape[0]] = ro
+    def _install_row_map(self, st) -> None:
+        self.row_map[:st.row_of.shape[0]] = st.row_of
 
     # -- cached schedule structures (flat fast path) -------------------------
 
@@ -1306,6 +1218,9 @@ class LikelihoodEngine:
         return st
 
     def _fast_eligible_flat(self, flat) -> bool:
+        """The fast path relayouts the whole arena, so it requires a
+        traversal covering every inner node (full=True callers after
+        invalidate_all) and the GAMMA kernels (PSR keeps the scan path)."""
         return (not self.psr and not self.force_scan
                 and self.fast_slack > 0 and flat.n == self.n_inner)
 
@@ -1313,10 +1228,10 @@ class LikelihoodEngine:
         """Publish the bounded chunk program's size gauges: unrolled
         blocks after coalescing, scan groups, and the per-traversal
         operation count (the launch-latency floor the bounded layout
-        exists to shrink) — landing in `--metrics` snapshots and BENCH
-        rows.  Tagged per engine like the other engine gauges
-        (_register_obs): two engines (DNA+AA instance, bench's several
-        K=4 instances) must not overwrite each other's program size."""
+        exists to shrink) — landing in `--metrics` snapshots.  Tagged
+        per engine like the other engine gauges (_register_obs): two
+        engines (a DNA+AA instance) must not overwrite each other's
+        program size."""
         from examl_tpu.ops import fastpath
         un, sc, total = fastpath.profile_stats(profile)
         tag = "." + self._obs_tag
@@ -1335,9 +1250,8 @@ class LikelihoodEngine:
         per-chunk widths) — two topologies of similar shape mint the
         same key and share one compiled program, which is the point of
         width bucketing (tests/test_fastpath.py asserts the cache-hit
-        counters).  Key leads with "fast" — same program family as
-        before for the bank/watchdog accounting; the legacy entry-list
-        path dispatches through this same cache entry."""
+        counters).  Key leads with "fast": the program family of the
+        bank/watchdog accounting."""
         key = ("fast", profile, "flat", with_eval)
         fn = self.cache_get(key)
         if fn is not None:
@@ -1371,8 +1285,6 @@ class LikelihoodEngine:
         this profile exists — same layout, same chunk arithmetic, but a
         topology-independent jit key."""
         from examl_tpu.ops import fastpath, universal
-        if self.pallas_whole and not self.universal_force:
-            return self._run_whole(flat.to_entries(), p_num, q_num, z)
         with self._phase("schedule"):
             st = self._fast_structure(flat)
         self._last_universal = False
@@ -1512,25 +1424,6 @@ class LikelihoodEngine:
         return self._universal_dispatch(st, desc, idx, zl, zr, npad,
                                         ppad, p_num, q_num, z)
 
-    def _run_universal_sched(self, sched, p_num=None, q_num=None,
-                             z=None):
-        """Interpreter dispatch from a legacy entry-list FastSchedule
-        (bank warming, entry-list callers): same program, host arrays
-        padded on the fly (no topology signature to cache under)."""
-        from examl_tpu.ops import universal
-        with_eval = p_num is not None
-        base_h, li, ri, lc, rc, zl_h, zr_h = sched._host
-        with self._phase("schedule"):
-            ent = self._universal_entry(sched.profile, base_h,
-                                        (li, ri, lc, rc))
-            npad, ppad, desc, idx = self._universal_args(ent, with_eval)
-            zl = jnp.asarray(universal.pad_slots(zl_h, ppad, fill=1),
-                             self.dtype)
-            zr = jnp.asarray(universal.pad_slots(zr_h, ppad, fill=1),
-                             self.dtype)
-        return self._universal_dispatch(sched, desc, idx, zl, zr, npad,
-                                        ppad, p_num, q_num, z)
-
     def _universal_dispatch(self, sched, desc, idx, zl, zr, npad: int,
                             ppad: int, p_num, q_num, z):
         """Ship the padded table + packed layout as data through the
@@ -1570,11 +1463,8 @@ class LikelihoodEngine:
     def _universal_fn(self, npad: int, ppad: int, with_eval: bool):
         """The ONE jitted interpreter program per (alphabet, buckets,
         with_eval) — the `("universal", ...)` cache family, with its
-        own compile-watchdog label via `_cache_family`.  Always the
-        plain-XLA chunk kernel: the interpreter is the portability rung
-        below the chunk tier (pallas -> chunk -> universal -> scan),
-        and a Mosaic kernel in every switch branch would multiply the
-        compile surface of the tier whose point is compiling once."""
+        own compile-watchdog label via `_cache_family`: the portability
+        rung below the chunk tier (chunk -> universal -> scan)."""
         from examl_tpu.ops import fastpath, universal
         akey = self._universal_akey()
         key = ("universal", akey, npad, ppad, with_eval)
@@ -1605,142 +1495,17 @@ class LikelihoodEngine:
         return self.cache_put(key, jax.jit(
             impl_eval if with_eval else run, donate_argnums=(0, 1)))
 
-    @property
-    def pallas_precision(self):
-        """Precision handed to the Pallas tiers: Mosaic lowers only
-        DEFAULT and HIGHEST ("Unsupported dot precision: HIGH" on real
-        v5e hardware), so the engine's HIGH default — a 3-pass-bf16
-        XLA-path optimization — maps to HIGHEST inside kernels, where
-        operands already sit in VMEM and extra passes cost no HBM.
-        Harnesses that pass an explicit HIGH to the pallas modules still
-        fail loudly (perf_lab precision sweeps must not mislabel rows)."""
-        if self.fast_precision == jax.lax.Precision.HIGH:
-            return jax.lax.Precision.HIGHEST
-        return self.fast_precision
-
-    def _chunk_applier(self, dm, block_part, tips):
-        """The per-chunk kernel on the engine-selected backend path
-        (fused Pallas on TPU, plain XLA elsewhere) — shared by the
-        unrolled reference executor and the bounded segment program."""
-        if self.use_pallas:
-            from examl_tpu.ops import pallas_newview
-            return pallas_newview.chunk_applier(
-                dm, block_part, tips, self.scale_exp,
-                precision=self.pallas_precision,
-                interpret=self.pallas_interpret)
-        from examl_tpu.ops import fastpath
-        return fastpath.chunk_applier(dm, block_part, tips,
-                                      self.scale_exp,
-                                      self.fast_precision)
-
-    def _run_chunks_impl(self, dm, block_part, tips, clv, scaler, chunks):
-        """Unrolled chunk-list execution (traced); the reference
-        strategy external harnesses time (bench.py, perf lab)."""
-        apply = self._chunk_applier(dm, block_part, tips)
-        for ch in chunks:
-            clv, scaler = apply(clv, scaler, ch)
-        return clv, scaler
-
     def _run_segments_impl(self, dm, block_part, tips, clv, scaler,
                            profile, base, lidx, ridx, lcode, rcode, zl,
                            zr):
         """Bounded-program execution over the packed 7-leaf layout
         (fastpath.run_segments): O(#segments) program ops — unrolled
-        hot chunks plus lax.scan long-tail groups — on the
-        engine-selected backend path."""
+        hot chunks plus lax.scan long-tail groups."""
         from examl_tpu.ops import fastpath
-        apply = self._chunk_applier(dm, block_part, tips)
+        apply = fastpath.chunk_applier(dm, block_part, tips,
+                                       self.scale_exp, self.fast_precision)
         return fastpath.run_segments(profile, base, lidx, ridx, lcode,
                                      rcode, zl, zr, clv, scaler, apply)
-
-    def run_chunks_traced(self, clv, scaler, chunks):
-        """Traceable chunk execution for harnesses that build their own
-        jit around the fast path (bench.py, perf lab)."""
-        return self._run_chunks_impl(self.models, self.block_part,
-                                     self.tips, clv, scaler, chunks)
-
-    def run_segments_traced(self, clv, scaler, sched):
-        """Traceable bounded-program execution from a FastSchedule (the
-        program the engine actually dispatches per full traversal) for
-        external harnesses (bench.py chunk tier)."""
-        return self._run_segments_impl(
-            self.models, self.block_part, self.tips, clv, scaler,
-            sched.profile, sched.base, sched.lidx, sched.ridx,
-            sched.lcode, sched.rcode, sched.zl, sched.zr)
-
-    # -- whole-traversal Pallas path (ops/pallas_whole.py) ------------------
-
-    def _whole_fn(self, E: int, with_eval: bool):
-        key = ("whole", E, with_eval)
-        fn = self.cache_get(key)
-        if fn is not None:
-            return fn
-        from examl_tpu.ops import pallas_whole
-
-        def run(clv, scaler, meta, lc, rc, zl, zr, dm, bp, tips):
-            return pallas_whole.run_flat_arrays(
-                dm, bp, tips, clv, scaler, E, meta, lc, rc, zl, zr,
-                self.scale_exp, self.pallas_precision,
-                self.pallas_interpret)
-
-        def impl_eval(clv, scaler, meta, lc, rc, zl, zr, p_idx, q_idx,
-                      zv, dm, bp, weights, tips):
-            clv, scaler = run(clv, scaler, meta, lc, rc, zl, zr, dm, bp,
-                              tips)
-            lnl = kernels.root_log_likelihood(
-                dm, bp, weights, tips, clv, scaler, p_idx, q_idx, zv,
-                self.num_parts, self.scale_exp, self.ntips, None)
-            return clv, scaler, lnl
-
-        return self.cache_put(key, jax.jit(impl_eval if with_eval else run,
-                                           donate_argnums=(0, 1)))
-
-    def _whole_args(self, entries):
-        from examl_tpu.ops import pallas_whole
-        with self._phase("schedule"):
-            sched = pallas_whole.build_flat(entries, self.ntips,
-                                            self.num_branch_slots)
-        with self._phase("stage"):
-            return sched, (self._stage(sched.meta),
-                           self._stage(sched.l_code),
-                           self._stage(sched.r_code),
-                           self._stage(sched.zl, self.dtype),
-                           self._stage(sched.zr, self.dtype))
-
-    def _run_whole(self, entries, p_num=None, q_num=None, z=None):
-        # One fused Mosaic program = one sequential device op: the
-        # whole tier's launch floor for the regime classifier (a stale
-        # scan-tier wave count here would wrongly stamp a whole-tier
-        # bandwidth number dispatch-bound).
-        self._last_dispatch_ops = 1
-        self._last_universal = False
-        sched, args = self._whole_args(entries)
-        if p_num is None:
-            fn = self._whole_fn(sched.e_real, with_eval=False)
-            with self._phase("launch"):
-                self.clv, self.scaler = fn(self.clv, self.scaler, *args,
-                                           self.models, self.block_part,
-                                           self.tips)
-            self._install_row_map(sched)
-            return None
-        fn = self._whole_fn(sched.e_real, with_eval=True)
-        with self._phase("stage"):
-            root = self._stage_root(sched, p_num, q_num, z)
-        with self._phase("launch"):
-            self.clv, self.scaler, out = fn(
-                self.clv, self.scaler, *args, *root, self.models,
-                self.block_part, self.weights, self.tips)
-        self._install_row_map(sched)
-        with self._phase("wait"):
-            return np.asarray(out)
-
-    def run_whole_traced(self, clv, scaler, sched):
-        """Traceable whole-traversal execution for external harnesses
-        (bench.py): schedule built once on host, kernel traced inline."""
-        from examl_tpu.ops import pallas_whole
-        return pallas_whole.run_flat(
-            self.models, self.block_part, self.tips, clv, scaler, sched,
-            self.scale_exp, self.pallas_precision, self.pallas_interpret)
 
     # -- batched SPR radius scan (search/batchscan.py) ----------------------
 
@@ -1995,8 +1760,6 @@ class LikelihoodEngine:
             if full and flat.n and self._fast_eligible_flat(flat):
                 return self._run_fast_flat(flat, p_num, q_num, z)
             entries = flat.to_entries()
-        if full and entries and self._fast_eligible(entries):
-            return self._trav_eval_fast(entries, p_num, q_num, z)
         if self.save_memory:
             self._sev_begin(entries)
         tv = self._traversal_arrays(entries)
@@ -2011,36 +1774,11 @@ class LikelihoodEngine:
         with self._phase("wait"):
             return np.asarray(out)
 
-    def _trav_eval_fast(self, entries, p_num, q_num, z) -> np.ndarray:
-        from examl_tpu.ops import universal
-        if self.pallas_whole and not self.universal_force:
-            return self._run_whole(entries, p_num, q_num, z)
-        sched = self._fast_schedule(entries)
-        self._last_universal = False
-        if self._universal_take(sched.profile, with_eval=True):
-            try:
-                return self._run_universal_sched(sched, p_num, q_num, z)
-            except universal.UniversalIneligible:
-                obs.inc("engine.universal_ineligible")
-        self._note_fast_program(sched.profile)
-        fn = self._fast_fn_flat(sched.profile, with_eval=True)
-        with self._phase("stage"):
-            root = self._stage_root(sched, p_num, q_num, z)
-        with self._phase("launch"):
-            self.clv, self.scaler, out = fn(
-                self.clv, self.scaler, sched.base, sched.lidx, sched.ridx,
-                sched.lcode, sched.rcode, sched.zl, sched.zr, *root,
-                self.models, self.block_part, self.weights, self.tips)
-        self._install_row_map(sched)
-        with self._phase("wait"):
-            return np.asarray(out)
-
     def _gidx_of(self, sched, num: int) -> int:
         """gather_child index of a node against a schedule's NEW layout
         WITHOUT installing it: a kernel failure between schedule build
         and dispatch must not leave self.row_map pointing at rows the
-        arena does not hold (shared by the chunk and whole-traversal
-        fast paths)."""
+        arena does not hold."""
         if num <= self.ntips:
             return num - 1
         return self.ntips + sched.row_of[num]

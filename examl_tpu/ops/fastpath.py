@@ -6,7 +6,7 @@ The TPU-native re-architecture of the reference's newview inner loops
 (ExaML `newviewGenericSpecial.c:1263-1497` dispatch over TIP_TIP /
 TIP_INNER / INNER_INNER kernels, and the MIC backend's tip-product
 precompute `umpX`, `mic_native_dna.c:132-165`), driven by what the MXU
-and XLA actually reward (measured, tools/perf_lab.py):
+and XLA actually reward:
 
 * Waves of independent entries are split by tip case and executed as
   chunks, each chunk one batched dot over its padded width.
@@ -58,18 +58,15 @@ group — and IS the jit key: program length is O(#segments) ~ O(log n)
 groups), and execution order equals wave order chunk for chunk, so the
 bounded program's lnL is bit-identical to the unbounded unroll.
 
-`build_structure` (vectorized, from a `FlatTraversal`) and the legacy
-per-entry `build_schedule` both produce the IDENTICAL bounded layout
-(equivalence contract, tests/test_scale.py + tests/test_fastpath.py);
-the engine caches the immutable structure per topology signature and
-refreshes only the packed z arrays per call (`refresh_z`).
-`EXAML_BOUNDED_CHUNKS=0` restores the legacy one-block-per-chunk
-layout (escape hatch + the equivalence-test reference).
+`build_structure` (vectorized, from a `FlatTraversal`) produces the
+layout; the engine caches the immutable structure per topology
+signature and refreshes only the packed z arrays per call
+(`refresh_z`).  `structure_chunks` + `run_chunks` execute the same
+layout unrolled: the reference the tests hold `run_segments` to.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -77,7 +74,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from examl_tpu.ops import kernels
-from examl_tpu.tree.topology import Tree, TraversalEntry
 from examl_tpu.utils import bucket_len, next_pow2
 
 # -- bounded-layout knobs ----------------------------------------------------
@@ -85,47 +81,21 @@ from examl_tpu.utils import bucket_len, next_pow2
 # fixed, so two topologies of similar shape produce the SAME profile and
 # share one compiled program (and one bank family / persistent-cache entry).
 
-MIN_WIDTH = 8        # width floor (EXAML_CHUNK_MIN_WIDTH)
-CHUNK_CAP = 1024     # width cap; wider chunks split (EXAML_CHUNK_CAP)
+# All three are powers of two with MIN_WIDTH <= TAIL_WIDTH, CHUNK_CAP.
+MIN_WIDTH = 8        # width floor
+CHUNK_CAP = 1024     # width cap; wider chunks split
 TAIL_WIDTH = 64      # waves whose chunks all bucket <= this join the
-                     # scanned tail (EXAML_CHUNK_TAIL_WIDTH)
+                     # scanned tail
 MIN_SCAN = 4         # shorter runs stay unrolled (replay padding would
                      # dominate them)
-
-
-def _env_int(name: str, default: int) -> int:
-    v = os.environ.get(name)
-    if not v:
-        return default
-    try:
-        return max(1, int(v))
-    except ValueError:
-        return default
-
-
-def _knobs() -> Tuple[int, int, int]:
-    mw = next_pow2(_env_int("EXAML_CHUNK_MIN_WIDTH", MIN_WIDTH))
-    cap = max(mw, next_pow2(_env_int("EXAML_CHUNK_CAP", CHUNK_CAP)))
-    tail = max(mw, next_pow2(_env_int("EXAML_CHUNK_TAIL_WIDTH",
-                                      TAIL_WIDTH)))
-    return mw, cap, tail
-
-
-def bounded_default() -> bool:
-    """Bounded layout unless EXAML_BOUNDED_CHUNKS=0 (escape hatch; also
-    the reference layout for the equivalence tests)."""
-    return os.environ.get("EXAML_BOUNDED_CHUNKS", "") != "0"
 
 
 def slack_rows(ntips: int) -> int:
     """Arena slack rows the bounded layout needs: headroom for padded
     chunk writes past the real rows AND the dedicated pad region the
-    scanned tail's width-MIN_WIDTH padding sub-chunks write (base = n).
-    Derived from the LIVE knobs so an env-tuned EXAML_CHUNK_MIN_WIDTH
-    is provisioned for, not crashed on (every build still asserts
-    max_write against the arena)."""
-    mw, _cap, _tailw = _knobs()
-    floor = 2 * mw
+    scanned tail's width-MIN_WIDTH padding sub-chunks write (base = n;
+    every build still asserts max_write against the arena)."""
+    floor = 2 * MIN_WIDTH
     return min(max(64, floor), max(next_pow2(ntips), floor))
 
 
@@ -177,57 +147,6 @@ class FastStructure(NamedTuple):
     z_swap: np.ndarray          # [P] slot's children were canonicalized
     num_rows: int
     max_write: int
-
-
-class FastSchedule:
-    """Entry-list twin of `FastStructure` (legacy per-entry builder):
-    the same packed layout plus the packed z arrays, and a lazily
-    materialized per-chunk `FastChunk` list for harnesses that unroll
-    chunks themselves (bench tiers, the Pallas equivalence tests).
-    `profile` is the bucketed segment tuple — identical to
-    `build_structure`'s for the same traversal (equivalence contract).
-    """
-
-    __slots__ = ("profile", "row_of", "num_rows", "max_write",
-                 "base", "lidx", "ridx", "lcode", "rcode", "zl", "zr",
-                 "_host", "_chunks")
-
-    def __init__(self, profile, row_of, num_rows, max_write, dev, host):
-        self.profile = profile
-        self.row_of: Dict[int, int] = row_of
-        self.num_rows = num_rows
-        self.max_write = max_write
-        (self.base, self.lidx, self.ridx, self.lcode, self.rcode,
-         self.zl, self.zr) = dev
-        self._host = host
-        self._chunks: Optional[Tuple[FastChunk, ...]] = None
-
-    @property
-    def chunks(self) -> Tuple[FastChunk, ...]:
-        """Materialized per-chunk list in execution order (includes the
-        replay/padding chunks of scan groups, so running it unrolled is
-        bit-identical to the segment program).  Built lazily — the
-        engine's jitted programs use the packed arrays instead."""
-        if self._chunks is None:
-            base_h, li, ri, lc, rc, zl, zr = self._host
-            views = []
-            metas = []
-            off = cidx = 0
-            for kind, W in iter_profile_chunks(self.profile):
-                views += [li[off:off + W], ri[off:off + W],
-                          lc[off:off + W], rc[off:off + W],
-                          zl[off:off + W], zr[off:off + W]]
-                metas.append((kind, W, np.int32(base_h[cidx])))
-                off += W
-                cidx += 1
-            dev = iter(jax.device_put(
-                [m[2] for m in metas] + views))
-            bases = [next(dev) for _ in metas]
-            self._chunks = tuple(
-                FastChunk(kind, W, b, next(dev), next(dev), next(dev),
-                          next(dev), next(dev), next(dev))
-                for (kind, W, _), b in zip(metas, bases))
-        return self._chunks
 
 
 # -- profile helpers ---------------------------------------------------------
@@ -294,24 +213,14 @@ def _bucket_w(s: int, mw: int) -> int:
 
 
 def _plan_layout(kinds: np.ndarray, sizes: np.ndarray, gwave: np.ndarray,
-                 starts: np.ndarray, child_key: np.ndarray, n: int,
-                 bounded: bool) -> _Layout:
+                 starts: np.ndarray, child_key: np.ndarray,
+                 n: int) -> _Layout:
     """Plan the chunk/segment layout from the (wave, kind)-sorted group
     table.  `child_key[g]` is the max (wave*3+kind) sort key over group
     g's inner children's defining entries (-1 when all children are
-    tips/external) — the vectorized dependency oracle for coalescing.
-
-    Unbounded (legacy) mode: one unrolled chunk per group, width
-    pow2(size) with no floor — byte-for-byte the historical layout."""
+    tips/external) — the vectorized dependency oracle for coalescing."""
     G = len(kinds)
-    if not bounded:
-        chunks = [_Chunk(int(kinds[g]), next_pow2(int(sizes[g])),
-                         [(int(starts[g]), int(starts[g] + sizes[g]))])
-                  for g in range(G)]
-        profile = tuple(("u", c.kind, c.W) for c in chunks)
-        return _finish_layout(profile, chunks, n)
-
-    mw, cap, tailw = _knobs()
+    mw, cap, tailw = MIN_WIDTH, CHUNK_CAP, TAIL_WIDTH
 
     # -- 1. coalescing: merge a small group into the newest earlier
     # same-kind group when every inner child of the candidate was
@@ -474,8 +383,7 @@ def _finish_layout(profile, chunks, n: int) -> _Layout:
                    max_write=max_write)
 
 
-def _layout_from_arrays(wave_id, el, er, lt, rt, child_nodes_key, n,
-                        bounded):
+def _layout_from_arrays(wave_id, el, er, lt, rt, child_nodes_key, n):
     """Shared planner front-end: group the (wave, kind)-sorted entries
     and plan.  Returns (layout, order, skey-derived group table pieces)
     where `order` is the (wave, kind) stable sort permutation."""
@@ -497,8 +405,7 @@ def _layout_from_arrays(wave_id, el, er, lt, rt, child_nodes_key, n,
                     child_nodes_key[er[order]])
     child_key = (np.maximum.reduceat(ck, starts) if n
                  else np.empty(0, np.int64))
-    layout = _plan_layout(kinds, sizes, gwave, starts, child_key, n,
-                          bounded)
+    layout = _plan_layout(kinds, sizes, gwave, starts, child_key, n)
     return layout, order
 
 
@@ -564,17 +471,11 @@ def _pack_structure(layout: _Layout, order, el, er, lt, rt, swap, parent,
     return row_of, base, lidx, ridx, lcode, rcode, z_src, z_swap, dst
 
 
-def build_structure(flat, ntips: int,
-                    bounded: Optional[bool] = None) -> FastStructure:
-    """Vectorized schedule-structure build from a FlatTraversal: the
-    per-entry Python loop of `build_schedule` replaced by numpy sort/
-    scatter over the whole traversal (this is what makes a 120k-taxon
-    schedule build array-rate).  Produces the identical bounded chunk
-    layout — same bucketing, coalescing, scan grouping, same row
-    assignment discipline — as `build_schedule` on the same wave order
-    (the equivalence contract both builders must keep)."""
-    if bounded is None:
-        bounded = bounded_default()
+def build_structure(flat, ntips: int) -> FastStructure:
+    """Vectorized schedule-structure build from a FlatTraversal: numpy
+    sort/scatter over the whole traversal (this is what makes a
+    120k-taxon schedule build array-rate) into the bounded chunk layout
+    of the module docstring."""
     n = flat.n
     left = flat.left
     right = flat.right
@@ -590,7 +491,7 @@ def build_structure(flat, ntips: int,
     node_key = np.full(2 * ntips - 1, -1, dtype=np.int64)
     node_key[flat.parent] = wave_id * 3 + kind
     layout, order = _layout_from_arrays(
-        wave_id, el, er, lt, rt, node_key, n, bounded)
+        wave_id, el, er, lt, rt, node_key, n)
     (row_of, base, lidx, ridx, lcode, rcode, z_src, z_swap,
      _dst) = _pack_structure(layout, order, el, er, lt, rt, swap,
                              flat.parent, 2 * ntips - 1)
@@ -633,77 +534,31 @@ def refresh_z(st: FastStructure, flat, num_slots: int, dtype,
                           placement)
 
 
-def _z_matrix(zs: List[tuple], num_slots: int) -> np.ndarray:
-    """[n, num_slots] branch-length matrix from per-entry z tuples
-    (vectorized for the uniform-length cases that dominate)."""
-    from examl_tpu.utils import z_slots
-    n = len(zs)
-    if n == 0:
-        return np.ones((0, num_slots))
-    ln = len(zs[0])
-    if all(len(z) == ln for z in zs):
-        arr = np.asarray(zs, dtype=np.float64)
-        if ln == num_slots:
-            return arr
-        if ln == 1:
-            return np.broadcast_to(arr, (n, num_slots)).copy()
-        if ln > num_slots:
-            return arr[:, :num_slots].copy()
-    return np.stack([z_slots(z, num_slots) for z in zs])
-
-
-def build_schedule(entries: List[TraversalEntry], ntips: int,
-                   num_slots: int, dtype,
-                   bounded: Optional[bool] = None) -> FastSchedule:
-    """Wave-schedule entries into the bounded chunk layout (see module
-    docstring), packed along one slot axis.  The uncached reference
-    builder: equivalence-tested against `build_structure`, and still
-    used by entry-list callers (bench tiers, bank warming)."""
-    if bounded is None:
-        bounded = bounded_default()
-    waves = Tree.schedule_waves(entries)
-    n = len(entries)
-    wave_entries = [e for w in waves for e in w]
-    wave_id = np.repeat(np.arange(len(waves), dtype=np.int64),
-                        [len(w) for w in waves])
-    parent = np.fromiter((e.parent for e in wave_entries), np.int64, n)
-    left = np.fromiter((e.left for e in wave_entries), np.int64, n)
-    right = np.fromiter((e.right for e in wave_entries), np.int64, n)
-    zl_e = _z_matrix([e.zl for e in wave_entries], num_slots)
-    zr_e = _z_matrix([e.zr for e in wave_entries], num_slots)
-    lt = left <= ntips
-    rt = right <= ntips
-    swap = (~lt) & rt
-    el = np.where(swap, right, left)
-    er = np.where(swap, left, right)
-    kind = 2 - (lt.astype(np.int64) + rt.astype(np.int64))
-    nk = max(2 * ntips - 1, int(max(el.max(), er.max())) + 1) if n else 1
-    node_key = np.full(nk, -1, dtype=np.int64)
-    node_key[parent] = wave_id * 3 + kind
-    layout, order = _layout_from_arrays(
-        wave_id, el, er, lt, rt, node_key, n, bounded)
-    (row_arr, base, lidx, ridx, lcode, rcode, z_src, z_swap,
-     dst) = _pack_structure(layout, order, el, er, lt, rt, swap,
-                            parent, nk)
-    P = layout.P
-    zl = np.ones((P, num_slots))
-    zr = np.ones((P, num_slots))
-    ok = z_src >= 0
-    src = z_src[ok]
-    sw = z_swap[ok, None]
-    zl[ok] = np.where(sw, zr_e[src], zl_e[src])
-    zr[ok] = np.where(sw, zl_e[src], zr_e[src])
-    zl = np.asarray(zl, dtype)
-    zr = np.asarray(zr, dtype)
-    row_of = {int(num): int(r) for num, r in enumerate(row_arr)
-              if r >= 0}
-    host = (base, lidx, ridx, lcode, rcode, zl, zr)
-    # ONE batched host->device transfer for the whole packed layout
-    # (per-array device_puts dominated the 50k schedule build).
-    dev = jax.device_put(list(host))
-    return FastSchedule(profile=layout.profile, row_of=row_of,
-                        num_rows=n, max_write=layout.max_write,
-                        dev=dev, host=host)
+def structure_chunks(st: FastStructure, zl, zr) -> Tuple[FastChunk, ...]:
+    """Materialized per-chunk list of a structure and its `refresh_z`
+    arrays, in execution order (includes the replay/padding chunks of
+    scan groups, so `run_chunks` over it is bit-identical to the
+    segment program).  The engine's jitted programs use the packed
+    arrays instead; this feeds the unrolled reference executor."""
+    base_h, li, ri, lc, rc, zl, zr = (np.asarray(a) for a in (
+        st.base, st.lidx, st.ridx, st.lcode, st.rcode, zl, zr))
+    views = []
+    metas = []
+    off = cidx = 0
+    for kind, W in iter_profile_chunks(st.profile):
+        views += [li[off:off + W], ri[off:off + W],
+                  lc[off:off + W], rc[off:off + W],
+                  zl[off:off + W], zr[off:off + W]]
+        metas.append((kind, W, np.int32(base_h[cidx])))
+        off += W
+        cidx += 1
+    dev = iter(jax.device_put(
+        [m[2] for m in metas] + views))
+    bases = [next(dev) for _ in metas]
+    return tuple(
+        FastChunk(kind, W, b, next(dev), next(dev), next(dev),
+                  next(dev), next(dev), next(dev))
+        for (kind, W, _), b in zip(metas, bases))
 
 
 # -- execution ---------------------------------------------------------------

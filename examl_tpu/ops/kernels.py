@@ -1,4 +1,4 @@
-"""Device kernels of the likelihood engine (jnp; Pallas variants can slot in).
+"""Device kernels of the likelihood engine (jnp).
 
 TPU-native re-design of the reference's hand-vectorized kernel inventory
 (ExaML `newviewGenericSpecial.c`, `evaluateGenericSpecial.c`,
@@ -90,7 +90,7 @@ class Traversal(NamedTuple):
 
 class TipState(NamedTuple):
     """Device-resident tip data, [ntips, B, lane] a field: the packed
-    state codes (the chunk and Pallas tiers contract their one-hot with
+    state codes (the chunk tier contracts their one-hot with
     `table`) and, for the jnp kernels, each site's state BITMASK
     (`DataType.code_bitmasks[codes]`, bit k set where state k is
     compatible), from which `tip_partials` makes the 0/1 partial row by

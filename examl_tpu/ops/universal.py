@@ -55,11 +55,9 @@ steps instead of the specialized program's O(log n) fused ops — the
 zero-compile tier for serving novel topologies, not a replacement for
 the chunk tier on a hot profile.
 
-The interpreter always executes the plain-XLA chunk kernel: it is the
-PORTABILITY tier — the escape ladder runs pallas -> chunk ->
-universal -> scan — and a Mosaic kernel inside every switch branch
-would multiply compile surface for the tier whose whole point is
-compiling once.  Opt out with `EXAML_UNIVERSAL=0`; force with
+The interpreter executes the chunk tier's own kernel
+(`fastpath.chunk_applier`): it is the PORTABILITY tier — the escape
+ladder runs chunk -> universal -> scan.  Opt out with `EXAML_UNIVERSAL=0`; force with
 `EXAML_UNIVERSAL=force` (what the supervisor's degradation ladder pins
 between the chunk and scan rungs).
 """
@@ -75,8 +73,8 @@ from examl_tpu.utils import bucket_len
 
 class UniversalIneligible(ValueError):
     """This layout cannot run through the interpreter (a chunk width
-    off the ladder — the legacy unbounded layout — or an empty
-    traversal).  Callers fall back to the specialized program."""
+    off the ladder the table is built for, or an empty traversal).
+    Callers fall back to the specialized program."""
 
 
 def width_ladder(mw: int, cap: int) -> Tuple[int, ...]:
@@ -92,12 +90,11 @@ def width_ladder(mw: int, cap: int) -> Tuple[int, ...]:
 
 
 def alphabet_key() -> Tuple[int, int]:
-    """(min_width, cap) — the layout knobs that determine step width
-    and table splitting; rides in every universal jit key so env-tuned
-    EXAML_CHUNK_MIN_WIDTH/CAP runs can never alias programs."""
+    """(min_width, cap) — the layout constants that determine step
+    width and table splitting; rides in every universal jit key, so
+    programs of two ladders can never alias."""
     from examl_tpu.ops import fastpath
-    mw, cap, _tail = fastpath._knobs()
-    return (mw, cap)
+    return (fastpath.MIN_WIDTH, fastpath.CHUNK_CAP)
 
 
 def alphabet(knobs: Optional[Tuple[int, int]] = None
@@ -132,8 +129,8 @@ def build_table(profile, base: np.ndarray,
     ladder widths are all multiples of the floor, chunk entries are
     independent, and every per-entry op in the kernel batches over the
     width axis, so sub-steps compute bit-identical rows.  Raises
-    UniversalIneligible for off-ladder widths (legacy unbounded layout)
-    or an empty profile."""
+    UniversalIneligible for widths off the `knobs` ladder or an empty
+    profile."""
     from examl_tpu.ops import fastpath
 
     if knobs is None:
@@ -149,7 +146,7 @@ def build_table(profile, base: np.ndarray,
         bad = ws[offladder]
         raise UniversalIneligible(
             f"chunk widths {sorted(set(int(b) for b in bad))} off the "
-            f"ladder (floor {mw}, cap {cap}) — unbounded layout?")
+            f"ladder (floor {mw}, cap {cap})")
     base = np.asarray(base, np.int64)
     if base.shape[0] != len(kinds_w):
         raise UniversalIneligible(

@@ -13,7 +13,7 @@ have observed, and makes every recovery path *testable on CPU*:
                 real seams (engine dispatch, compile monitor, lnL
                 boundary, checkpoint write, bank worker, heartbeat).
 * `exitcause` — the ONE worker/child exit-classification used by
-                bench.py, ops/bank.py and the supervisor (SIGILL vs
+                ops/bank.py and the supervisor (SIGILL vs
                 OOM vs hang-kill vs preempt).
 * `heartbeat` — per-iteration liveness file emitted by the search loop
                 from the obs registry; the supervisor's only way to see
@@ -25,11 +25,11 @@ have observed, and makes every recovery path *testable on CPU*:
 * `supervisor`— `--supervise`: runs the search as a killable child,
                 watches the heartbeat, classifies failures, restarts
                 from the newest checkpoint with capped retries, backoff
-                and escalating degradation pins (pallas→chunk→scan).
+                and escalating degradation pins (chunk→universal→scan).
 
 IMPORT CONTRACT: this `__init__` and the `exitcause`/`faults` modules
-are stdlib-only and must stay that way — the bench PARENT and the
-supervisor parent import them and must never load jax (a broken
+are stdlib-only and must stay that way — the supervisor parent
+imports them and must never load jax (a broken
 accelerator plugin can hang the importing process, and on
 exclusive-access accelerators the parent must never take the device
 handle the child needs).

@@ -1,11 +1,9 @@
 """Shared worker/child exit classification (stdlib-only by contract).
 
 One taxonomy for every process that watches another process die: the
-bench parent's stage workers, the bank's compile workers, and the run
-supervisor.  Before this module each grew its own `_exit_desc` copy
-(bench.py duplicated bank's "on purpose" because the bench parent must
-never import jax — solved here by keeping this module stdlib-only; the
-package `__init__` documents the contract).
+bank's compile workers and the run supervisor.  Stdlib-only, so a
+parent that must never import jax can use it (the package `__init__`
+documents the contract).
 
 Two layers:
 
@@ -95,7 +93,7 @@ RETRYABLE = frozenset({CAUSE_HANG_KILL, CAUSE_OOM_KILL, CAUSE_SIGILL,
 
 # Causes that indicate the *program tier* (not the environment) may be
 # at fault — these escalate the supervisor's degradation ladder
-# (pallas→chunk→scan), mirroring the bank's `_is_wedge` rule that only
+# (chunk→universal→scan), mirroring the bank's `_is_wedge` rule that only
 # deadline kills and deaths-by-signal justify routing around a family.
 # A collective wedge is the program-wedge class by definition; a
 # single-rank straggler is presumed environmental (one slow/blocked
@@ -107,8 +105,8 @@ def exit_desc(rc: Optional[int], none_desc: str = "(still running)") -> str:
     """Human-readable exit cause for a Popen returncode.
 
     `none_desc` covers the rc-is-None case, which different watchers
-    read differently: the bank polls (None = still running) while the
-    bench names it after the action it just took (None = hang-killed).
+    read differently: the bank polls (None = still running); a watcher
+    that just killed the child names it after that action.
     """
     if rc is None:
         return none_desc

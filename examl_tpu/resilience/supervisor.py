@@ -16,9 +16,8 @@ plugin must not be able to hang the watcher).  It:
   (`resilience/exitcause.py`: SIGILL vs OOM vs hang-kill vs preempt);
 * restarts from the newest checkpoint (`-R` once one exists) with
   capped retries, exponential backoff, and ESCALATING degradation pins
-  mirroring the bank's escape hatches: retry 1 pins `EXAML_PALLAS=0`
-  (the default XLA chunk tier, whatever the failed run asked for),
-  retry 2 pins `EXAML_UNIVERSAL=force`
+  mirroring the bank's escape hatches: retry 1 is a plain retry (the
+  chunk tier again), retry 2 pins `EXAML_UNIVERSAL=force`
   (chunk→universal: the topology-as-data interpreter compiles ONE
   program regardless of topology, so a wedge inside a per-profile
   chunk compile cannot recur), retry 3+ pins the scan tier
@@ -75,9 +74,9 @@ from examl_tpu.resilience import exitcause, heartbeat
 # without importing it: bank pulls in obs/jax, this parent must not).
 DEGRADE_LADDER = (
     {},
-    {"EXAML_PALLAS": "0"},
-    {"EXAML_PALLAS": "0", "EXAML_UNIVERSAL": "force"},
-    {"EXAML_PALLAS": "0", "EXAML_FAST_TRAVERSAL": "0",
+    {},
+    {"EXAML_UNIVERSAL": "force"},
+    {"EXAML_FAST_TRAVERSAL": "0",
      "EXAML_UNIVERSAL": "0", "EXAML_BATCH_SCAN": "0",
      "EXAML_BATCH_THOROUGH": "0", "EXAML_GRAD_SMOOTH": "0"},
 )

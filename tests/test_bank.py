@@ -142,8 +142,8 @@ def test_enumerate_families_config_matrix():
     assert "fast" not in fams                            # -S: pooled scan
     fams = bank.enumerate_families("d", env={"EXAML_FAST_TRAVERSAL": "0"})
     assert "fast" not in fams
-    fams = bank.enumerate_families("d", env={"EXAML_PALLAS": "whole"})
-    assert "whole" in fams
+    assert set(bank.FALLBACK_ENV) <= set(bank.enumerate_families(
+        "d", env={}))                 # no hatch for a family never run
     fams = bank.enumerate_families("d", env={"EXAML_BATCH_SCAN": "0"})
     assert "scan" not in fams and "thscan" not in fams
     del base
@@ -155,18 +155,14 @@ def test_exit_desc_names_signals():
     assert "SIGKILL" in bank._exit_desc(-int(signal.SIGKILL))
     assert bank._exit_desc(3) == "(returncode 3)"
     assert bank._exit_desc(None) == "(still running)"
-    # bench.py carries its own copy (its parent must not import jax):
-    import bench
-    assert "SIGILL" in bench._exit_desc(-int(signal.SIGILL))
-    assert bench._exit_desc(None) == "(hang-killed)"
 
 
 def test_manifest_roundtrip_and_degraded_set(tmp_path):
     report = {"fast": {"status": "timeout", "seconds": 5.0},
               "traverse": {"status": "banked", "seconds": 1.2},
               "scan": {"status": "skipped", "reason": "cpu"},
-              "whole": {"status": "error",
-                        "error": "worker died mid-stage (signal SIGILL)"},
+              "grad": {"status": "error",
+                       "error": "worker died mid-stage (signal SIGILL)"},
               "derivs": {"status": "error",
                          "error": "worker exited (returncode 1)"}}
     bank._save_manifest(str(tmp_path), report, lambda m: None)
@@ -174,11 +170,11 @@ def test_manifest_roundtrip_and_degraded_set(tmp_path):
     assert m["families"]["fast"]["status"] == "timeout"
     # Wedge verdicts gate (deadline kill, death-by-signal); plain
     # environment errors (returncode) do not.
-    assert bank.manifest_degraded_families(m) == {"fast", "whole"}
+    assert bank.manifest_degraded_families(m) == {"fast", "grad"}
     assert bank.manifest_degraded_families(None) == set()
     assert bank.load_manifest(cache_path=str(tmp_path / "nope")) is None
     # A later run that does not enumerate 'fast' must not erase its
-    # verdict (bench gating depends on it surviving).
+    # verdict.
     bank._save_manifest(str(tmp_path),
                         {"traverse": {"status": "banked"}},
                         lambda m: None)
@@ -186,17 +182,37 @@ def test_manifest_roundtrip_and_degraded_set(tmp_path):
     assert m2["families"]["fast"]["status"] == "timeout"
 
 
-def test_bench_stage_families_gate_degraded_tiers():
-    import bench
-    assert "fast" in bench._STAGE_FAMILIES["s-chunks"]
-    assert "whole" in bench._STAGE_FAMILIES["s-whole"]
-    assert "s-scan" not in bench._STAGE_FAMILIES       # fallback never gated
-    assert "prims" not in bench._STAGE_FAMILIES
-    # Every BASELINE config has a CPU-fallback mid stage (VERDICT Next §3).
-    for stage in ("L:dna-mid", "L:aa-mid", "L:psr-mid", "L:sev-mid",
-                  "L:bf16-mid"):
-        assert stage in bench.CPU_PLAN
-        assert stage[2:] in bench.LARGE_CONFIGS
+def test_older_manifest_with_removed_family_loads_and_survives(tmp_path):
+    """Outside input: a manifest an older version wrote, with a wedge
+    verdict for the `whole` family this version no longer has and its
+    `bounded` layout field.  It loads, pins no escape hatch and gates no
+    family a run enumerates, and a save keeps it beside the new
+    verdicts."""
+    old = {"version": 1, "updated": 1.0,
+           "chunk_layout": {"bounded": True, "min_width": 8,
+                            "chunk_cap": 1024, "tail_width": 64},
+           "families": {"whole": {"status": "timeout", "seconds": 180.0},
+                        "traverse": {"status": "banked", "seconds": 1.0}}}
+    (tmp_path / bank.MANIFEST_NAME).write_text(json.dumps(old))
+    m = bank.load_manifest(cache_path=str(tmp_path))
+    assert m["families"]["whole"]["status"] == "timeout"
+    gated = bank.manifest_degraded_families(m)
+    assert gated == {"whole"}
+    assert not gated & set(bank.enumerate_families("d", env={}))
+    assert "whole" not in bank.FALLBACK_ENV
+    bank.reset()
+    env0 = dict(os.environ)
+    try:
+        bank._apply_degradations(m["families"], lambda msg: None)
+        assert dict(os.environ) == env0        # no hatch pulled
+    finally:
+        bank.reset()
+    bank._save_manifest(str(tmp_path), {"fast": {"status": "banked"}},
+                        lambda msg: None)
+    m2 = bank.load_manifest(cache_path=str(tmp_path))
+    assert m2["families"]["whole"]["status"] == "timeout"
+    assert m2["families"]["fast"]["status"] == "banked"
+    assert "bounded" not in m2["chunk_layout"]
 
 
 # -- CLI end-to-end: compile time moves into the bank phase -----------------

@@ -58,7 +58,7 @@ def test_rehearsal_lines_and_contract(rehearsal):
     assert full["rel_err"] <= full["rtol"] == 2e-5
     assert full["lnl_after_smooth"] >= full["lnl_engine"]
     assert full["grad_passes"] >= 1 and full["tiers"] == ["chunk", "grad"]
-    assert not full["use_pallas"] and not full["demotions"]
+    assert not full["demotions"]
     ev = by["evaluate"]
     assert ev["lnl_end"] > ev["lnl_start_oracle"] and not ev["demotions"]
     assert ev["ledger_events"] > 0      # the demotion check read a ledger

@@ -33,16 +33,19 @@ def _fresh(data, text, **kw):
     return inst, inst.tree_from_newick(text)
 
 
-def test_fast_matches_scan(data49, tree49_text):
-    inst_f, tree = _fresh(data49, tree49_text)
-    lnl_fast = inst_f.evaluate(tree, full=True)
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_fast_matches_scan(datatype):
+    """K = 4 and K = 20 through the chunk tier against the scan tier."""
+    data = _synth(datatype=datatype)
+    inst_f = PhyloInstance(data)
+    lnl_fast = inst_f.evaluate(inst_f.random_tree(3), full=True)
     assert any(len(e._fast_jit_cache) > 0 for e in inst_f.engines.values()), \
         "full evaluate did not take the fast path"
 
-    inst_s, tree_s = _fresh(data49, tree49_text)
+    inst_s = PhyloInstance(data)
     for eng in inst_s.engines.values():
         eng.fast_slack = 0          # force scan path
-    lnl_scan = inst_s.evaluate(tree_s, full=True)
+    lnl_scan = inst_s.evaluate(inst_s.random_tree(3), full=True)
     assert lnl_fast == pytest.approx(lnl_scan, rel=1e-12, abs=1e-7)
 
 
@@ -99,27 +102,26 @@ def test_fast_path_binary_and_small():
 
 # -- bounded-program equivalence matrix (ISSUE 5) ----------------------------
 # Width bucketing + chunk coalescing + the scanned long tail must be
-# invisible to the numbers: the bounded layout's lnL matches the legacy
-# one-block-per-chunk unroll and the scan tier bit-for-bit on these
+# invisible to the numbers: the bounded layout's lnL matches the scan
+# tier bit-for-bit on these
 # fixtures, the lax.scan groups match their own unrolled execution
 # bit-for-bit BY CONSTRUCTION (same kernel body, same order), and any
 # valid re-split of the waves preserves per-node arena contents.
-
-import os
 
 import jax.numpy as jnp
 
 from examl_tpu import obs
 from examl_tpu.ops import fastpath
-from examl_tpu.tree.topology import Tree, hookup
 
 
-def _synth(n=40, width=97, seed=0):
+def _synth(n=40, width=97, seed=0, datatype="DNA"):
     rng = np.random.default_rng(seed)
+    alphabet = {"DNA": "ACGT", "AA": "ARNDCQEGHILKMFPSTWYV"}[datatype]
     names = [f"t{i}" for i in range(n)]
-    seqs = ["".join("ACGT"[b] for b in rng.integers(0, 4, width))
+    seqs = ["".join(alphabet[b]
+                    for b in rng.integers(0, len(alphabet), width))
             for _ in range(n)]
-    return build_alignment_data(names, seqs)
+    return build_alignment_data(names, seqs, datatype_name=datatype)
 
 
 @pytest.fixture(scope="module")
@@ -131,37 +133,62 @@ def _counter(name):
     return obs.counter(name)
 
 
-def _eval(data, seed=3, force_scan=False, bounded=True, **kw):
-    if not bounded:
-        os.environ["EXAML_BOUNDED_CHUNKS"] = "0"
-    try:
-        inst = PhyloInstance(data, **kw)
-        tree = inst.random_tree(seed)
-        if force_scan:
-            for e in inst.engines.values():
-                e.force_scan = True
-        return inst, tree, inst.evaluate(tree, full=True)
-    finally:
-        os.environ.pop("EXAML_BOUNDED_CHUNKS", None)
+def _eval(data, seed=3, force_scan=False, **kw):
+    inst = PhyloInstance(data, **kw)
+    tree = inst.random_tree(seed)
+    if force_scan:
+        for e in inst.engines.values():
+            e.force_scan = True
+    return inst, tree, inst.evaluate(tree, full=True)
 
 
-def test_bounded_matches_legacy_and_scan_bitwise(sdata):
-    """The tentpole acceptance: bounded layout vs the uncapped unroll vs
-    the scan tier, bit-identical lnL on the f64 fixture (all three tip
-    cases present in a 40-taxon random tree)."""
-    _, _, lnl_b = _eval(sdata)
-    _, _, lnl_l = _eval(sdata, bounded=False)
-    _, _, lnl_s = _eval(sdata, force_scan=True)
-    assert lnl_b == lnl_l
+@pytest.mark.parametrize("per_partition_branches", [False, True],
+                         ids=["joint", "per_partition"])
+def test_bounded_matches_scan_bitwise(sdata, per_partition_branches):
+    """The tentpole acceptance: bounded layout vs the scan tier,
+    bit-identical lnL on the f64 fixture (all three tip cases present
+    in a 40-taxon random tree), with one branch-length slot and with
+    C>1 slots through the packed z plumbing."""
+    kw = {"per_partition_branches": per_partition_branches}
+    _, _, lnl_b = _eval(sdata, **kw)
+    _, _, lnl_s = _eval(sdata, force_scan=True, **kw)
     assert lnl_b == lnl_s
 
 
-def test_bounded_matches_legacy_per_partition_branches(sdata):
-    """C>1 branch slots through the packed z plumbing."""
-    _, _, lnl_b = _eval(sdata, per_partition_branches=True)
-    _, _, lnl_l = _eval(sdata, bounded=False,
-                        per_partition_branches=True)
-    assert lnl_b == lnl_l
+def test_entry_list_takes_scan_tier_bitwise(sdata):
+    """One way into the fast tier: a full traversal handed over as a
+    TraversalEntry LIST runs the scan tier, and leaves the lnL the flat
+    form's chunk program leaves, to the bit."""
+    inst_f, tree_f, lnl_flat = _eval(sdata)
+    inst = PhyloInstance(sdata)
+    tree = inst.random_tree(3)
+    p = tree.centroid_branch()
+    entries = tree.flat_full_traversal(p).to_entries()
+    (eng,) = inst.engines.values()
+    assert eng._tier_for(entries, True) == "scan"
+    vals = eng.traverse_evaluate(entries, p.number, p.back.number, p.z,
+                                 full=True)
+    assert not any(k[0] == "fast" for k in eng._fast_jit_cache)
+    assert float(np.sum(vals)) == lnl_flat
+    (eng_f,) = inst_f.engines.values()
+    assert any(k[0] == "fast" for k in eng_f._fast_jit_cache)
+
+
+@pytest.mark.parametrize("var,value,refused", [
+    ("EXAML_PALLAS", "1", True), ("EXAML_PALLAS", "whole", True),
+    ("EXAML_BOUNDED_CHUNKS", "0", True),
+    ("EXAML_PALLAS_INTERPRET", "1", True), ("EXAML_PALLAS", "0", False)])
+def test_removed_switches_raise_by_name(sdata, monkeypatch, var, value,
+                                        refused):
+    """A run that asks for a deleted kernel or layout gets an error that
+    names the variable, not the default path in silence; a supervisor's
+    child that inherits EXAML_PALLAS=0 from an older parent passes."""
+    monkeypatch.setenv(var, value)
+    if refused:
+        with pytest.raises(ValueError, match=var + "="):
+            PhyloInstance(sdata)
+    else:
+        PhyloInstance(sdata)
 
 
 def test_bounded_matches_sev_scan(sdata):
@@ -172,10 +199,10 @@ def test_bounded_matches_sev_scan(sdata):
     assert lnl_s == pytest.approx(lnl_b, rel=1e-12, abs=1e-7)
 
 
-def test_profile_bounded_and_builders_agree(sdata):
-    """Both builders produce the identical bucketed layout (equivalence
-    contract); the profile is made of ladder widths only and its
-    operation count is far below the raw chunk count."""
+def test_profile_bounded(sdata):
+    """The profile is made of ladder widths only, its operation count is
+    far below the raw chunk count, and its writes fit the arena the
+    engine provisions."""
     inst = PhyloInstance(sdata)
     tree = inst.random_tree(3)
     p = tree.centroid_branch()
@@ -184,10 +211,9 @@ def test_profile_bounded_and_builders_agree(sdata):
     flat = tree.flat_full_traversal(p)
     n = inst.alignment.ntaxa
     st = fastpath.build_structure(flat, n)
-    sch = fastpath.build_schedule(flat.to_entries(), n, 1, jnp.float64)
-    assert st.profile == sch.profile
-    assert st.max_write == sch.max_write
-    assert st.num_rows == sch.num_rows
+    (eng,) = inst.engines.values()
+    assert st.num_rows == n - 2
+    assert st.num_rows <= st.max_write <= eng.num_rows - 1
     un, sc, total = fastpath.profile_stats(st.profile)
     assert sc >= 1, st.profile            # the long tail actually scans
     assert un + sc < total                # fewer ops than chunks
@@ -198,31 +224,40 @@ def test_profile_bounded_and_builders_agree(sdata):
         assert w & (w - 1) == 0           # ladder = powers of two
 
 
-def test_segment_program_matches_unrolled_bitwise(sdata):
+def _unrolled(eng, flat, ntips):
+    """(structure, z arrays, arena and scaler after the unrolled
+    reference executor ran the structure's materialised chunks)."""
+    st = fastpath.build_structure(flat, ntips)
+    zl, zr = fastpath.refresh_z(st, flat, eng.num_branch_slots, eng.dtype)
+    c, s = fastpath.run_chunks(
+        eng.models, eng.block_part, eng.tips, jnp.array(eng.clv),
+        jnp.array(eng.scaler), fastpath.structure_chunks(st, zl, zr),
+        eng.scale_exp, eng.fast_precision)
+    return st, zl, zr, np.asarray(c), np.asarray(s)
+
+
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_segment_program_matches_unrolled_bitwise(datatype):
     """The lax.scan groups execute the identical chunk kernel in the
     identical order: real arena rows and scalers bit-equal to the
-    unrolled execution of the same chunk list."""
-    inst = PhyloInstance(sdata)
+    unrolled execution of the same chunk list (K = 4 and K = 20)."""
+    inst = PhyloInstance(_synth(datatype=datatype))
     tree = inst.random_tree(3)
     (eng,) = inst.engines.values()
     p = tree.centroid_branch()
     if tree.is_tip(p.number):
         p = p.back
     flat = tree.flat_full_traversal(p)
-    n = inst.alignment.ntaxa
-    sch = fastpath.build_schedule(flat.to_entries(), n, 1, eng.dtype)
+    st, zl, zr, c1, s1 = _unrolled(eng, flat, inst.alignment.ntaxa)
     apply = fastpath.chunk_applier(eng.models, eng.block_part, eng.tips,
                                    eng.scale_exp, eng.fast_precision)
-    c1, s1 = fastpath.run_chunks(
-        eng.models, eng.block_part, eng.tips, jnp.array(eng.clv),
-        jnp.array(eng.scaler), sch.chunks, eng.scale_exp,
-        eng.fast_precision)
     c2, s2 = fastpath.run_segments(
-        sch.profile, sch.base, sch.lidx, sch.ridx, sch.lcode, sch.rcode,
-        sch.zl, sch.zr, jnp.array(eng.clv), jnp.array(eng.scaler), apply)
-    rows = np.asarray(sorted(sch.row_of.values()))
-    assert (np.asarray(c1)[rows] == np.asarray(c2)[rows]).all()
-    assert (np.asarray(s1)[rows] == np.asarray(s2)[rows]).all()
+        st.profile, st.base, st.lidx, st.ridx, st.lcode, st.rcode,
+        zl, zr, jnp.array(eng.clv), jnp.array(eng.scaler), apply)
+    assert any(seg[0] == "s" for seg in st.profile)   # a scan group ran
+    rows = np.sort(st.row_of[st.row_of >= 0])
+    assert (c1[rows] == np.asarray(c2)[rows]).all()
+    assert (s1[rows] == np.asarray(s2)[rows]).all()
 
 
 def test_wave_resplit_preserves_arena_rows(sdata):
@@ -230,31 +265,28 @@ def test_wave_resplit_preserves_arena_rows(sdata):
     re-split/reorder of the waves (here: random within-wave entry
     permutations, which reshuffle chunk membership and row assignment)
     preserves every node's arena row contents bit-for-bit."""
+    from examl_tpu.tree.topology import FlatTraversal
     inst = PhyloInstance(sdata)
     tree = inst.random_tree(3)
     (eng,) = inst.engines.values()
     n = inst.alignment.ntaxa
-    _, entries = tree.full_traversal_centroid()
+    flat = tree.flat_full_traversal(tree.centroid_branch())
 
-    def run(ents):
-        sch = fastpath.build_schedule(ents, n, 1, eng.dtype)
-        c, s = fastpath.run_chunks(
-            eng.models, eng.block_part, eng.tips, jnp.array(eng.clv),
-            jnp.array(eng.scaler), sch.chunks, eng.scale_exp,
-            eng.fast_precision)
-        c, s = np.asarray(c), np.asarray(s)
-        return {num: (c[r], s[r]) for num, r in sch.row_of.items()}
+    def run(fl):
+        st, _, _, c, s = _unrolled(eng, fl, n)
+        return {num: (c[r], s[r]) for num, r in enumerate(st.row_of)
+                if r >= 0}
 
-    base = run(entries)
+    base = run(flat)
+    assert len(base) == n - 2
     rng = np.random.default_rng(11)
+    starts = np.r_[0, np.cumsum(flat.wave_sizes)]
     for trial in range(3):
-        waves = Tree.schedule_waves(entries)
-        shuffled = []
-        for w in waves:
-            w = list(w)
-            rng.shuffle(w)
-            shuffled.extend(w)
-        got = run(shuffled)
+        perm = np.concatenate([lo + rng.permutation(hi - lo)
+                               for lo, hi in zip(starts[:-1], starts[1:])])
+        got = run(FlatTraversal(
+            flat.parent[perm], flat.left[perm], flat.right[perm],
+            flat.zl[perm], flat.zr[perm], flat.wave_sizes, flat.ntips))
         assert got.keys() == base.keys()
         for num in base:
             assert (got[num][0] == base[num][0]).all(), (trial, num)

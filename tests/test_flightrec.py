@@ -128,10 +128,9 @@ def _entries(ntips=4):
     return [_E(5, 1, 2), _E(6, 3, 4), _E(7, 5, 6)], ntips
 
 
-def test_bytes_model_closed_form_and_bench_delegation():
-    """ONE shared definition: bench.py's historical accounting must be
-    bit-for-bit the obs/traffic closed form."""
-    import bench
+def test_bytes_model_closed_form():
+    """The obs/traffic closed form, both signatures, against a hand
+    count."""
     entries, ntips = _entries()
     patterns, R, K, itemsize = 97, 4, 4, 4
     clv_row = patterns * R * K * itemsize
@@ -144,8 +143,6 @@ def test_bytes_model_closed_form_and_bench_delegation():
     got = traffic.bytes_per_traversal(entries, ntips, patterns, R, K,
                                       itemsize)
     assert got == expect
-    assert bench._bytes_per_traversal(entries, ntips, patterns, R, K,
-                                      itemsize) == got
     assert traffic.bytes_per_traversal_counts(3, 4, patterns, R, K,
                                               itemsize) == got
 
@@ -184,10 +181,10 @@ def test_traffic_window_accumulates_then_verdicts():
         del os.environ["EXAML_TRAFFIC_WINDOW_WALL_S"]
 
 
-def test_engine_traffic_agrees_with_bench_model():
-    """bench <-> engine consistency: the engine's per-dispatch byte
-    accounting (entry-list AND FlatTraversal forms) equals the shared
-    model bench.py delegates to — one definition, bit-for-bit."""
+def test_engine_traffic_agrees_with_model():
+    """The engine's per-dispatch byte accounting (entry-list AND
+    FlatTraversal forms) equals the obs/traffic model: one definition,
+    bit-for-bit."""
     from examl_tpu.instance import PhyloInstance
 
     inst = PhyloInstance(correlated_dna(8, 120, seed=11))
@@ -365,10 +362,10 @@ def test_run_report_renders_synthetic_artifacts(tmp_path):
     # matched-start filtering.
     ledger.event("compile", family="wedged", status="start")
     ledger.finalize()
-    bench_doc = {"value": 1e8, "vs_baseline": 2.0, "backend": "cpu",
-                 "vs_baseline_valid": False, "achieved_gbps": 55.0,
-                 "regime": "bandwidth-meaningful",
-                 "traversal_variant": "fused"}
+    bench_doc = {"bench": "fleet", "trees_per_sec": 12.5,
+                 "single_trees_per_sec": 0.5, "speedup_vs_single": 25.0,
+                 "target_speedup": 11.2, "meets_target": True,
+                 "batch_occupancy": 0.9}
     lines = []
     run_report.render(snap, ledger.read_events(
         str(tmp_path / ledger.MERGED_NAME)), bench_doc,
@@ -376,7 +373,7 @@ def test_run_report_renders_synthetic_artifacts(tmp_path):
     text = "\n".join(lines)
     assert "21.00 GB/s" in text and "dispatch-bound" in text
     assert "[NOT a bandwidth number]" in text   # the regime flag
-    assert "55.00 GB/s" in text                 # bench row
+    assert "trees_per_sec 12.5" in text and "MET" in text  # fleet row
     assert "dispatch" in text and "p95" in text
     assert "compile" in text                    # timeline event
     assert "family=wedged" in text              # unmatched start kept

@@ -480,8 +480,8 @@ def test_gl004_default_argument_reads_are_import_time(monkeypatch):
 
 
 def test_gl004_and_gl006_doc_matching_is_whole_token(monkeypatch):
-    # A documented EXAML_CHUNK_CAP must not vacuously document a new
-    # EXAML_CHUNK; a registered fleet.job point is not documented by
+    # A documented EXAML_COMPILE_CACHE must not vacuously document a new
+    # EXAML_COMPILE; a registered fleet.job point is not documented by
     # the text mentioning fleet.job.poison.
     monkeypatch.setattr(checks_env, "ENV_REGISTRY", {
         "EXAML_TEST": {"doc": "readme", "note": "x"}})
@@ -541,7 +541,6 @@ def test_strict_select_does_not_report_out_of_scope_stale(tmp_path):
     root = tmp_path / "repo"
     (root / "examl_tpu").mkdir(parents=True)
     (root / "tools").mkdir()
-    (root / "bench.py").write_text("")
     (root / "examl_tpu" / "ok.py").write_text("X = 1\n")
     bp = tmp_path / "baseline.json"
     bp.write_text(json.dumps({"entries": [
@@ -705,7 +704,6 @@ def test_cli_json_artifact_and_exit_codes(tmp_path, monkeypatch):
     (root / "examl_tpu").mkdir(parents=True)
     (root / "tools").mkdir()
     (root / "examl_tpu" / "bad.py").write_text(DURABILITY_BAD)
-    (root / "bench.py").write_text("")
     out_json = tmp_path / "gl.json"
     rc = main(["--root", str(root), "--select", "GL007",
                "--json", str(out_json)])

@@ -90,14 +90,17 @@ def test_bf16x3_child_dot_bound():
     exact = float(inst.evaluate(tree, full=True))
 
     eng = inst.engines[4]
-    root, entries = tree.full_traversal_centroid()
-    sched = eng._fast_schedule(entries)
+    root = tree.centroid_branch()
+    flat = tree.flat_full_traversal(root)
+    sched = fp.build_structure(flat, eng.ntips)
+    zl, zr = fp.refresh_z(sched, flat, eng.num_branch_slots, eng.dtype)
+    chunks = fp.structure_chunks(sched, zl, zr)
     jax.lax.dot_general = patched
     fp.jax.lax.dot_general = patched
     try:
         clv, sc = fp.run_chunks(eng.models, eng.block_part, eng.tips,
                                 jnp.array(eng.clv), jnp.array(eng.scaler),
-                                sched.chunks, eng.scale_exp,
+                                chunks, eng.scale_exp,
                                 jax.lax.Precision.HIGHEST)
     finally:
         jax.lax.dot_general = orig_dg
@@ -136,6 +139,5 @@ def test_bf16_clv_storage_bound(monkeypatch):
     inst, bf_full, bf_part = build("bf16")
     (eng,) = inst.engines.values()
     assert eng.clv.dtype == jnp.bfloat16
-    assert not eng.use_pallas          # Pallas tier requires f32 storage
     assert abs(bf_full - f32_full) < 4.0, (bf_full, f32_full)
     assert abs(bf_part - f32_part) < 4.0, (bf_part, f32_part)

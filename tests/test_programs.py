@@ -296,14 +296,14 @@ def test_model_vs_xla_past_tolerance_counts_documented_divergence(
 def test_model_vs_xla_without_compiler_figure_stays_model():
     assert programs.model_vs_xla("chunk.x", 500) == "model"
     _seed_fast_row(1000.0)
-    assert programs.model_vs_xla("pallas.x", 500) == "xla"  # fast serves it
+    assert programs.model_vs_xla("chunk.y", 500) == "xla"   # fast serves it
     assert programs.model_vs_xla("grad.x", 500) == "model"  # no grad row
     assert programs.model_vs_xla("chunk.x", 0) == "model"   # no bytes
 
 
 def test_tier_families_cover_every_engine_tier():
-    assert set(programs.TIER_FAMILIES) >= {
-        "scan", "chunk", "pallas", "whole", "universal", "grad"}
+    assert set(programs.TIER_FAMILIES) == {
+        "scan", "chunk", "universal", "grad"}
 
 
 # -- live memory sampling -----------------------------------------------------

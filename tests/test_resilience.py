@@ -145,15 +145,35 @@ def test_exitcause_taxonomy():
     assert "usage" not in exitcause.RETRYABLE
 
 
-def test_exit_desc_shared_by_bank_and_bench():
-    """One taxonomy (satellite): bank and bench now delegate to
-    resilience/exitcause.py, keeping their distinct rc-None wording."""
-    import bench
+def test_exit_desc_shared_by_bank():
+    """One taxonomy (satellite): the bank delegates to
+    resilience/exitcause.py, with its own rc-None wording."""
     from examl_tpu.ops import bank
     assert bank._exit_desc(-int(signal.SIGILL)) == "(signal SIGILL)"
     assert bank._exit_desc(None) == "(still running)"
-    assert bench._exit_desc(-int(signal.SIGILL)) == "(signal SIGILL)"
-    assert bench._exit_desc(None) == "(hang-killed)"
+    assert exitcause.exit_desc(None, "(hang-killed)") == "(hang-killed)"
+
+
+def test_every_pinned_variable_is_one_the_package_reads():
+    """DEGRADE_LADDER is a hand copy of bank.FALLBACK_ENV (the jax-free
+    parent cannot import it): every variable a rung or an escape hatch
+    pins must still be READ (`.get("X"`, `getenv("X"`, `environ["X"]`)
+    somewhere under examl_tpu/, or a pin lands on a deleted switch."""
+    import re
+    from examl_tpu.ops import bank
+    ladder = {var for rung in sup.DEGRADE_LADDER for var in rung}
+    hatches = {hatch[0][0] for hatch in bank.FALLBACK_ENV.values()}
+    assert ladder and ladder <= hatches    # the copy pins nothing new
+    text = ""
+    for root, _dirs, files in os.walk(os.path.join(REPO, "examl_tpu")):
+        for name in files:
+            if name.endswith(".py") and name != "supervisor.py":
+                with open(os.path.join(root, name)) as f:
+                    text += f.read()
+    for var in sorted(ladder | hatches):
+        assert re.search(r'(\.get|getenv)\(\s*"%s"|environ\[\s*"%s"\s*\]'
+                         % (var, var), text), \
+            f"{var} is pinned but nothing under examl_tpu/ reads it"
 
 
 # -- supervisor plumbing (jax-free paths) -----------------------------------

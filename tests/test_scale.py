@@ -56,8 +56,7 @@ def test_tree_build_traverse_5k(caterpillar_newick):
 
 def test_flat_host_path_5k_smoke():
     """Non-slow synthetic host-path smoke (ISSUE 4): flat traversal +
-    vectorized structure build + z refresh at 5k taxa, checked against
-    the legacy per-entry schedule builder's layout."""
+    vectorized structure build + z refresh at 5k taxa."""
     import time
 
     import jax.numpy as jnp
@@ -75,18 +74,17 @@ def test_flat_host_path_5k_smoke():
     assert flat.n == N - 2
     assert int(flat.wave_sizes.sum()) == N - 2
     st = fastpath.build_structure(flat, N)
-    legacy = fastpath.build_schedule(flat.to_entries(), N, 1,
-                                     jnp.float32)
-    assert st.profile == legacy.profile
-    assert st.num_rows == legacy.num_rows
-    assert st.max_write == legacy.max_write
+    assert st.num_rows == N - 2 <= st.max_write
+    assert (np.sort(st.row_of[st.row_of >= 0]) == np.arange(N - 2)).all()
+    un, sc, total = fastpath.profile_stats(st.profile)
+    assert 1 <= un <= 256 and un + sc < total
+    assert fastpath.profile_slots(st.profile) == st.z_src.shape[0]
     t0 = time.time()
     for _ in range(3):
         f = tree.flat_full_traversal(p)
         zl, zr = fastpath.refresh_z(st, f, 1, jnp.float32)
     t_hit = (time.time() - t0) / 3
     # Padding slots carry z=1 (identity P), real slots the branch z.
-    import numpy as np
     zl_h = np.asarray(zl)
     assert (zl_h[st.z_src < 0] == 1.0).all()
     assert t_cold < 3.0, t_cold              # measured ~0.03 s
@@ -136,23 +134,34 @@ def test_native_newick_scanner_parity():
 @pytest.mark.slow
 def test_chunk_tier_50k_bounded_compile():
     """ISSUE 5 acceptance: the bounded chunk program at 50k synthetic
-    taxa stays under the 256-unrolled-block cap, compiles on CPU inside
-    the scale-lab budget (measured ~37 s vs tens of minutes unrolled),
-    and its lnL matches the scan tier (tools/scale_lab.py asserts the
-    same at the 5k smoke size in CI)."""
-    import os
-    import sys
+    taxa stays under the 256-unrolled-block cap, compiles on CPU
+    (measured ~37 s vs tens of minutes unrolled), and its lnL matches
+    the scan tier."""
+    import jax.numpy as jnp
 
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    import scale_lab
+    from examl_tpu.ops import fastpath
 
-    res = scale_lab.run_size(50_000, 64)
-    assert 1 <= res["program_chunks"] <= 256, res["program_chunks"]
-    assert res["dispatches_per_traversal"] < res["chunks"] / 5
-    assert res["lnl_fast"] is not None
-    assert abs(res["lnl"] - res["lnl_fast"]) <= max(
-        1e-6 * abs(res["lnl"]), 1e-3), (res["lnl"], res["lnl_fast"])
+    n = 50_000
+    rng = np.random.default_rng(7)
+    names = [f"t{i}" for i in range(n)]
+    lut = np.frombuffer(b"ACGT", dtype=np.uint8)
+    seqs = [bytes(row).decode()
+            for row in lut[rng.integers(0, 4, (n, 64), dtype=np.int8)]]
+    inst = PhyloInstance(build_alignment_data(names, seqs),
+                         dtype=jnp.float32)
+    tree = Tree.random(names, seed=1)
+    (eng,) = inst.engines.values()
+    eng.force_scan = True
+    lnl = inst.evaluate(tree, full=True)
+    eng.force_scan = False
+    lnl_fast = inst.evaluate(tree, full=True)
+    assert lnl_fast == inst.evaluate(tree, full=True)  # cached structure
+    (st,) = eng._sched_cache.values()
+    un, sc, total = fastpath.profile_stats(st.profile)
+    assert 1 <= un <= 256, un
+    assert un + sc < total / 5, (un, sc, total)
+    assert np.isfinite(lnl) and abs(lnl - lnl_fast) <= max(
+        1e-6 * abs(lnl), 1e-3), (lnl, lnl_fast)
 
 
 @pytest.mark.slow
@@ -160,8 +169,8 @@ def test_host_paths_50k_taxa_within_budget():
     """The host-side pipeline at 50k taxa (reference ambition ~120k,
     SURVEY §6) stays interactive: random-addition build is O(n) via the
     incremental branch list, and one full-tree fast-path schedule builds
-    in about half a second (measured 0.52-0.61 s warm; generous bounds
-    absorb CI host contention).  Spot-measured at 100k taxa (one-off,
+    in well under a second (generous bounds absorb CI host
+    contention).  Spot-measured at 100k taxa (one-off,
     2026-07): build 2.4 s, traversal 0.29 s, to_newick 1.67 s,
     from_newick 3.43 s, schedule 0.94 s — all linear in n."""
     import time
@@ -183,28 +192,24 @@ def test_host_paths_50k_taxa_within_budget():
     waves = Tree.schedule_waves(entries)
     t_waves = time.time() - t0
     assert sum(len(w) for w in waves) == n - 2
-    fastpath.build_schedule(entries, n, 1, jnp.float32)   # warm jax
-    t0 = time.time()
-    sched = fastpath.build_schedule(entries, n, 1, jnp.float32)
-    t_sched = time.time() - t0
-    assert len(sched.row_of) == n - 2
-    assert t_build < 5.0, t_build            # measured 0.56 s
-    assert t_trav < 2.0, t_trav              # measured 0.13 s
-    assert t_waves < 1.0, t_waves            # measured 0.02 s
-    assert t_sched < 3.0, t_sched            # measured 0.52-0.61 s
-    # The cached flat path (ISSUE 4 acceptance: >=5x on repeated
-    # fixed-topology traversals; SCALE.md measured 23x at 50k).
     p = tree.centroid_branch()
     if tree.is_tip(p.number):
         p = p.back
+    fastpath.build_structure(tree.flat_full_traversal(p), n)  # warm jax
+    t0 = time.time()
     flat = tree.flat_full_traversal(p)
     st = fastpath.build_structure(flat, n)
-    assert st.profile == fastpath.build_schedule(
-        flat.to_entries(), n, 1, jnp.float32).profile
+    t_sched = time.time() - t0
+    assert int((st.row_of >= 0).sum()) == n - 2
+    assert t_build < 5.0, t_build            # measured 0.56 s
+    assert t_trav < 2.0, t_trav              # measured 0.13 s
+    assert t_waves < 1.0, t_waves            # measured 0.02 s
+    assert t_sched < 3.0, t_sched
+    # The cached path: repeated fixed-topology traversals refresh z
+    # only.
     t0 = time.time()
     for _ in range(3):
         f = tree.flat_full_traversal(p)
         fastpath.refresh_z(st, f, 1, jnp.float32)
     t_hit = (time.time() - t0) / 3
-    t_legacy = t_trav + t_waves + t_sched
-    assert t_legacy / t_hit >= 5.0, (t_legacy, t_hit)
+    assert t_hit < 1.0, t_hit                # measured 0.05 s

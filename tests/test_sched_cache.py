@@ -151,8 +151,8 @@ def test_cached_vs_rebuilt_lnl_bit_identical(data16):
     inst2 = PhyloInstance(data16)
     tree2 = inst2.random_tree(1)
     assert inst2.evaluate(tree2, full=True) == lnl1
-    # Against the UNCACHED legacy entries path (per-entry
-    # build_schedule) on the same engine state: bit-identical.
+    # Against the scan tier, which an entry LIST takes even when full
+    # (one way into the fast tier: the flat form): bit-identical.
     inst3 = PhyloInstance(data16)
     tree3 = inst3.random_tree(1)
     s, entries = tree3.full_traversal_centroid()
@@ -160,6 +160,7 @@ def test_cached_vs_rebuilt_lnl_bit_identical(data16):
     vals = eng.traverse_evaluate(entries, s.number, s.back.number, s.z,
                                  full=True)
     assert float(np.sum(vals)) == lnl1
+    assert not any(k[0] == "fast" for k in eng._fast_jit_cache)
 
 
 def test_branch_length_change_hits_cache_correctly(data16):

@@ -4,13 +4,12 @@ The compiler for a v5e is installed in the CPU sandbox and compiles for a
 chip that is DESCRIBED, not attached (`jax.experimental.topologies`).
 Nothing runs, so these say nothing about results or times — only that the
 program the chip will be handed compiles at the smoke's real shapes, fits
-the device's memory, carries the collectives it should, and that a kernel
-Mosaic refuses is known to be refused.
+the device's memory and carries the collectives it should.
 
 The engine reads `jax.devices()` and would take its CPU branch, so each
 case builds a 140-taxon ONE-block f32 engine on CPU, takes the jitted
 body the engine would dispatch (steering the engine from here: the jit
-cache hands back the raw `jax.jit`, `use_pallas` is set by hand), and
+cache hands back the raw `jax.jit`), and
 lowers it with `ShapeDtypeStruct`s on the described device with the block
 axis scaled up to the real width.
 
@@ -35,8 +34,6 @@ from examl_tpu.ops import fastpath  # noqa: E402
 
 HBM_BYTES = 16 * 1000 ** 3        # one v5e chip: 16 GB (Cloud TPU docs)
 NTAXA = 140
-# What Mosaic says of the chunk kernel's [B, lane, R*K] row DMA today.
-MOSAIC_REFUSAL = "must be aligned to tiling (128), but is 16"
 
 
 @pytest.fixture(scope="module")
@@ -354,29 +351,3 @@ def test_newton_program_compiles(one_chip, chip_compile):
     compiled = jax.jit(eng._newton_impl).lower(
         *_as_shapes(eng, args, 128, lambda kind: one_chip)).compile()
     _fits(compiled)
-
-
-class _KnownRefusal(Exception):
-    """Mosaic refused the kernel with exactly today's message."""
-
-
-@pytest.mark.xfail(
-    strict=True, raises=_KnownRefusal,
-    reason="Mosaic failed to compile TPU kernel: Slice shape along "
-           "dimension 3 must be aligned to tiling (128), but is 16 — the "
-           "arena's minor dimension is R*K and the kernel DMAs whole "
-           "[B, lane, RK] rows; the PR that fixes the layout flips this")
-def test_pallas_chunk_kernel_compiles(one_chip, chip_compile):
-    """What EXAML_PALLAS=1 would hand the chip: the same chunk program
-    with `pallas_newview` kernels in it.  Strict xfail: passes the day
-    the kernel compiles, fails on any OTHER error than the known one."""
-    _, eng, _, p, flat, st = _one_block_engine("DNA")
-    eng.use_pallas = True              # what EXAML_PALLAS=1 sets on a TPU
-    assert not eng.pallas_interpret
-    fn, args = _chunk_eval_call(eng, p, flat, st)
-    try:
-        fn.lower(*_as_shapes(eng, args, 8, lambda kind: one_chip)).compile()
-    except Exception as e:
-        if MOSAIC_REFUSAL in str(e):
-            raise _KnownRefusal(str(e)[:400]) from e
-        raise

@@ -45,7 +45,7 @@ def _counter(name):
 
 def _eval(data, seed=3, env=None, force_scan=False, **kw):
     """Build an instance under optional env overrides (engines read
-    EXAML_UNIVERSAL / chunk-layout knobs at construction), evaluate a
+    EXAML_UNIVERSAL at construction), evaluate a
     random tree, restore the environment."""
     saved = {}
     for k, v in (env or {}).items():
@@ -93,14 +93,18 @@ def test_universal_per_partition_branches(sdata):
     assert lnl_u == lnl_c
 
 
-def test_universal_env_tuned_alphabet(sdata):
-    """An env-retuned width ladder (EXAML_CHUNK_MIN_WIDTH/CAP) changes
+def test_universal_env_tuned_alphabet(sdata, monkeypatch):
+    """A retuned width ladder (fastpath.MIN_WIDTH / CHUNK_CAP) changes
     the alphabet; the interpreter must key on it and stay bit-identical
-    to the specialized program under the same knobs."""
-    knobs = {"EXAML_CHUNK_MIN_WIDTH": "4", "EXAML_CHUNK_CAP": "64"}
-    _, _, lnl_u = _eval(sdata, env={**FORCE, **knobs})
-    _, _, lnl_c = _eval(sdata, env=knobs)
+    to the specialized program under the same constants."""
+    monkeypatch.setattr(fastpath, "MIN_WIDTH", 4)
+    monkeypatch.setattr(fastpath, "CHUNK_CAP", 64)
+    inst_u, _, lnl_u = _eval(sdata, env=FORCE)
+    _, _, lnl_c = _eval(sdata)
     assert lnl_u == lnl_c
+    (eng,) = inst_u.engines.values()
+    assert any(k[:2] == ("universal", (4, 64))
+               for k in eng._fast_jit_cache)
     assert universal.alphabet((4, 64)) != universal.alphabet((8, 1024))
     assert universal.alphabet((4, 64)) == ((0, 4), (1, 4), (2, 4))
     assert universal.width_ladder(4, 64) == (4, 8, 16, 32, 64)
@@ -157,28 +161,31 @@ def test_replay_padding_idempotent(sdata):
         p = p.back
     flat = tree.flat_full_traversal(p)
     n = inst.alignment.ntaxa
-    sch = fastpath.build_schedule(flat.to_entries(), n, 1, eng.dtype)
+    sch = fastpath.build_structure(flat, n)
+    zl_h, zr_h = (np.asarray(a) for a in fastpath.refresh_z(
+        sch, flat, 1, eng.dtype))
     knobs = eng._universal_akey()
     alpha = universal.alphabet(knobs)
-    table = universal.build_table(sch.profile, sch._host[0], knobs)
+    table = universal.build_table(sch.profile, np.asarray(sch.base), knobs)
     npad = bucket_len(table.n_chunks) + 8     # deliberately oversized
     ppad = bucket_len(table.slots) + 64
     cls, slot, base = universal.pad_table(table, npad)
-    base_h, li, ri, lc, rc, zl_h, zr_h = sch._host
-    idx = [universal.pad_slots(a, ppad) for a in (li, ri, lc, rc)]
+    idx = [universal.pad_slots(np.asarray(a), ppad)
+           for a in (sch.lidx, sch.ridx, sch.lcode, sch.rcode)]
     zl = jnp.asarray(universal.pad_slots(zl_h, ppad, fill=1), eng.dtype)
     zr = jnp.asarray(universal.pad_slots(zr_h, ppad, fill=1), eng.dtype)
     apply = fastpath.chunk_applier(eng.models, eng.block_part, eng.tips,
                                    eng.scale_exp, eng.fast_precision)
     c1, s1 = fastpath.run_chunks(
         eng.models, eng.block_part, eng.tips, jnp.array(eng.clv),
-        jnp.array(eng.scaler), sch.chunks, eng.scale_exp,
+        jnp.array(eng.scaler),
+        fastpath.structure_chunks(sch, zl_h, zr_h), eng.scale_exp,
         eng.fast_precision)
     c2, s2 = universal.run_universal(
         alpha, jnp.asarray(cls), jnp.asarray(slot), jnp.asarray(base),
         *(jnp.asarray(a) for a in idx), zl, zr, jnp.array(eng.clv),
         jnp.array(eng.scaler), apply.values)
-    rows = np.asarray(sorted(sch.row_of.values()))
+    rows = np.sort(sch.row_of[sch.row_of >= 0])
     assert (np.asarray(c1)[rows] == np.asarray(c2)[rows]).all()
     assert (np.asarray(s1)[rows] == np.asarray(s2)[rows]).all()
 
@@ -380,20 +387,6 @@ def test_pick_pads_reuses_compiled_buckets():
         (bucket_len(nb + 1), pb)
 
 
-def test_routing_gate_requires_bounded_layout(sdata):
-    """EXAML_BOUNDED_CHUNKS=0 (legacy unbounded layout) must disable
-    routing up front: the interpreter would decline every table and
-    the run would pay singleton groups AND per-profile compiles."""
-    from examl_tpu.fleet.driver import FleetDriver
-    os.environ["EXAML_BOUNDED_CHUNKS"] = "0"
-    try:
-        inst = PhyloInstance(sdata)
-        drv = FleetDriver(inst, batch_cap=4, route_universal=True)
-        assert not drv.route_universal
-    finally:
-        os.environ.pop("EXAML_BOUNDED_CHUNKS", None)
-
-
 # -- bank / ladder integration ----------------------------------------------
 
 
@@ -413,8 +406,8 @@ def test_bank_enumerates_universal_before_fast():
 
 
 def test_degradation_ladder_has_universal_rung():
-    """pallas -> chunk -> universal -> scan: the interpreter rung sits
-    between the chunk tier and the scan floor, and the floor pins the
+    """chunk -> universal -> scan: the interpreter rung sits between
+    the chunk tier and the scan floor, and the floor pins the
     interpreter OFF."""
     from examl_tpu.resilience import supervisor as sup
     rungs = list(sup.DEGRADE_LADDER)
@@ -422,8 +415,8 @@ def test_degradation_ladder_has_universal_rung():
                if r.get("EXAML_UNIVERSAL") == "force")
     scan = next(i for i, r in enumerate(rungs)
                 if r.get("EXAML_FAST_TRAVERSAL") == "0")
-    assert uni < scan
-    assert rungs[uni].get("EXAML_PALLAS") == "0"
+    assert (uni, scan) == (2, 3) and len(rungs) == 4
+    assert rungs[0] == rungs[1] == {}      # a plain retry comes first
     assert rungs[scan].get("EXAML_UNIVERSAL") == "0"
 
 
@@ -448,14 +441,14 @@ def test_ladder_floor_reached_within_retry_budget():
     st = Stub()
     st.max_retries = sup.DEFAULT_RETRIES
     sup.Supervisor._escalate(st, cause)
-    assert sup.DEGRADE_LADDER[st.degrade_level].get("EXAML_PALLAS") == "0"
-    assert "EXAML_FAST_TRAVERSAL" not in sup.DEGRADE_LADDER[st.degrade_level]
+    assert st.degrade_level == 1
+    assert sup.DEGRADE_LADDER[st.degrade_level] == {}
 
 
 def test_minted_buckets_track_resident_programs(sdata):
     """The bucket set `pick_pads` consults is DERIVED from the jit
-    cache, so every invalidation path — LRU eviction, the
-    Pallas-failure bulk clear, an env knob retune changing the
+    cache, so every invalidation path — LRU eviction, a bulk clear,
+    a retuned ladder changing the
     alphabet key — drops gone programs automatically (reusing a gone
     bucket would silently recompile at a padded size forever)."""
     inst = PhyloInstance(sdata)
@@ -473,7 +466,7 @@ def test_minted_buckets_track_resident_programs(sdata):
     eng.cache_put(("dummy", 0), lambda *a: None)   # evicts universal
     assert key not in eng._fast_jit_cache
     assert eng._universal_minted(akey, True) == set()
-    # ... and so does the Pallas-failure bulk clear.
+    # ... and so does a bulk clear.
     eng._fast_jit_cache_cap = 32
     inst.evaluate(inst.random_tree(3), full=True)
     assert eng._universal_minted(akey, True) == {pair}

@@ -2,8 +2,7 @@
 # Build the reference ExaML (AVX) and its parser as single-process binaries
 # using the single-rank MPI shim in tools/mpistub (no MPI in this image).
 # Produces /tmp/refexaml/examl-AVX and /tmp/refparser/parse-examl, used by
-# the golden-parity tests (tests/test_reference_parity.py) and the AVX
-# baseline measurement (tools/bench_reference.py).
+# the golden-parity tests (tests/test_reference_parity.py).
 set -euo pipefail
 
 REF=${REF:-/root/reference}

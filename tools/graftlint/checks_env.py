@@ -31,8 +31,8 @@ _ENV_NAME = re.compile(r"^EXAML_[A-Z0-9_]+$")
 
 
 def _documented(var: str, text: str) -> bool:
-    """Whole-token presence: EXAML_CHUNK must not pass because the text
-    contains EXAML_CHUNK_CAP (substring matching would make every
+    """Whole-token presence: EXAML_COMPILE must not pass because the text
+    contains EXAML_COMPILE_CACHE (substring matching would make every
     prefix of a documented name vacuously documented)."""
     return re.search(r"(?<![A-Z0-9_])" + re.escape(var) + r"(?![A-Z0-9_])",
                      text) is not None

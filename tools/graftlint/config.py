@@ -15,7 +15,7 @@ import fnmatch
 # corpora (GL004-GL006 diff against them) but are not themselves linted
 # — tests monkeypatch env vars, read private counters and exercise
 # hazards on purpose.
-LINT_ROOTS = ("examl_tpu", "tools", "bench.py")
+LINT_ROOTS = ("examl_tpu", "tools")
 EVIDENCE_TEST_ROOT = "tests"
 EVIDENCE_DOCS = ("README.md",)
 EVIDENCE_WORKFLOWS = (".github/workflows",)
@@ -47,8 +47,6 @@ SYNC_SEAMS = (
     # so the sync here IS the measurement.
     ("examl_tpu/ops/engine.py", "_run_fast_flat"),
     ("examl_tpu/ops/engine.py", "_universal_dispatch"),
-    ("examl_tpu/ops/engine.py", "_run_whole"),
-    ("examl_tpu/ops/engine.py", "_trav_eval_fast"),
     # Batched SPR scan/thorough scoring: one sync per candidate batch —
     # the candidate lnls ARE the selection input on the host.
     ("examl_tpu/ops/engine.py", "batched_scan"),

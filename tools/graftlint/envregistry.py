@@ -22,15 +22,6 @@ ENV_REGISTRY = {
     "EXAML_FAST_TRAVERSAL": {
         "doc": "readme",
         "note": "0 pins the scan tier for full traversals (ladder rung)."},
-    "EXAML_PALLAS": {
-        "doc": "readme",
-        "note": "unset/0 = XLA chunk tier (default); 1 asks for the "
-                "Mosaic chunk kernels, 'whole' for the whole-traversal "
-                "Pallas tier (a compiler refusal is an error)."},
-    "EXAML_PALLAS_INTERPRET": {
-        "doc": "readme",
-        "note": "1 runs Pallas kernels in interpret mode (CPU tests "
-                "only; refused on a TPU placement)."},
     "EXAML_BATCH_SCAN": {
         "doc": "readme",
         "note": "0 disables the batched SPR scan tier."},
@@ -44,9 +35,6 @@ ENV_REGISTRY = {
         "doc": "readme",
         "note": "0 opts out of the universal interpreter; force pins it "
                 "(the supervisor's chunk->scan ladder rung)."},
-    "EXAML_BOUNDED_CHUNKS": {
-        "doc": "readme",
-        "note": "0 restores the legacy unbounded chunk layout."},
     "EXAML_GRAD_SMOOTH": {
         "doc": "readme",
         "note": "0 restores the per-branch Newton smoothing path "
@@ -55,16 +43,6 @@ ENV_REGISTRY = {
         "doc": "readme",
         "note": "base step scale for gradient-mode branch smoothing "
                 "(default 1.0; the per-branch Rprop ladder caps at it)."},
-    # -- chunk layout knobs ----------------------------------------------
-    "EXAML_CHUNK_MIN_WIDTH": {
-        "doc": "readme",
-        "note": "bucketed-width ladder floor (default 8)."},
-    "EXAML_CHUNK_CAP": {
-        "doc": "readme",
-        "note": "bucketed-width ladder cap (default 1024)."},
-    "EXAML_CHUNK_TAIL_WIDTH": {
-        "doc": "readme",
-        "note": "scanned-tail normalization width."},
     # -- numerics ---------------------------------------------------------
     "EXAML_CLV_DTYPE": {
         "doc": "readme",
@@ -122,10 +100,6 @@ ENV_REGISTRY = {
     "EXAML_TRAFFIC_WINDOW_WALL_S": {
         "doc": "readme",
         "note": "min wall seconds per achieved-GB/s window."},
-    "EXAML_PEAK_FLOPS": {
-        "doc": "readme",
-        "note": "peak-FLOPs denominator override for bench efficiency "
-                "rows."},
     "EXAML_PROGRAM_OBS": {
         "doc": "readme",
         "note": "program observatory mode: deep (default: registry rows "
@@ -198,25 +172,6 @@ ENV_REGISTRY = {
                 "per-step compute: a dispatch-bound-only win, so "
                 "default off; fleet.universal_retrace counts the "
                 "forgone batching)."},
-    # -- bench harness -----------------------------------------------------
-    "EXAML_BENCH_T0": {
-        "doc": "registry",
-        "note": "bench budget epoch: children inherit the original "
-                "process's start time so spent wall counts against the "
-                "window budget."},
-    "EXAML_BENCH_BUDGET_S": {
-        "doc": "registry",
-        "note": "bench wall budget in seconds (driver-set)."},
-    "EXAML_BENCH_IGNORE_BANK": {
-        "doc": "readme",
-        "note": "1 runs bench stages even for bank-degraded families."},
-    "EXAML_BENCH_LARGE": {
-        "doc": "registry",
-        "note": "1 adds the large synthetic configs to the bench plan."},
-    "EXAML_BENCH_STRIP_PYTHONPATH": {
-        "doc": "registry",
-        "note": "1 strips PYTHONPATH from bench worker children "
-                "(hermetic-subprocess debugging aid)."},
     # -- tools -------------------------------------------------------------
     "EXAML_DEBUG_MODOPT": {
         "doc": "registry",

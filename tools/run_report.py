@@ -2,20 +2,18 @@
 """Render the roofline measurement report from a run's artifacts.
 
 The chip-window contract (ROADMAP §1, ROOFLINE.md "Measurement
-protocol"): every run — bench, CLI search, supervised gang — leaves a
-metrics snapshot, a run ledger and (for bench rounds) a BENCH json, and
-THIS tool turns them into the human report: per-tier achieved GB/s
-against the 306 GB/s roofline target with the dispatch-bound vs
-bandwidth-meaningful regime verdict, latency-histogram quantiles for
-the hot timers, and the merged event timeline.  The
-BENCH_r06 rows flow through here; a window that produced artifacts but
-no report is a window half wasted.
+protocol"): every run — CLI search, supervised gang — leaves a
+metrics snapshot and a run ledger, and THIS tool turns them into the
+human report: per-tier achieved GB/s against the 306 GB/s roofline
+target with the dispatch-bound vs bandwidth-meaningful regime verdict,
+latency-histogram quantiles for the hot timers, and the merged event
+timeline.
 
     python tools/run_report.py --metrics m.json [--ledger DIR|FILE]
-                               [--bench BENCH_r06.json] [--timeline N]
+                               [--bench FLEET_BENCH.json] [--timeline N]
 
 stdlib-only (plus the jax-free examl_tpu.obs helpers): runnable on any
-host, including the bench parent's no-backend environment.
+host with no backend.
 """
 
 from __future__ import annotations
@@ -33,10 +31,10 @@ from examl_tpu.obs import ledger as _ledger      # noqa: E402
 from examl_tpu.obs import traffic as _traffic    # noqa: E402
 
 # Timers whose quantiles the report always surfaces when present
-# (ISSUE: dispatch, host_schedule, compile families, CLI phases, the
-# bench/perf-lab stopwatches and the bank compile/warm phases).
-_KEY_TIMER_PREFIXES = ("dispatch", "host_schedule", "bench.",
-                       "perf_lab.", "bank.compile.", "bank.warm.",
+# (ISSUE: dispatch, host_schedule, compile families, CLI phases and
+# the bank compile/warm phases).
+_KEY_TIMER_PREFIXES = ("dispatch", "host_schedule",
+                       "bank.compile.", "bank.warm.",
                        "bank.export_load_seconds",
                        "bank.export_write_seconds",
                        "engine.compile_seconds.", "engine.grad_pass",
@@ -94,25 +92,6 @@ def tier_rows_from_metrics(snap: dict) -> list:
     return rows
 
 
-def tier_rows_from_bench(bench: dict) -> list:
-    """[(label, gbps, regime, source, drift)] from a BENCH json's
-    per-stage fields (bench rows carry the analytic model's bytes —
-    source "model" by construction)."""
-    rows = []
-    if bench.get("achieved_gbps") is not None:
-        rows.append((f"small/{bench.get('traversal_variant', '?')}",
-                     float(bench["achieved_gbps"]),
-                     bench.get("regime", "?"), None, None))
-    for key, val in sorted(bench.items()):
-        if key.endswith("_achieved_gbps") and val is not None:
-            pre = key[:-len("_achieved_gbps")]
-            rows.append((f"{bench.get(pre + '_config', pre)}"
-                         f"/{bench.get(pre + '_variant', '?')}",
-                         float(val), bench.get(pre + "_regime", "?"),
-                         None, None))
-    return rows
-
-
 def render_roofline(out, rows: list, source: str) -> None:
     target = _traffic.ROOFLINE_TARGET_GBPS
     out(f"Roofline ({source}; target {target:.0f} GB/s sustained "
@@ -149,23 +128,13 @@ def _fmt_bytes(v) -> str:
     return f"{v:.0f}"
 
 
-def program_rows(snap: dict, bench: dict = None) -> list:
-    """The observatory table embedded in a metrics snapshot (or, for
-    BENCH artifacts, in the workers' merged registry)."""
-    rows = snap.get("programs") or []
-    if not rows and bench:
-        rows = (bench.get("programs")
-                or (bench.get("metrics") or {}).get("programs") or [])
-    return rows
-
-
-def render_programs(out, snap: dict, bench: dict = None) -> None:
+def render_programs(out, snap: dict) -> None:
     """The Programs table (obs/programs.py): one row per compiled or
     deserialized executable with its compile source and the compiler's
     own cost/memory accounting — the memory column is XLA's structural
     peak (argument+output+temp), the figure the analytic model cannot
     provide."""
-    rows = program_rows(snap, bench)
+    rows = snap.get("programs") or []
     if not rows:
         return
     out("")
@@ -618,31 +587,14 @@ def render(metrics: dict, events: list, bench: dict,
             f"{bench.get('target_speedup')}x = 0.7*N, "
             + ("MET" if bench.get("meets_target") else "not met")
             + f"; occupancy {bench.get('batch_occupancy')})")
-    elif bench:
-        if rows:
-            out("")
-        render_roofline(out, tier_rows_from_bench(bench), "BENCH rows")
-        vb = bench.get("vs_baseline")
-        out(f"  headline: {bench.get('value', 0):.3g} updates/s on "
-            f"{bench.get('backend', '?')} = {vb}x one AVX socket "
-            + ("(VALID vs baseline)" if bench.get("vs_baseline_valid")
-               else "(NOT comparable: fallback backend)"))
-        if bench.get("pallas_validated") is not None:
-            out(f"  pallas_validated: {bench['pallas_validated']}")
     if not rows and not bench:
         render_roofline(out, [], "no artifact")
     render_timers(out, metrics)
-    render_programs(out, metrics, bench)
+    render_programs(out, metrics)
     render_memory(out, metrics)
     render_bank(out, metrics)
     render_fleet(out, metrics, events)
     render_counters(out, metrics)
-    # Bench artifacts embed the workers' merged registry under
-    # "metrics"; surface its timers too when the standalone snapshot
-    # lacks them.
-    if bench and not metrics.get("timers") and bench.get("metrics"):
-        render_timers(out, bench["metrics"])
-        render_counters(out, bench["metrics"])
     render_timeline(out, events, timeline)
 
 
@@ -753,8 +705,8 @@ def diff_snapshots(old: dict, new: dict, out=print,
     # Program table: per-family compiler-truth bytes must be stable
     # between comparable runs — a moved bytes_accessed is a program
     # (or model) change arriving with its cause attached.
-    op_rows = {r.get("family"): r for r in program_rows(old)}
-    np_rows = {r.get("family"): r for r in program_rows(new)}
+    op_rows = {r.get("family"): r for r in old.get("programs") or []}
+    np_rows = {r.get("family"): r for r in new.get("programs") or []}
     fams = sorted(set(op_rows) | set(np_rows))
     if fams:
         out("  programs (bytes_accessed per family):")
@@ -789,8 +741,8 @@ def main(argv=None) -> int:
     ap.add_argument("--ledger", default=None,
                     help="ledger directory, merged file, or rank file")
     ap.add_argument("--bench", default=None,
-                    help="BENCH_r*.json artifact (the bench.py output "
-                         "line saved to a file)")
+                    help="FLEET_BENCH json (tools/fleet_smoke.py's "
+                         "output line saved to a file)")
     ap.add_argument("--timeline", type=int, default=60,
                     help="max timeline events to print (default 60)")
     ap.add_argument("--diff", nargs=2, metavar=("OLD", "NEW"),
