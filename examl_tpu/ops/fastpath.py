@@ -20,6 +20,12 @@ and XLA actually reward:
   arena, so every write is a `dynamic_update_slice` that XLA performs
   in place — the `.at[].set` scatter inside scan was measured to copy
   the whole CLV buffer every step (half the runtime).
+* Inner children's rows and scalers are read through
+  `kernels.take_rows`: `arena[idx]` while a row is at most 128 blocks
+  wide, one dynamic slice a row above that, where the v5e compiler
+  would cut the gather into pieces by slicing the whole arena — a copy
+  of the arena a chunk (PERF.md §6, PR 32 and 34).  The values are
+  `arena[idx]`'s bit for bit either way.
 
 Program-size discipline (the BEAGLE lesson: library-scale phylogenetics
 lives or dies on operation scheduling cost, not FLOPs).  A naive
@@ -592,11 +598,15 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
 
     def inner_child(p, idx, clv, B, lane, RK):
         # block-diagonal (r,k)->(r,a) contraction: exact same arithmetic
-        # as per-rate P application, one MXU-friendly [RK,RK] dot.
+        # as per-rate P application, one MXU-friendly [RK,RK] dot.  The
+        # rows come by index (take_rows), never by a gather wider than
+        # the compiler takes in one piece; a narrower arena (bf16) is
+        # cast after the read.
         W = idx.shape[0]
         pb = jnp.einsum("wmrak,rs->wmrksa", p, eyeR).reshape(W, M, RK, RK)
         pb = pb[:, block_part]                              # [W,B,RK,RK]
-        x = clv[idx].astype(cdt).reshape(W, B, lane, RK)
+        x = kernels.take_rows(clv, idx).astype(cdt)
+        x = x.reshape(W, B, lane, RK)
         return jax.lax.dot_general(x, pb,
                                    (((3,), (2,)), ((0, 1), (0, 1))),
                                    precision=precision)
@@ -622,11 +632,12 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
         elif ch.kind == 1:
             yl = tip_child(pl, ch.lcode, B, RK)
             yr = inner_child(pr, ch.ridx, clv, B, lane, RK)
-            sc = scaler[ch.ridx]
+            sc = kernels.take_rows(scaler, ch.ridx)
         else:
             yl = inner_child(pl, ch.lidx, clv, B, lane, RK)
             yr = inner_child(pr, ch.ridx, clv, B, lane, RK)
-            sc = scaler[ch.lidx] + scaler[ch.ridx]
+            sc = (kernels.take_rows(scaler, ch.lidx)
+                  + kernels.take_rows(scaler, ch.ridx))
         v = yl * yr                                         # [W,B,lane,RK]
         needs = jnp.max(jnp.abs(v), axis=3) < minlik
         v = jnp.where(needs[..., None], v * two_e, v)

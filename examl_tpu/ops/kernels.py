@@ -117,7 +117,9 @@ def tip_partials(tips: TipState, tip_idx: jax.Array) -> jax.Array:
     gradient program's loops (PERF.md §6, PR 29).  `masks[tip_idx]` is a
     gather of whole contiguous rows of a byte a site: cheap at every
     width (131 KB a row at 131,072 patterns, never split), as a CLV
-    row's gather is only up to 128 blocks (`take_rows`)."""
+    or scaler row's gather is only up to 128 blocks: those rows, in the
+    gradient pass and in the traversal alike, are read through
+    `take_rows`."""
     K = tips.table.shape[1]
     masks = tips.masks[tip_idx]                      # [..., B, lane]
     bits = (masks[..., None] >> jnp.arange(K, dtype=masks.dtype)) & 1
@@ -146,6 +148,12 @@ def take_rows(arena: jax.Array, idx: jax.Array) -> jax.Array:
     """`arena[idx]`, bit for bit: whole rows [B, lane, ...] of an arena
     [rows, B, lane, ...] at in-range row indices idx [...].
 
+    Every read of whole arena rows inside a device loop comes here: the
+    gradient pass (`outroot_pass`, `gather_child`,
+    `gradient.edge_gradients`; PR 32) and the traversal
+    (`fastpath.chunk_applier`'s child rows and scalers, PR 34; the scan
+    tier through `gather_child`).
+
     Up to `ONE_PIECE_SITES` sites a row it IS `arena[idx]`, a gather
     the compiler runs near the HBM roofline.  A wider gather the v5e
     compiler cuts into B/128 pieces by slicing its OPERAND: every piece
@@ -156,7 +164,14 @@ def take_rows(arena: jax.Array, idx: jax.Array) -> jax.Array:
     [len(idx), B, lane, ...] block: the rows' bytes and no more.  The
     form follows from the arena's shape alone.  (PERF.md §6, PR 32:
     the loop costs a 1 MiB row 2 us more than its gather; unrolled,
-    the slices cost a relayout copy of each whole arena.)"""
+    the slices cost a relayout copy of each whole arena.)
+
+    Under GSPMD (the site-sharded traversal programs) the shape seen
+    here is the GLOBAL one, so a four-chip run between 16,385 and
+    65,536 global patterns takes the loop where a shard's row is one
+    piece and its gather would do: 2 us a row, no cell is there, no
+    knob for it.  Inside `shard_map` (the gradient pass) it is the
+    shard's."""
     idx = jnp.asarray(idx)
     if idx.ndim == 0 or arena.shape[1] * arena.shape[2] <= ONE_PIECE_SITES:
         return arena[idx]

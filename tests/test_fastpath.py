@@ -260,6 +260,102 @@ def test_segment_program_matches_unrolled_bitwise(datatype):
     assert (s1[rows] == np.asarray(s2)[rows]).all()
 
 
+# -- arena rows read by index (kernels.take_rows) ----------------------------
+
+
+def _traversed(data, executor, monkeypatch, arena):
+    """(lnL or None, real arena rows, their scalers) of a full traversal
+    of one f32 engine through `executor`: the chunk tier's segment
+    program, the unrolled `run_chunks`, or the universal interpreter."""
+    monkeypatch.setenv("EXAML_CLV_DTYPE",
+                       "bf16" if arena == "bfloat16" else "same")
+    monkeypatch.setenv("EXAML_UNIVERSAL",
+                       "force" if executor == "universal" else "0")
+    inst = PhyloInstance(data, dtype=jnp.float32)
+    (eng,) = inst.engines.values()
+    assert eng.B > 1 and eng.clv.dtype == jnp.dtype(arena)
+    tree = inst.random_tree(3)
+    p = tree.centroid_branch()
+    if executor == "chunks":
+        flat = tree.flat_full_traversal(p.back if tree.is_tip(p.number)
+                                        else p)
+        st, _, _, clv, sc = _unrolled(eng, flat, inst.alignment.ntaxa)
+        rows = np.sort(st.row_of[st.row_of >= 0])
+        return None, clv[rows].astype(np.float32), sc[rows]
+    lnl = inst.evaluate(tree, full=True)
+    assert eng._last_universal == (executor == "universal")
+    return (lnl, np.asarray(eng.clv.astype(jnp.float32)),
+            np.asarray(eng.scaler))
+
+
+@pytest.mark.parametrize("executor", ["segments", "chunks", "universal"])
+@pytest.mark.parametrize("arena", ["float32", "bfloat16"])
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_rows_read_by_index_bitwise_equal_row_gather(datatype, arena,
+                                                     executor, monkeypatch):
+    """`chunk_applier` reads child rows and scalers through
+    `kernels.take_rows`.  Forced to its by-index form (what an arena
+    wider than 128 blocks takes), every executor of the chunk layout
+    leaves the arena, the scalers and lnL that the parent's `clv[idx]`
+    and `scaler[idx]` leave, bit for bit; a bf16 arena still casts after
+    the read."""
+    from examl_tpu.ops import kernels
+    data = _synth(width=300, datatype=datatype)
+    monkeypatch.setattr(kernels, "ONE_PIECE_SITES", 0)
+    lnl, clv, sc = _traversed(data, executor, monkeypatch, arena)
+    # a fresh instance traces its programs anew, through the gather
+    monkeypatch.setattr(kernels, "take_rows", lambda a, idx: a[idx])
+    lnl_old, clv_old, sc_old = _traversed(data, executor, monkeypatch, arena)
+    assert np.isfinite(clv).all() and np.abs(clv).max() > 0
+    assert np.array_equal(clv, clv_old) and np.array_equal(sc, sc_old)
+    assert lnl == lnl_old and (lnl is None or np.isfinite(lnl))
+
+
+@pytest.mark.parametrize("blocks, gathered", [(128, True), (129, False),
+                                              (1024, False)])
+def test_chunk_program_gathers_no_wide_arena(blocks, gathered):
+    """Structural: above 128 blocks a row the chunk program, walked
+    through its scans, holds no gather of the rank-5 CLV arena nor of
+    the rank-3 scaler; at 128 blocks it holds the parent's (one a read:
+    the compiler runs those in one piece)."""
+    import jax
+
+    from tests.test_gradients import _gathers
+
+    inst = PhyloInstance(_synth(), dtype=jnp.float32)
+    tree = inst.random_tree(3)
+    (eng,) = inst.engines.values()
+    assert eng.B == 1
+    flat = tree.flat_full_traversal(tree.centroid_branch())
+    st = fastpath.build_structure(flat, inst.alignment.ntaxa)
+    zl, zr = fastpath.refresh_z(st, flat, eng.num_branch_slots, eng.dtype)
+
+    def wide(a, axis):
+        shape = a.shape[:axis] + (blocks,) + a.shape[axis + 1:]
+        return jax.ShapeDtypeStruct(shape, a.dtype)
+
+    def program(clv, scaler, block_part, tips):
+        apply = fastpath.chunk_applier(eng.models, block_part, tips,
+                                       eng.scale_exp, eng.fast_precision)
+        return fastpath.run_segments(
+            st.profile, st.base, st.lidx, st.ridx, st.lcode, st.rcode,
+            zl, zr, clv, scaler, apply)
+
+    tips = eng.tips._replace(codes=wide(eng.tips.codes, 1),
+                             masks=wide(eng.tips.masks, 1))
+    clv, scaler = wide(eng.clv, 1), wide(eng.scaler, 1)
+    jaxpr = jax.make_jaxpr(program)(clv, scaler, wide(eng.block_part, 0),
+                                    tips)
+    shapes = [tuple(e.invars[0].aval.shape) for e in _gathers(jaxpr.jaxpr)]
+    assert tuple(tips.codes.shape) in shapes, "the walk lost the tips"
+    # inner children a chunk = its kind; a scan group's body is traced once
+    reads = sum(seg[1] if seg[0] == "u" else sum(k for k, _ in seg[2])
+                for seg in st.profile)
+    assert reads > 0 and tips.codes.shape != scaler.shape
+    arenas = [s for s in shapes if s in (clv.shape, scaler.shape)]
+    assert len(arenas) == (2 * reads if gathered else 0), shapes
+
+
 def test_wave_resplit_preserves_arena_rows(sdata):
     """Property: entries within a wave are independent, so any valid
     re-split/reorder of the waves (here: random within-wave entry

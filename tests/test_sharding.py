@@ -286,6 +286,29 @@ def test_sharded_gradients_read_by_index_bitwise(problem, monkeypatch):
     assert np.array_equal(a1, b1) and np.array_equal(a2, b2)
 
 
+def test_sharded_traversal_reads_by_index_bitwise(problem, monkeypatch):
+    """The chunk program under GSPMD reads child rows and scalers by
+    index (`fastpath.chunk_applier` through `kernels.take_rows`) where
+    the global row is wider than one piece: the sharded arena, its
+    scalers and lnL equal, bit for bit, those of a sharded engine that
+    gathers them."""
+    from examl_tpu.ops import kernels
+    data, _, newick, _ = problem
+    got = []
+    for one_piece in (0, kernels.ONE_PIECE_SITES):
+        monkeypatch.setattr(kernels, "ONE_PIECE_SITES", one_piece)
+        inst = PhyloInstance(data, block_multiple=4,
+                             sharding=MESHES["mesh4"]())
+        (eng,) = inst.engines.values()
+        lnl = inst.evaluate(inst.tree_from_newick(newick), full=True)
+        assert eng._dispatch_tier(True) == "chunk"
+        assert len(eng.clv.sharding.device_set) == 4
+        got.append((lnl, np.asarray(eng.clv), np.asarray(eng.scaler)))
+    (lnl_a, clv_a, sc_a), (lnl_b, clv_b, sc_b) = got
+    assert np.isfinite(lnl_a) and lnl_a == lnl_b
+    assert np.array_equal(clv_a, clv_b) and np.array_equal(sc_a, sc_b)
+
+
 def test_sharded_tree_evaluate_makes_gradient_passes(sharded):
     """`tree_evaluate` on a site-sharded instance smooths with
     whole-tree gradient passes (O(1) dispatches a sweep, no fallback, no

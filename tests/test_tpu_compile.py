@@ -207,6 +207,18 @@ def _reads_rows_by_index(compiled, arena_elems: int) -> None:
     assert _arena_sized_in_loops(text, arena_elems) == []
 
 
+def _one_all_reduce_outside_loops(text: str) -> None:
+    """The program's one cross-shard collective is an all-reduce that
+    runs once a call: counted in the text, it is one a call only while
+    none sits in a loop."""
+    n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
+    assert n_reduce == 1, n_reduce
+    assert " while(" in text and collectives_in_loops(text) == 0
+    for other in ("all-gather", "all-to-all", "collective-permute",
+                  "reduce-scatter"):
+        assert f" {other}(" not in text and f" {other}-start(" not in text
+
+
 def test_chunk_evaluate_program_140x131072_dna(one_chip, chip_compile):
     """The fullwidth phase's first program: full traversal (XLA chunk
     tier) + root evaluation at 140 x 131,072 DNA patterns, f32."""
@@ -248,17 +260,18 @@ def test_gradient_pass_140x16384_protein(one_chip, chip_compile):
     _reads_rows_by_index(compiled, eng.num_rows * 128 * 128 * 80)
 
 
-@pytest.mark.xfail(
-    strict=True, raises=AssertionError,
-    reason="fastpath.inner_child reads clv[idx] by gather: 56 operand "
-           "slices in the chunk+evaluate program at 131,072 patterns, a "
-           "copy of the arena a chunk (PERF.md section 7); the PR that "
-           "reads them through kernels.take_rows flips this")
 def test_chunk_evaluate_program_reads_rows_by_index(one_chip, chip_compile):
+    """`fastpath.chunk_applier` reads its child rows and scalers through
+    `kernels.take_rows`: at 131,072 patterns the chunk+evaluate program
+    holds no operand slice (56 while `clv[idx]` was a gather) and no
+    copy of the arena in a loop."""
     _, eng, _, p, flat, st = _one_block_engine("DNA")
     fn, args = _chunk_eval_call(eng, p, flat, st)
     compiled = fn.lower(*_as_shapes(eng, args, 1024,
                                     lambda kind: one_chip)).compile()
+    # 1.77 GB while the rows were gathered (one copy of the arena),
+    # 0.54 GB read by index
+    assert _fits(compiled)["temporaries"] < 1.0e9
     _reads_rows_by_index(compiled, eng.num_rows * 1024 * 128 * 16)
 
 
@@ -288,12 +301,31 @@ def test_site_sharded_chunk_program_one_all_reduce(topo, chip_compile):
     # per-device bytes: a quarter of the 140 x 16,384 arena, not all
     arena = eng.num_rows * 128 * 128 * 16 * 4
     assert sizes["arguments"] < arena // 2
-    text = compiled.as_text()
-    n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
-    assert n_reduce == 1, n_reduce
-    assert collectives_in_loops(text) == 0       # and it runs once a call
-    for other in ("all-gather", "all-to-all", "collective-permute"):
-        assert f" {other}(" not in text and f" {other}-start(" not in text
+    _one_all_reduce_outside_loops(compiled.as_text())
+
+
+def test_site_sharded_chunk_program_262144_reads_rows_by_index(
+        topo, chip_compile):
+    """The four-chip deployment's traversal: the chunk+evaluate program
+    at 140 x 262,144 DNA with the block axis sharded four ways (GSPMD).
+    The program sees the GLOBAL 2,048 blocks, so `take_rows` reads by
+    index, and a shard's rows (512 blocks) are cut into no operand
+    slices (28 a chip while they were gathered); the root lnL sum stays
+    the one collective, outside every loop.  The 16,384 case above takes
+    the gather form and cannot see this."""
+    from examl_tpu.parallel.sharding import make_mesh, site_sharding
+    sh = site_sharding(make_mesh(devices=topo.devices[:4]))
+    _, eng, _, p, flat, st = _one_block_engine("DNA")
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    blocks = 262144 // 128
+    compiled = fn.lower(*_as_shapes(
+        eng, args, blocks, lambda kind: getattr(sh, kind))).compile()
+    sizes = _fits(compiled)
+    arena = eng.num_rows * blocks * 128 * 16 * 4
+    assert sizes["arguments"] < arena // 2       # a quarter and the tips
+    assert sizes["temporaries"] < 0.5e9          # 0.27 GB a chip by index
+    _reads_rows_by_index(compiled, eng.num_rows * (blocks // 4) * 128 * 16)
+    _one_all_reduce_outside_loops(compiled.as_text())
 
 
 def test_site_sharded_gradient_program_262144_one_all_reduce(
@@ -324,14 +356,9 @@ def test_site_sharded_gradient_program_262144_one_all_reduce(
     _reads_rows_by_index(compiled, eng.num_rows * (blocks // 4) * 128 * 16)
     text = compiled.as_text()
     assert "jit__grad_impl" in text.split("\n", 1)[0]   # the trace's name
-    n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
-    assert n_reduce == 1, n_reduce
     # one in the text is one a pass only outside every loop: moved into
     # the chunk loop it would still count one here and run once a chunk
-    assert " while(" in text and collectives_in_loops(text) == 0
-    for other in ("all-gather", "all-to-all", "collective-permute",
-                  "reduce-scatter"):
-        assert f" {other}(" not in text and f" {other}-start(" not in text
+    _one_all_reduce_outside_loops(text)
 
 
 def test_newton_program_compiles(one_chip, chip_compile):
