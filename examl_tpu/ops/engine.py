@@ -1541,6 +1541,7 @@ class LikelihoodEngine:
                                           self.sharding.scaler)
             self._scan_cap += grow
             self.num_rows += grow
+            obs.gauge("engine.scan_rows", self._scan_cap)
         return self._scan_base
 
     @staticmethod

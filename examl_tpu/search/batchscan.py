@@ -24,7 +24,12 @@ scan is restructured around directional CLVs:
 
 One jitted program per shape bucket runs the uppass traversal AND the
 batched scoring: one device dispatch per pruned node, versus
-O(candidates) round-trips in the reference.
+O(candidates) round-trips in the reference.  The two programs' XLA
+modules are `jit_spr_scan_impl` (lazy arm) and `jit_spr_thorough_impl`
+(thorough arm): names of their own, so a device trace tells them from
+the full-traversal family `jit_impl` / `jit_impl_eval`; their scoring
+operations trace under the named scopes `examl/spr_score` and
+`examl/spr_thorough`.
 
 The candidate SET matches `addTraverseBIG`'s full radius window; the
 reference's lnL-cutoff additionally skips descendants of bad branches
@@ -42,6 +47,7 @@ import numpy as np
 
 from examl_tpu import obs
 from examl_tpu.constants import DEFAULTZ, DELTAZ, ZMAX, ZMIN
+from examl_tpu.obs.traffic import count_tip_children
 from examl_tpu.tree.topology import Node, Tree
 
 
@@ -200,6 +206,34 @@ def plan_for_endpoints(inst, tree: Tree, p: Node, q1: Node, q2: Node,
                     s_num=subtree_root.number, zp=_zt(p.z))
 
 
+def _count_dispatch(inst, plan: ScanPlan, thorough: bool = False) -> None:
+    """The counters of one scan dispatch, by what it carries: entries
+    (orientation fixes and uppass rows), the tip children among their
+    operands, candidates, and the tips among the scoring operands (a
+    candidate's far end, the pruned subtree's root): rows a byte a site
+    where the others are CLV rows.  `search.scan_*` count both arms,
+    `search.thorough_*` the thorough arm's share of them."""
+    ntips = next(iter(inst.engines.values())).ntips
+    tip_children = count_tip_children(plan.down_entries, ntips) + sum(
+        kind == "node" and v <= ntips for e in plan.up_entries
+        for kind, v in (e.left, e.right))
+    tip_operands = (plan.s_num <= ntips) + sum(
+        c.q_num <= ntips for c in plan.candidates)
+    n_cand = len(plan.candidates)
+    n_entries = len(plan.down_entries) + len(plan.up_entries)
+    obs.inc("search.scan_dispatches")
+    obs.inc("search.scan_candidates", n_cand)
+    obs.inc("search.scan_entries", n_entries)
+    obs.inc("search.scan_tip_children", tip_children)
+    obs.inc("search.scan_tip_operands", tip_operands)
+    if thorough:
+        obs.inc("search.thorough_dispatches")
+        obs.inc("search.thorough_candidates", n_cand)
+        obs.inc("search.thorough_entries", n_entries)
+        obs.inc("search.thorough_tip_children", tip_children)
+        obs.inc("search.thorough_tip_operands", tip_operands)
+
+
 def run_plan(inst, tree: Tree, plan: ScanPlan) -> np.ndarray:
     """Execute the plan; returns per-candidate total lnL [N].
 
@@ -207,8 +241,7 @@ def run_plan(inst, tree: Tree, plan: ScanPlan) -> np.ndarray:
     ONE device program per engine — one dispatch per pruned node.
     """
     N = len(plan.candidates)
-    obs.inc("search.scan_dispatches")
-    obs.inc("search.scan_candidates", N)
+    _count_dispatch(inst, plan)
     total = np.zeros(N, dtype=np.float64)
     with obs.span("search:spr_batched_scan", args={"candidates": N}):
         for eng in inst.engines.values():
@@ -246,8 +279,8 @@ def scan_program(eng, n_chunks: int):
     ntips = eng.ntips
     psr = eng.psr
 
-    def impl(clv, scaler, aux, tv, qg, upg, zc, sg, zp, dm, block_part,
-             weights, tips, sr_rates):
+    def spr_scan_impl(clv, scaler, aux, tv, qg, upg, zc, sg, zp, dm,
+                      block_part, weights, tips, sr_rates):
         clv, scaler = eng._traverse_kernel(clv, aux, scaler, tv, dm,
                                            block_part, tips, sr_rates)
         xs, ss = eng._gather(clv, aux, scaler, sg, tips)
@@ -263,6 +296,7 @@ def scan_program(eng, n_chunks: int):
         acc = kernels._acc_dtype(cdt)
         _, _, log_min = kernels.scale_constants(acc, scale_exp)
 
+        @jax.named_scope("examl/spr_score")
         def chunk(carry, args):
             qg_c, upg_c, z_c = args                       # [T], [T], [T,C]
             xq, sq = eng._gather(clv, aux, scaler, qg_c, tips)
@@ -307,13 +341,13 @@ def scan_program(eng, n_chunks: int):
         v = eng._site_spec_vocab()
         REP = v["rep"]
         fn = v["wrap"](
-            impl,
+            spr_scan_impl,
             (v["pool"], v["scaler"], v["aux"], v["traversal"], REP, REP,
              REP, REP, REP, v["models"], v["blocks"], v["sites"],
              v["tips"], v["sr"]),
             (v["pool"], v["scaler"], REP), donate=(0, 1))
     else:
-        fn = jax.jit(impl, donate_argnums=(0, 1))
+        fn = jax.jit(spr_scan_impl, donate_argnums=(0, 1))
     return eng.cache_put(key, fn)
 
 
@@ -367,8 +401,8 @@ def thorough_program(eng, n_chunks: int):
     psr = eng.psr
     lzmax = float(np.log(ZMAX))
 
-    def impl(clv, scaler, aux, tv, qg, upg, zq0, sg, dm, block_part,
-             weights, tips, sr_rates):
+    def spr_thorough_impl(clv, scaler, aux, tv, qg, upg, zq0, sg, dm,
+                          block_part, weights, tips, sr_rates):
         clv, scaler = eng._traverse_kernel(clv, aux, scaler, tv, dm,
                                            block_part, tips, sr_rates)
         xs, ss = eng._gather(clv, aux, scaler, sg, tips)
@@ -453,6 +487,7 @@ def thorough_program(eng, n_chunks: int):
                           * (jnp.log(lsite).astype(acc) + sc * log_min))
             return lnl, e1, e2, e3
 
+        @jax.named_scope("examl/spr_thorough")
         def chunk(carry, args):
             qg_c, upg_c, z0_c = args
             xq, sq = eng._gather(clv, aux, scaler, qg_c, tips)
@@ -474,13 +509,13 @@ def thorough_program(eng, n_chunks: int):
         v = eng._site_spec_vocab()
         REP = v["rep"]
         fn = v["wrap"](
-            impl,
+            spr_thorough_impl,
             (v["pool"], v["scaler"], v["aux"], v["traversal"], REP, REP,
              REP, REP, v["models"], v["blocks"], v["sites"], v["tips"],
              v["sr"]),
             (v["pool"], v["scaler"], REP, REP), donate=(0, 1))
     else:
-        fn = jax.jit(impl, donate_argnums=(0, 1))
+        fn = jax.jit(spr_thorough_impl, donate_argnums=(0, 1))
     return eng.cache_put(key, fn)
 
 
@@ -491,8 +526,7 @@ def run_plan_thorough(inst, tree: Tree, plan: ScanPlan
     Single-engine, single-branch-slot instances only (the caller
     gates); the padding/chunk/dispatch plumbing lives on the engine
     next to the lazy arm's (`LikelihoodEngine.batched_thorough`)."""
-    obs.inc("search.scan_dispatches")
-    obs.inc("search.scan_candidates", len(plan.candidates))
+    _count_dispatch(inst, plan, thorough=True)
     (eng,) = inst.engines.values()
     with obs.span("search:spr_batched_thorough",
                   args={"candidates": len(plan.candidates)}):

@@ -69,10 +69,11 @@ def tree_optimize_rapid(inst: PhyloInstance, tree: Tree, ctx: SprContext,
                                     best_ml, ilist)
 
 
-def _tree_optimize_rapid(inst: PhyloInstance, tree: Tree, ctx: SprContext,
-                         mintrav: int, maxtrav: int,
-                         bt: BestList, best_ml: Optional[BestList],
-                         ilist: InfoList) -> float:
+def spr_cycle_head(inst: PhyloInstance, tree: Tree, ctx: SprContext,
+                   maxtrav: int, bt: BestList, ilist: InfoList):
+    """Head of an SPR cycle: the slot order, the lists' reset and the
+    lnL-cutoff bookkeeping; returns (slots, maxtrav clipped to the
+    tree)."""
     slots = dfs_slot_order(tree)
     maxtrav = min(maxtrav, tree.ntips - 3)
     ilist.reset()
@@ -93,16 +94,32 @@ def _tree_optimize_rapid(inst: PhyloInstance, tree: Tree, ctx: SprContext,
         ctx.it_count += 1
         ctx.lh_avg = 0.0
         ctx.lh_dec = 0
+    return slots, maxtrav
 
-    for p in slots:
-        # Liveness beat per SPR slot: every beat proves the previous
-        # slot's dispatches returned — a wedged dispatch/collective
-        # freezes this clock and the supervisor acts (the compile
-        # watchdog cannot see post-compile wedges).
-        heartbeat.beat("SPR_THOROUGH" if ctx.thorough else "SPR_LAZY")
-        ctx.best_of_node = UNLIKELY
+
+def spr_slot(inst: PhyloInstance, tree: Tree, ctx: SprContext, p,
+             mintrav: int, maxtrav: int, bt: BestList,
+             best_ml: Optional[BestList], ilist: InfoList,
+             beat: str) -> None:
+    """One slot of an SPR cycle, in the arm `ctx.thorough` names: every
+    move pruning at p (and at p.back) inside the radius window is
+    scored; the lazy arm notes the slot's best score in `ilist` and
+    commits an improvement, the thorough arm (a thorough cycle's slots
+    and the lazy cycle's re-pass) commits and saves an improvement and
+    else saves the slot's best candidate topology."""
+    # Liveness beat per SPR slot: every beat proves the previous
+    # slot's dispatches returned — a wedged dispatch/collective
+    # freezes this clock and the supervisor acts (the compile
+    # watchdog cannot see post-compile wedges).
+    heartbeat.beat(beat)
+    ctx.best_of_node = UNLIKELY
+    prunes = ctx.prunes
+    with obs.span("search:spr_slot",
+                  args={"arm": "thorough" if ctx.thorough else "lazy"}):
         if not rearrange(inst, tree, ctx, p, mintrav, maxtrav):
-            continue
+            return
+        if ctx.prunes > prunes:
+            obs.inc("search.spr_slots")
         if ctx.thorough:
             if ctx.end_lh > ctx.start_lh:
                 restore_tree_fast(inst, tree, ctx)
@@ -118,25 +135,46 @@ def _tree_optimize_rapid(inst: PhyloInstance, tree: Tree, ctx: SprContext,
                 restore_tree_fast(inst, tree, ctx)
                 ctx.start_lh = ctx.end_lh = inst.likelihood
 
+
+def _tree_optimize_rapid(inst: PhyloInstance, tree: Tree, ctx: SprContext,
+                         mintrav: int, maxtrav: int,
+                         bt: BestList, best_ml: Optional[BestList],
+                         ilist: InfoList) -> float:
+    slots, maxtrav = spr_cycle_head(inst, tree, ctx, maxtrav, bt, ilist)
+    beat = "SPR_THOROUGH" if ctx.thorough else "SPR_LAZY"
+    for p in slots:
+        spr_slot(inst, tree, ctx, p, mintrav, maxtrav, bt, best_ml, ilist,
+                 beat)
+
     if not ctx.thorough:
         # Thorough re-pass over the best lazy-insertion origins (iList).
         ctx.thorough = True
         for p in ilist.active_nodes():
-            heartbeat.beat("SPR_REPASS")
-            ctx.best_of_node = UNLIKELY
-            if not rearrange(inst, tree, ctx, p, mintrav, maxtrav):
-                continue
-            if ctx.end_lh > ctx.start_lh:
-                restore_tree_fast(inst, tree, ctx)
-                ctx.start_lh = ctx.end_lh = inst.likelihood
-                bt.save(tree, inst.likelihood)
-                if best_ml is not None:
-                    best_ml.save(tree, inst.likelihood)
-            elif ctx.best_of_node != UNLIKELY:
-                save_candidate_topology(inst, tree, ctx, bt, best_ml)
+            spr_slot(inst, tree, ctx, p, mintrav, maxtrav, bt, best_ml,
+                     ilist, "SPR_REPASS")
         ctx.thorough = False
 
     return ctx.start_lh
+
+
+def rescore_best(inst: PhyloInstance, tree: Tree, bt: BestList,
+                 best_t: BestList, lh: float, previous_lh: float,
+                 difference: float, epsilon: float):
+    """Re-score the trees an SPR cycle saved: recall each, smooth its
+    branches (`tree_evaluate(0.25)`), keep in `best_t` the one that
+    beats `lh` by more than `epsilon` over the cycle's start.  Returns
+    (improved, lh, difference)."""
+    impr = False
+    with obs.span("search:rescore", args={"trees": bt.nvalid}):
+        for i in range(1, bt.nvalid + 1):
+            bt.recall(inst, tree, i)
+            tree_evaluate(inst, tree, 0.25)
+            difference = abs(inst.likelihood - previous_lh)
+            if inst.likelihood > lh and difference > epsilon:
+                impr = True
+                lh = inst.likelihood
+                best_t.save(tree, inst.likelihood)
+    return impr, lh, difference
 
 
 def determine_rearrangement_setting(inst: PhyloInstance, tree: Tree,
@@ -334,15 +372,9 @@ def compute_big_rapid(inst: PhyloInstance, tree: Tree,
             tree_optimize_rapid(inst, tree, ctx, 1, best_trav, bt,
                                 best_ml, ilist)
 
-            impr = False
-            for i in range(1, bt.nvalid + 1):
-                bt.recall(inst, tree, i)
-                tree_evaluate(inst, tree, 0.25)
-                difference = abs(inst.likelihood - previous_lh)
-                if inst.likelihood > lh and difference > epsilon:
-                    impr = True
-                    lh = inst.likelihood
-                    best_t.save(tree, inst.likelihood)
+            impr, lh, difference = rescore_best(
+                inst, tree, bt, best_t, lh, previous_lh, difference,
+                epsilon)
 
     res.fast_iterations = fast_iterations
 
@@ -393,15 +425,8 @@ def compute_big_rapid(inst: PhyloInstance, tree: Tree,
         tree_optimize_rapid(inst, tree, ctx, rearr_min, rearr_max, bt,
                             best_ml, ilist)
 
-        impr = False
-        for i in range(1, bt.nvalid + 1):
-            bt.recall(inst, tree, i)
-            tree_evaluate(inst, tree, 0.25)
-            difference = abs(inst.likelihood - previous_lh)
-            if inst.likelihood > lh and difference > epsilon:
-                impr = True
-                lh = inst.likelihood
-                best_t.save(tree, inst.likelihood)
+        impr, lh, difference = rescore_best(
+            inst, tree, bt, best_t, lh, previous_lh, difference, epsilon)
 
     # ---- finish ----------------------------------------------------------
     res.thorough_iterations = thorough_iterations

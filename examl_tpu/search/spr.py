@@ -14,10 +14,12 @@ branches around the insertion point (triangle solve + local smoothing).
 
 from __future__ import annotations
 
+import os
 from typing import List, Optional
 
 import numpy as np
 
+from examl_tpu import obs
 from examl_tpu.constants import DEFAULTZ, SMOOTHINGS, UNLIKELY, ZMAX, ZMIN
 from examl_tpu.instance import PhyloInstance
 from examl_tpu.optimize.branch import local_smooth
@@ -60,6 +62,9 @@ class SprContext:
         # + the pruned subtree's cluster set, cached per prune.
         self.constraint = None
         self.pruned_clusters = None
+        # Prunes made so far (remove_node calls): a slot body reads it to
+        # tell whether its rearrange pruned anything.
+        self.prunes = 0
 
 
 from examl_tpu.utils import z_slots
@@ -78,6 +83,7 @@ def remove_node(inst: PhyloInstance, tree: Tree, ctx: SprContext,
     zqr = _zvec(inst, q.z) * _zvec(inst, r.z)
     result = inst.makenewz(tree, q, r, zqr, maxiter=SPR_NR_ITERATIONS)
     ctx.zqr = result.copy()
+    ctx.prunes += 1
     hookup(q, r, result.tolist())
     p.next.back = None
     p.next.next.back = None
@@ -243,14 +249,17 @@ def restore_tree_fast(inst: PhyloInstance, tree: Tree,
                       ctx: SprContext) -> None:
     """Commit the best move found for the current pruned node
     (reference `restoreTreeFast`)."""
-    remove_node_restore(inst, tree, ctx, ctx.remove_node)
-    test_insert_restore(inst, tree, ctx, ctx.remove_node, ctx.insert_node)
-    # Committed topology change: drop the engines' cached schedule
-    # structures (the topology-signature keys make staleness impossible
-    # either way — this is memory hygiene + the obs invalidation
-    # evidence; the host-side flat caches self-invalidate via the
-    # topology clock the hookups above bumped).
-    inst.invalidate_schedules()
+    obs.inc("search.moves_committed")
+    with obs.span("search:commit"):
+        remove_node_restore(inst, tree, ctx, ctx.remove_node)
+        test_insert_restore(inst, tree, ctx, ctx.remove_node,
+                            ctx.insert_node)
+        # Committed topology change: drop the engines' cached schedule
+        # structures (the topology-signature keys make staleness
+        # impossible either way — this is memory hygiene + the obs
+        # invalidation evidence; the host-side flat caches self-invalidate
+        # via the topology clock the hookups above bumped).
+        inst.invalidate_schedules()
 
 
 def save_candidate_topology(inst: PhyloInstance, tree: Tree, ctx: SprContext,
@@ -397,7 +406,6 @@ def batched_scan_enabled(inst: PhyloInstance) -> bool:
     skipped work is the cheaper currency -- so by default it is gated
     to accelerator devices.  EXAML_BATCH_SCAN=0 forces sequential
     everywhere; =1 forces the batched scan on any backend."""
-    import os
     if os.environ.get("EXAML_BATCH_SCAN") == "0":
         return False
     if os.environ.get("EXAML_BATCH_SCAN") == "1":
@@ -447,9 +455,10 @@ def rearrange_batched(inst: PhyloInstance, tree: Tree, ctx: SprContext,
         p1z = list(p1.z)
         p2z = list(p2.z)
         remove_node(inst, tree, ctx, prune)
-        plan = batchscan.plan_for_endpoints(
-            inst, tree, prune, p1, p2, mintrav_, maxtrav,
-            ctx.constraint, ctx.pruned_clusters)
+        with obs.span("search:plan"):
+            plan = batchscan.plan_for_endpoints(
+                inst, tree, prune, p1, p2, mintrav_, maxtrav,
+                ctx.constraint, ctx.pruned_clusters)
         if plan is not None:
             if thorough:
                 lnls, es = batchscan.run_plan_thorough(inst, tree, plan)
@@ -533,7 +542,6 @@ def thorough_batched_ok(inst: PhyloInstance) -> bool:
     are hard constraints of the on-device Newton loops, not
     preferences.
     """
-    import os
     forced = os.environ.get("EXAML_BATCH_THOROUGH")
     if forced == "0" or os.environ.get("EXAML_BATCH_SCAN") == "0":
         return False

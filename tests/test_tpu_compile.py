@@ -70,12 +70,12 @@ def chip_compile():
         cc.reset_cache()
 
 
-def _one_block_engine(datatype: str):
+def _one_block_engine(datatype: str, ntaxa: int = NTAXA):
     """140 taxa x 128 random sites (one 128-lane block) on CPU, f32,
     with a full-traversal schedule of a random tree."""
     rng = np.random.default_rng(7)
     alphabet = {"AA": "ARNDCQEGHILKMFPSTWYV", "DNA": "ACGT"}[datatype]
-    names = [f"t{i}" for i in range(NTAXA)]
+    names = [f"t{i}" for i in range(ntaxa)]
     seqs = ["".join(alphabet[c] for c in rng.integers(0, len(alphabet), 128))
             for _ in names]
     inst = PhyloInstance(
@@ -378,3 +378,79 @@ def test_newton_program_compiles(one_chip, chip_compile):
     compiled = jax.jit(eng._newton_impl).lower(
         *_as_shapes(eng, args, 128, lambda kind: one_chip)).compile()
     _fits(compiled)
+
+
+def _scan_call(inst, eng, tree, thorough: bool, scan_rows: int):
+    """(jitted SPR scan program, its arguments) as `batched_scan` /
+    `batched_thorough` dispatch them, for the first slot of the cycle's
+    order with an inner node on both sides, radius 10, with the scan
+    region grown to `scan_rows` rows."""
+    from examl_tpu.search import batchscan, spr
+    inst.evaluate(tree, full=True)
+    p = next(s for s in spr.dfs_slot_order(tree)
+             if not tree.is_tip(s.number)
+             and not tree.is_tip(s.next.back.number)
+             and not tree.is_tip(s.next.next.back.number))
+    p1, p2 = p.next.back, p.next.next.back
+    spr.remove_node(inst, tree, spr.SprContext(inst), p)
+    plan = batchscan.plan_for_endpoints(inst, tree, p, p1, p2, 1, 10)
+    assert len(plan.up_entries) <= scan_rows
+    base = eng.ensure_scan_rows(scan_rows)
+    assert eng.num_rows - base == scan_rows
+    T = batchscan.TH_CHUNK if thorough else batchscan.CAND_CHUNK
+    tv = eng._scan_traversal_arrays(plan.down_entries, plan.up_entries, base)
+    n_chunks, npad, qg, upg = eng._scan_dispatch_arrays(plan, base, T)
+    f32 = lambda a: jnp.asarray(a, dtype=jnp.float32)  # noqa: E731
+    cand = (jnp.asarray(qg.reshape(n_chunks, T)),
+            jnp.asarray(upg.reshape(n_chunks, T)))
+    if thorough:
+        fn = batchscan.thorough_program(eng, n_chunks)
+        cand += (f32(np.ones((n_chunks, T))),
+                 jnp.int32(eng._gidx(plan.s_num)))
+    else:
+        fn = batchscan.scan_program(eng, n_chunks)
+        cand += (f32(np.ones((n_chunks, T, 1))),
+                 jnp.int32(eng._gidx(plan.s_num)), f32(plan.zp))
+    return fn, (eng.clv, eng.scaler, (), tv, *cand, eng.models,
+                eng.block_part, eng.weights, eng.tips, None)
+
+
+@pytest.mark.parametrize("thorough", [False, True],
+                         ids=["spr_scan", "spr_thorough"])
+@pytest.mark.parametrize("ntaxa,blocks,scan_rows", [(49, 1024, 128),
+                                                    (140, 128, 512)])
+def test_spr_scan_programs_at_the_search_cells_sizes(
+        one_chip, chip_compile, ntaxa, blocks, scan_rows, thorough):
+    """The search cells' two device programs (search/batchscan.py) at
+    49 x 131,072 with a 128-row scan region and at 140 x 16,384 with
+    512 (the most radius 10 can ask for: 4 + 2 x (n - 3) uppass
+    entries): they fit beside the gradient program's count, hold no
+    operand slice and no arena-sized value in a loop but the in-place
+    writes, and carry names the full-traversal family's pattern does
+    not match.  At 131,072 `take_rows` reads by index (no gather of an
+    arena); at 16,384 a row is one piece and it is the gather, by design
+    (kernels.ONE_PIECE_SITES), which the compiler does not cut."""
+    import re
+    inst, eng, tree, _, _, st = _one_block_engine("DNA", ntaxa)
+    eng._install_row_map(st)
+    fn, args = _scan_call(inst, eng, tree, thorough, scan_rows)
+    compiled = fn.lower(*_as_shapes(eng, args, blocks,
+                                    lambda kind: one_chip)).compile()
+    sizes = _fits(compiled)
+    arena = eng.num_rows * blocks * 128 * 16 * 4   # with the scan region
+    assert sizes["arguments"] > arena
+    # beside what the gradient program holds while the scan region is
+    # allocated: its outroot arena of 2n - 1 rows (PERF.md section 7)
+    outroot = (2 * ntaxa - 1) * blocks * 128 * 16 * 4
+    assert sizes["arguments"] + sizes["temporaries"] + outroot < HBM_BYTES
+    arena_elems = (eng.num_rows - scan_rows) * blocks * 128 * 16
+    text = compiled.as_text()
+    if blocks * 128 > 128 * 128:
+        _reads_rows_by_index(compiled, arena_elems)
+    else:
+        assert " while(" in text and operand_slices(text) == 0
+        assert _arena_sized_in_loops(text, arena_elems) == []
+    name = text.split("\n", 1)[0]
+    want = "jit_spr_thorough_impl" if thorough else "jit_spr_scan_impl"
+    assert want in name
+    assert not re.search(r"^jit_impl(_eval)?\(", want + "(1)")
