@@ -544,8 +544,7 @@ class BatchEvaluator:
 
     def _grad_fn(self, eng, profile, steps: int, width: int, chunks: int,
                  jpad: int):
-        key = ("fleetgrad", profile, bucket_len(steps), next_pow2(width),
-               next_pow2(chunks), jpad, self.C)
+        key = ("fleetgrad", profile, steps, width, chunks, jpad, self.C)
         fn = eng.cache_get(key)
         if fn is not None:
             return fn
@@ -572,7 +571,8 @@ class BatchEvaluator:
             if j.gs is None:
                 with obs.timer("host_schedule"):
                     j.gs = gradient.build_structure(
-                        j.flat, self.engines[0].wave_width)
+                        j.flat, min(e.grad_wave_cap()
+                                    for e in self.engines))
             gss.append(j.gs)
         shapes = {(g.n_steps, g.wave_w, g.n_chunks) for g in gss}
         assert len(shapes) == 1, f"grad batch mixes shapes {shapes}"

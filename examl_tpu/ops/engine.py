@@ -1942,13 +1942,22 @@ class LikelihoodEngine:
         post-order output); -S SEV pools keep the per-branch path."""
         return not self.save_memory
 
+    def grad_wave_cap(self) -> int:
+        """Entries an outroot step of this engine's gradient program may
+        hold (`gradient.wave_cap`), from the sites a row holds THERE:
+        the arena's blocks x lanes, a shard's under the mesh, where
+        `_grad_impl` runs inside `shard_map`."""
+        from examl_tpu.ops import gradient
+        shards = 1 if self.sharding is None else self.sharding.site_shards
+        return gradient.wave_cap(self.B * self.lane // shards)
+
     def _grad_structure(self, flat):
         from examl_tpu.ops import gradient
         gs = self._grad_structs.get(flat.topo_key)
         if gs is not None:
             self._grad_structs.move_to_end(flat.topo_key)
             return gs
-        gs = gradient.build_structure(flat, self.wave_width)
+        gs = gradient.build_structure(flat, self.grad_wave_cap())
         self._grad_structs[flat.topo_key] = gs
         while len(self._grad_structs) > self._grad_structs_cap:
             self._grad_structs.popitem(last=False)
@@ -2017,9 +2026,14 @@ class LikelihoodEngine:
         `run_traversal(flat, full=True)` — any tier — just ran);
         `root_z` is the root edge's branch vector.
 
-        The jit key is shape-only — ("grad", steps, width, chunks), all
-        bucketed — so like the scan tier this is a tiny closed program
-        family and topology ships as runtime data.
+        The jit key is shape-only, ("grad", steps, width, chunks), and
+        topology ships as runtime data.  The chunks are
+        ceil(E / GRAD_CHUNK), a constant of the engine; the width
+        follows the row's sites (`grad_wave_cap`); the steps are n at
+        width 1 (one program an engine, whatever the tree) and a
+        `bucket_len` of the packed waves above it (a few by topology).
+        `engine.grad_slots` counts the slots both loops ran and
+        `engine.grad_live_slots` those that held an entry or an edge.
         """
         from examl_tpu.ops import gradient
         from examl_tpu.ops.kernels import OutrootTraversal
@@ -2037,8 +2051,7 @@ class LikelihoodEngine:
                 gs = self._grad_structure(flat)
                 pre, ex_rows, ey_gidx, ez = gradient.grad_arrays(
                     gs, flat, self.row_map, self.num_branch_slots, root_z)
-            key = ("grad", _bucket_len(gs.n_steps), _next_pow2(gs.wave_w),
-                   _next_pow2(gs.n_chunks))
+            key = ("grad", gs.n_steps, gs.wave_w, gs.n_chunks)
             fn = self.cache_get(key)
             if fn is None:
                 fn = self.cache_put(key, self._grad_program())
@@ -2078,6 +2091,9 @@ class LikelihoodEngine:
         # The gradient program is one device op whose scan walks
         # n_steps + n_chunks dependent steps — the launch-floor term.
         self._last_dispatch_ops = gs.n_steps + gs.n_chunks
+        obs.inc("engine.grad_slots", gs.n_steps * gs.wave_w
+                + gs.n_chunks * gradient.GRAD_CHUNK)
+        obs.inc("engine.grad_live_slots", gs.n + gs.n_edges)
         self._record_traffic(
             nbytes, "grad", wall_s=disp.elapsed,
             window=(obs.registry().counter("engine.compile_count")

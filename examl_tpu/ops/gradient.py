@@ -31,12 +31,21 @@ Per-site CLV rescaling cancels in every dsite/lsite ratio the
 derivatives are built from, so the outroot pass rescales VALUES (same
 threshold/multiplier as newview) but tracks no counts.
 
-Shapes are bucketed (`bucket_len`/`next_pow2`) so the jitted gradient
-program — keyed ("grad", L, W, n_chunks), and therefore eligible for
-the exported program bank (ops/export_bank.py: a restart deserializes
-the compiled gradient pass instead of recompiling it) — is a tiny
-closed family
-shared across topologies, like the scan tier: topology ships as data.
+Both loops run the tree's live slots, not a rounded-up count.  The
+edge loop has ceil(E / GRAD_CHUNK) chunks, and E = 2 * ntips - 3 for
+every full traversal: a constant of the engine.  The outroot loop's
+step width W follows the sites a row holds in the program that runs
+(`wave_cap`): 8 entries a step up to 16,384 sites, where a step
+amortises a loop iteration over small rows; 1 from 131,072, where a
+row is megabytes, `take_rows` reads rows one by one anyway and a wide
+step is mostly scratch rows (a tree's root-side waves hold one to
+three entries).  At W = 1 the step count is n = ntips - 2 whatever
+the topology; above it the steps are bucketed (`bucket_len`).  The
+jitted gradient program is keyed ("grad", L, W, n_chunks), and
+therefore eligible for the exported program bank (ops/export_bank.py:
+a restart deserializes the compiled gradient pass instead of
+recompiling it): one program an engine at W = 1, a few step buckets
+by topology above it; topology ships as data.
 """
 
 from __future__ import annotations
@@ -54,6 +63,14 @@ from examl_tpu.utils import bucket_len, next_pow2, z_slots
 # [GRAD_CHUNK, B, lane, R, K] is the gradient program's peak transient
 # beyond the outroot arena (mirrors batchscan.CAND_CHUNK).
 GRAD_CHUNK = 32
+
+
+def wave_cap(sites: int) -> int:
+    """Entries an outroot step may hold, from the sites (blocks x lanes)
+    of a row in the program that runs (a shard's under the mesh): a
+    step moves about what 8 entries move at `kernels.ONE_PIECE_SITES`
+    sites, and never fewer than one entry."""
+    return max(1, min(8, 8 * kernels.ONE_PIECE_SITES // sites))
 
 
 class GradStructure:
@@ -113,7 +130,10 @@ class GradStructure:
             lo, hi = int(offs[w]), int(offs[w + 1])
             for s in range(lo, hi, W):
                 steps.append(np.arange(s, min(s + W, hi), dtype=np.int64))
-        L = bucket_len(len(steps)) if steps else bucket_len(1)
+        # One entry a step: n steps whatever the topology, no bucket.
+        L = max(len(steps), 1)
+        if W > 1:
+            L = bucket_len(L)
         pk = np.full((L, W), -1, dtype=np.int64)
         for i, st in enumerate(steps):
             pk[i, :st.shape[0]] = st
@@ -143,7 +163,7 @@ class GradStructure:
         ez_src[2::2] = np.arange(n)
         ez_side = np.zeros(E, dtype=np.int64)
         ez_side[2::2] = 1
-        nc = max(1, next_pow2(-(-E // GRAD_CHUNK)))
+        nc = -(-E // GRAD_CHUNK)
         Epad = nc * GRAD_CHUNK
         self.n_chunks = nc
 

@@ -150,6 +150,177 @@ def test_edge_count_and_root_edge():
     assert len({id(s.z) for s in slots}) == len(slots)
 
 
+# -- the gradient program's loops follow the tree's live slots ---------------
+
+def _balanced_newick(names):
+    if len(names) == 1:
+        return names[0]
+    h = len(names) // 2
+    return f"({_balanced_newick(names[:h])},{_balanced_newick(names[h:])})"
+
+
+def _shaped_newick(kind, n):
+    """An n-taxon Newick string: maximally unbalanced, balanced, or None
+    for the instance's own random tree."""
+    if kind == "caterpillar":
+        return _caterpillar_newick(n)
+    if kind == "balanced":
+        return _balanced_newick([f"t{i}" for i in range(n)]) + ";"
+    return None
+
+
+TREE_KINDS = ("caterpillar", "balanced", "random")
+
+
+@pytest.mark.parametrize("kind", TREE_KINDS)
+@pytest.mark.parametrize("cap", [1, 2, 8])
+def test_grad_structure_holds_live_slots_in_order(cap, kind):
+    """`GradStructure` at outroot step widths 1, 2 and 8: every entry
+    sits in exactly one slot, an entry reads its `up_row` only after
+    the step that wrote it (the two root rows are there before the
+    loop), padding slots touch the scratch row alone, the edge loop has
+    ceil(E / GRAD_CHUNK) chunks, and one entry a step means n steps
+    whatever the topology."""
+    from examl_tpu.ops import gradient
+    from examl_tpu.tree.topology import Tree
+    n_taxa = 41                                   # E = 79: three chunks
+    names = [f"t{i}" for i in range(n_taxa)]
+    newick = _shaped_newick(kind, n_taxa)
+    if newick is None:
+        inst = PhyloInstance(correlated_dna(n_taxa, 40))
+        newick = inst.random_tree(seed=11).to_newick(names)
+    tree = Tree.from_newick(newick, names, 1)
+    flat = tree.flat_full_traversal(tree.centroid_branch())
+    gs = gradient.build_structure(flat, cap)
+    n, E = n_taxa - 2, 2 * n_taxa - 3
+    assert (gs.n, gs.n_edges) == (n, E)
+    assert gs.wave_w <= cap and gs.pk.shape == (gs.n_steps, gs.wave_w)
+    live = ~gs.pk_pad
+    assert sorted(gs.pk[live]) == list(range(n))
+    assert (gs.up_row[gs.pk_pad] == gs.scratch).all()
+    assert (gs.lrow[gs.pk_pad] == gs.scratch).all()
+    assert (gs.rrow[gs.pk_pad] == gs.scratch).all()
+    written = {r - 1: -1 for r in gs.roots}       # row -> step written
+    for t in range(gs.n_steps):
+        for w in np.flatnonzero(live[t]):
+            assert written[int(gs.up_row[t, w])] < t
+        for w in np.flatnonzero(live[t]):
+            for row in (int(gs.lrow[t, w]), int(gs.rrow[t, w])):
+                assert row not in written
+                written[row] = t
+    assert len(written) == 2 * n + 2 and gs.scratch not in written
+    assert gs.n_chunks == -(-E // gradient.GRAD_CHUNK) == 3
+    assert (~gs.edge_pad).sum() == E
+    # an edge a written row, but one for the root edge's two
+    assert sorted(gs.edge_x_row[~gs.edge_pad]) == \
+        sorted(set(written) - {gs.roots[1] - 1})
+    if cap == 1:
+        assert gs.n_steps == n and live.all()
+    else:
+        from examl_tpu.utils import bucket_len
+        assert gs.n_steps == bucket_len(gs.n_steps) >= -(-n // cap)
+
+
+def _arm_instance(arm, ntaxa=14):
+    if arm == "gamma":
+        return PhyloInstance(correlated_dna(ntaxa, 260))
+    if arm == "per_partition_branches":
+        inst = PhyloInstance(_partitioned_dna(ntaxa=ntaxa),
+                             per_partition_branches=True)
+        assert inst.num_branch_slots == 2
+        return inst
+    return _psr_instance(ntaxa=ntaxa)
+
+
+def _per_branch_derivatives(inst, tree, slot):
+    """(d1, d2) [C] of one branch by the per-branch Newton path's own
+    programs: both end views, `sumtable`, `nr_derivatives`."""
+    inst.new_view(tree, slot)
+    inst.new_view(tree, slot.back)
+    d1 = d2 = 0.0
+    for eng in inst.engines.values():
+        st = eng.make_sumtable(slot.number, slot.back.number)
+        e1, e2 = eng.branch_derivatives(st, slot.z)
+        d1, d2 = d1 + np.asarray(e1, float), d2 + np.asarray(e2, float)
+    return d1, d2
+
+
+@pytest.mark.parametrize("kind", TREE_KINDS)
+@pytest.mark.parametrize("arm", ["gamma", "per_partition_branches", "psr"])
+def test_whole_tree_gradients_equal_at_every_wave_cap(arm, kind,
+                                                      monkeypatch):
+    """(d1, d2) of `whole_tree_gradients` with one, two and eight
+    entries an outroot step are one result (f64 here: 1e-12 of the
+    largest derivative), and the per-branch `sumtable` /
+    `nr_derivatives` path's on the branches checked: the step width
+    moves scratch work, not an entry's or an edge's arithmetic."""
+    from examl_tpu.ops import gradient
+    from examl_tpu.optimize.branch import tree_gradients
+    from examl_tpu.utils import next_pow2
+    inst = _arm_instance(arm)
+    ntaxa = len(inst.alignment.taxon_names)
+    newick = _shaped_newick(kind, ntaxa)
+    tree = (inst.tree_from_newick(newick) if newick
+            else inst.random_tree(seed=7))
+    rng = np.random.default_rng(5)
+    for s in tree_gradients(inst, tree)[0]:       # distinct lengths
+        s.z[:] = list(rng.uniform(0.6, 0.95, len(s.z)))
+    tree.invalidate_all()
+    widest = next_pow2(int(max(tree.flat_full_traversal(
+        tree.centroid_branch()).wave_sizes)))
+    assert widest >= 2
+    got = {}
+    for cap in (8, 2, 1):
+        for eng in inst.engines.values():
+            monkeypatch.setattr(eng, "grad_wave_cap", lambda cap=cap: cap)
+            eng._grad_structs.clear()
+        s0 = obs.counter("engine.grad_slots")
+        l0 = obs.counter("engine.grad_live_slots")
+        inst.evaluate(tree, full=True)
+        slots, d1, d2 = tree_gradients(inst, tree)
+        (gs,) = eng._grad_structs.values()
+        assert gs.wave_w == min(cap, widest)
+        E = 2 * ntaxa - 3
+        assert obs.counter("engine.grad_live_slots") - l0 == \
+            len(inst.engines) * (ntaxa - 2 + E)
+        assert obs.counter("engine.grad_slots") - s0 == len(inst.engines) \
+            * (gs.n_steps * gs.wave_w + gs.n_chunks * gradient.GRAD_CHUNK)
+        if cap == 1:
+            assert gs.n_steps == ntaxa - 2
+        got[cap] = (d1, d2)
+    assert np.isfinite(got[8][0]).all() and np.abs(got[8][0]).max() > 0
+    for cap in (1, 2):
+        for a, b in zip(got[cap], got[8]):
+            np.testing.assert_allclose(a, b, rtol=1e-12,
+                                       atol=1e-12 * np.abs(b).max())
+    for k in (0, 3, len(slots) - 1):
+        r1, r2 = _per_branch_derivatives(inst, tree, slots[k])
+        for cap in (1, 2, 8):
+            np.testing.assert_allclose(got[cap][0][k], r1, rtol=1e-9,
+                                       atol=1e-9 * np.abs(got[8][0]).max())
+            np.testing.assert_allclose(got[cap][1][k], r2, rtol=1e-9,
+                                       atol=1e-9 * np.abs(got[8][1]).max())
+
+
+@pytest.mark.parametrize("sites,cap", [
+    (128, 8), (16_384, 8), (16_512, 7), (32_768, 4), (65_536, 2),
+    (131_072, 1), (262_144, 1)])
+def test_wave_cap_follows_the_rows_sites(sites, cap):
+    """An outroot step moves about what eight entries move at one
+    gathered piece (`kernels.ONE_PIECE_SITES`), never fewer than one
+    entry, and the engine asks with its own arena's blocks x lanes."""
+    from examl_tpu.ops import gradient
+    assert gradient.wave_cap(sites) == cap
+    inst = PhyloInstance(correlated_dna(6, 60))
+    (eng,) = inst.engines.values()
+    assert eng.grad_wave_cap() == 8               # one block of 128
+    eng.B = sites // eng.lane
+    assert eng.grad_wave_cap() == cap
+    tree = inst.random_tree(seed=2)
+    flat = tree.flat_full_traversal(tree.centroid_branch())
+    assert eng._grad_structure(flat).wave_w <= cap
+
+
 def test_gradient_bitwise_stable_across_invalidation():
     """The pre-order plan is content-keyed: an SPR-commit-style
     sched-cache invalidation (cold plan rebuild) must reproduce the

@@ -265,6 +265,36 @@ def test_sharded_whole_tree_gradients_match(sharded):
                                atol=1e-6 * np.abs(r2).max())
 
 
+def test_sharded_engine_takes_the_wave_cap_from_a_shards_row(sharded,
+                                                             monkeypatch):
+    """The outroot step width follows the sites a row holds in the
+    program that runs: inside the `shard_map` a chip sees its shard's
+    blocks, a quarter of the engine's, and `grad_wave_cap` asks with
+    those.  At 262,144 global patterns that is two entries a step (a
+    shard's 65,536) where one device would take one."""
+    from examl_tpu.ops import gradient
+    from examl_tpu.optimize.branch import tree_gradients
+    _, inst4, _, newick = sharded
+    (eng,) = inst4.engines.values()
+    seen = []
+    impl = eng._grad_impl
+
+    def recording(clv, *rest):
+        seen.append(clv.shape[1] * clv.shape[2])
+        return impl(clv, *rest)
+
+    monkeypatch.setattr(eng, "_grad_impl", recording)
+    tree = inst4.tree_from_newick(newick)
+    inst4.evaluate(tree, full=True)
+    tree_gradients(inst4, tree)
+    assert seen == [eng.B * eng.lane // 4]        # traced once, a shard's
+    assert eng.grad_wave_cap() == gradient.wave_cap(seen[0]) == 8
+    monkeypatch.setattr(eng, "B", 262_144 // eng.lane)
+    assert eng.grad_wave_cap() == gradient.wave_cap(65_536) == 2
+    monkeypatch.setattr(eng, "sharding", None)
+    assert eng.grad_wave_cap() == 1
+
+
 def test_sharded_gradients_read_by_index_bitwise(problem, monkeypatch):
     """The mapped pass reads a shard's wide rows by index
     (`kernels.take_rows`, a loop of dynamic slices inside the

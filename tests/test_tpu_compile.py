@@ -34,6 +34,15 @@ from examl_tpu.ops import fastpath  # noqa: E402
 
 HBM_BYTES = 16 * 1000 ** 3        # one v5e chip: 16 GB (Cloud TPU docs)
 NTAXA = 140
+# The compiler's temporaries for the gradient program while both loops
+# ran the next power of two's slots (PR 35's tree, this file's own
+# compiles): the live-slot shapes hold no more, to the MiB the loops'
+# index tables move.  (PR 36 reads 3,950,129,152 / 3,176,473,088 /
+# 3,996,271,104: the peak is the edge chunk's 32 rows beside the
+# outroot arena, which no step width changes at 131,072.)
+GRAD_TEMP_PR35 = {"dna131k": 3_950_031_872 + 2 ** 20,
+                  "dna65k_chip": 3_243_680_256 + 2 ** 20,
+                  "aa16k": 3_996_271_104 + 2 ** 20}
 
 
 @pytest.fixture(scope="module")
@@ -105,12 +114,15 @@ def _chunk_eval_call(eng, p, flat, st):
     return fn, args
 
 
-def _grad_call(eng, p, flat, st):
-    """(jitted gradient pass, its arguments) as `whole_tree_gradients`
-    dispatches them (ops/gradient.py)."""
+def _grad_call(eng, p, flat, st, blocks: int, sharding=None):
+    """(jitted gradient pass, its arguments, its structure) as
+    `whole_tree_gradients` dispatches them (ops/gradient.py) on an
+    engine of `blocks` blocks under `sharding`: the structure's outroot
+    step width follows the row's sites there (`Engine.grad_wave_cap`)."""
     from examl_tpu.ops import gradient
     from examl_tpu.ops.kernels import OutrootTraversal
     eng._install_row_map(st)
+    eng.B, eng.sharding = blocks, sharding
     gs = eng._grad_structure(flat)
     root_z = np.asarray(p.z, dtype=np.float64)
     pre, ex_rows, ey_gidx, ez = gradient.grad_arrays(
@@ -126,7 +138,7 @@ def _grad_call(eng, p, flat, st):
             jnp.int32(eng._gidx(pn)), jnp.int32(eng._gidx(qn)), tvp,
             jnp.asarray(ex_rows), jnp.asarray(ey_gidx), f32(ez),
             eng.models, eng.block_part, eng.weights, eng.tips, None)
-    return jax.jit(eng._grad_impl), args
+    return jax.jit(eng._grad_impl), args, gs
 
 
 def _as_shapes(eng, args, blocks: int, place):
@@ -232,19 +244,25 @@ def test_chunk_evaluate_program_140x131072_dna(one_chip, chip_compile):
     assert "tpu_custom_call" not in compiled.as_text()    # no kernel
 
 
-def test_gradient_pass_140x131072_dna(one_chip, chip_compile):
+@pytest.mark.parametrize("ntaxa,shapes", [(NTAXA, (NTAXA - 2, 1, 9)),
+                                          (49, (47, 1, 3))])
+def test_gradient_pass_131072_dna(one_chip, chip_compile, ntaxa, shapes):
     """The whole-tree gradient pass (ops/gradient.py) at the same
-    width: the outroot arena (2n-1 rows) lives inside the program."""
-    _, eng, _, p, flat, st = _one_block_engine("DNA")
-    fn, args = _grad_call(eng, p, flat, st)
-    eng.B = 1024                       # _grad_impl sizes its arena by it
+    width, 140 taxa (cell 2) and 49 (the wide search cell): the outroot
+    arena (2n-1 rows) lives inside the program.  A row is 8.39 MB, so
+    one entry an outroot step: n steps whatever the tree, and
+    ceil(E / 32) edge chunks, 9 for 277 edges and 3 for 95."""
+    _, eng, _, p, flat, st = _one_block_engine("DNA", ntaxa)
+    fn, args, gs = _grad_call(eng, p, flat, st, 1024)
+    assert (gs.n_steps, gs.wave_w, gs.n_chunks) == shapes
     compiled = fn.lower(*_as_shapes(eng, args, 1024,
                                     lambda kind: one_chip)).compile()
     sizes = _fits(compiled)
-    outroot = (2 * NTAXA - 1) * 1024 * 128 * 16 * 4
-    # 5.19 GB while the rows were gathered (32 operand slices), 3.95 GB
-    # read by index
-    assert outroot <= sizes["temporaries"] < 5.0e9
+    outroot = (2 * ntaxa - 1) * 1024 * 128 * 16 * 4
+    # 140 taxa: 5.19 GB while the rows were gathered (32 operand
+    # slices), 3.95 GB read by index in steps of 8 entries and 16
+    # chunks (PR 32 to 35)
+    assert outroot <= sizes["temporaries"] <= GRAD_TEMP_PR35["dna131k"]
     _reads_rows_by_index(compiled, eng.num_rows * 1024 * 128 * 16)
 
 
@@ -253,10 +271,12 @@ def test_gradient_pass_140x16384_protein(one_chip, chip_compile):
     gathers (a row is one piece), and the compiler lowers them to loops
     of dynamic slices itself: the same three findings."""
     _, eng, _, p, flat, st = _one_block_engine("AA")
-    fn, args = _grad_call(eng, p, flat, st)
+    fn, args, gs = _grad_call(eng, p, flat, st, 128)
+    # a row is one piece: today's 8 entries a step, bucketed; 9 chunks
+    assert gs.wave_w == 8 and gs.n_chunks == 9
     compiled = fn.lower(*_as_shapes(eng, args, 128,
                                     lambda kind: one_chip)).compile()
-    _fits(compiled)
+    assert _fits(compiled)["temporaries"] <= GRAD_TEMP_PR35["aa16k"]
     _reads_rows_by_index(compiled, eng.num_rows * 128 * 128 * 80)
 
 
@@ -340,9 +360,11 @@ def test_site_sharded_gradient_program_262144_one_all_reduce(
     from examl_tpu.parallel.sharding import make_mesh, site_sharding
     sh = site_sharding(make_mesh(devices=topo.devices[:4]))
     _, eng, _, p, flat, st = _one_block_engine("DNA")
-    _, args = _grad_call(eng, p, flat, st)
-    eng.sharding = sh                  # what select_sharding gives there
     blocks = 262144 // 128
+    # sh: what select_sharding gives there; a shard's row holds 65,536
+    # sites, so two entries a step
+    _, args, gs = _grad_call(eng, p, flat, st, blocks, sh)
+    assert gs.wave_w == 2 and gs.n_chunks == 9
     compiled = eng._grad_program().lower(*_as_shapes(
         eng, args, blocks, lambda kind: getattr(sh, kind))).compile()
     sizes = _fits(compiled)
@@ -352,7 +374,7 @@ def test_site_sharded_gradient_program_262144_one_all_reduce(
     outroot = (2 * NTAXA - 1) * (blocks // 4) * 128 * 16 * 4
     # 3.75 GB a chip while the rows were gathered (16 operand slices),
     # 3.24 GB read by index
-    assert outroot <= sizes["temporaries"] < 3.5e9
+    assert outroot <= sizes["temporaries"] <= GRAD_TEMP_PR35["dna65k_chip"]
     _reads_rows_by_index(compiled, eng.num_rows * (blocks // 4) * 128 * 16)
     text = compiled.as_text()
     assert "jit__grad_impl" in text.split("\n", 1)[0]   # the trace's name
