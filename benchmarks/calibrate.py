@@ -5,7 +5,8 @@ process.  The benchmark's own runs never come here.
 
     python benchmarks/calibrate.py --workload <cell> --seeds 1,2,3 \
         --seconds 6 [--control dot|clv] [--data-seed N] \
-        [--fault unchanged|half|altered|freqs]
+        [--fault unchanged|half|altered|freqs|swap_parts|part_freqs] \
+        [--manifest FILE]
 
 Each run is `run.run_cell` as the driver's run makes it, so what is
 compared is what the timed steps produced at the timed sizes.
@@ -19,7 +20,11 @@ compared is what the timed steps produced at the timed sizes.
   (no Brent, no smoothing); `half`: half of the site patterns are left
   out and the rest counted double; `altered`: the step's lnL is altered
   where it is produced (by 1e-4 of itself); `freqs`: the parser counts
-  its empirical frequencies over every other column only.
+  its empirical frequencies over every other column only.  Two that only
+  a cell of several partitions can show: `swap_parts`: at engine build
+  the block ids of the two widest partitions are exchanged, so the
+  program applies each one's model to the other's sites; `part_freqs`:
+  `freqs` on the second partition only.
 * `--data-seed N`: another problem (tree, alignment, moved trees) than
   the configuration's `data_seed`, to read the numbers on problems the
   limits were not set on.
@@ -32,9 +37,12 @@ numbers compared with their limits, steps and `step_s`.
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import sys
+
+import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -74,11 +82,31 @@ def plant(fault: str):
                 keep = (w.reshape(-1).at[::2].set(0) * 2).reshape(w.shape)
                 eng.weights = keep.astype(w.dtype)
         patch(PhyloInstance, "__init__", init)
-    elif fault == "freqs":
+    elif fault in ("freqs", "part_freqs"):
         real_freqs = alignment.empirical_frequencies
-        patch(alignment, "empirical_frequencies",
-              lambda codes, weights, dt, *a, **k: real_freqs(
-                  codes[:, ::2], weights[::2], dt, *a, **k))
+        calls = itertools.count()        # the parser counts a partition a call
+
+        def some_freqs(codes, weights, dt, *a, **k):
+            if fault == "freqs" or next(calls) == 1:
+                codes, weights = codes[:, ::2], weights[::2]
+            return real_freqs(codes, weights, dt, *a, **k)
+        patch(alignment, "empirical_frequencies", some_freqs)
+    elif fault == "swap_parts":
+        from examl_tpu import instance
+        real_pack = instance.pack_partitions
+
+        def pack(partitions, **k):
+            buckets = real_pack(partitions, **k)
+            for bucket in buckets.values():
+                if bucket.num_parts < 2:
+                    raise SystemExit("swap_parts needs two partitions of "
+                                     "one state count")
+                a, b = np.argsort(bucket.part_widths)[-2:]
+                ids = bucket.block_part
+                bucket.block_part = np.where(
+                    ids == a, b, np.where(ids == b, a, ids)).astype(ids.dtype)
+            return buckets
+        patch(instance, "pack_partitions", pack)
     elif fault == "altered":
         for kind in ("modopt", "treeset"):
             mod = importlib.import_module(f"benchmarks.steps.{kind}")
@@ -100,7 +128,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=6.0)
     ap.add_argument("--control", choices=sorted(CONTROLS))
     ap.add_argument("--fault", choices=("unchanged", "half", "altered",
-                                        "freqs"))
+                                        "freqs", "swap_parts", "part_freqs"))
+    ap.add_argument("--manifest", default="BENCHMARK.json", metavar="FILE")
     ap.add_argument("--data-seed", type=int)
     ap.add_argument("--rehearse", action="store_true")
     a = ap.parse_args(argv)
@@ -111,7 +140,8 @@ def main(argv=None) -> int:
         try:
             r = bench.run_cell(a.workload, seed, a.seconds, False,
                                rehearse=a.rehearse, control_env=env,
-                               data_seed=a.data_seed)
+                               data_seed=a.data_seed,
+                               manifest_file=a.manifest)
         finally:
             undo()
             for k in env or {}:

@@ -125,9 +125,10 @@ def slot_readings(workload: str, seed: int, rehearse: bool, env) -> dict:
     got = sorted((c.q_num, c.q_slot.back.number) for c in plan.candidates)
     want = sorted((v, w) for v, w, _d in reference_search.window(
         edges, p.number, s, traffic["radius"], ntips))
-    model = {"rates": cell.gen["model"]["rates"],
+    (generating,) = cell.gen["models"]          # the search cells: one part
+    model = {"rates": generating["rates"],
              "freqs": bench.own_model(config, cell.gen["patterns"])["freqs"],
-             "alpha": cell.gen["model"]["alpha"]}
+             "alpha": generating["alpha"]}
     pick = np.random.default_rng([seed, 0x5CA9]).permutation(
         len(plan.candidates))[:SAMPLE]
     out = {"scan_lnl_rel_err": 0.0, "thorough_lnl_rel_err": 0.0,

@@ -21,6 +21,16 @@ compiled programs by the traversal's layout, which follows the node
 numbering, so trees relabelled by the seed would compile anew in every
 run (seen on the v5e, PR 27) and move `setup_s` with the seed.
 
+An alignment may be in several parts (`parts_of`): one tree, and for
+each part a generating model of its own and exactly its `patterns`
+distinct columns evolved on the tree's lengths times the part's `rate`,
+concatenated in part order; `--seed` then orders the columns inside each
+part, so every column stays in the partition the partition file puts it
+in.  A configuration without `parts` is one part, and its rng is drawn
+call for call as it was before parts existed: the accepted cells'
+problems are byte for byte what they were (tests/benchmarks/
+test_partitioned.py pins their sha256).
+
 A tree is an adjacency map {node: [neighbours]} over tips 0..n-1 and
 inner nodes n..2n-3, with branch lengths keyed by the sorted node pair.
 """
@@ -109,12 +119,25 @@ def newick(adj, ntaxa: int, lengths=None) -> str:
     return fmt(ntaxa, None) + ";"
 
 
-def model_params(config: dict, rng):
-    """(rates, freqs, alpha) the data are evolved under: the
-    configuration's own numbers, or for `"generating": "random"` a
+def parts_of(config: dict) -> list:
+    """The alignment's partitions as the configuration states them.  With
+    `parts`, each `{name, model (the parser's token), patterns,
+    exchangeabilities (a table under models/; absent where the rates are
+    free), generating: {alpha, rate, rates, freqs}}`; without, ONE part
+    made of the configuration's own keys."""
+    if "parts" in config:
+        return config["parts"]
+    part = {"name": "p1", "patterns": config["patterns"],
+            "generating": config["generating"]}
+    if config.get("exchangeabilities"):
+        part["exchangeabilities"] = config["exchangeabilities"]
+    return [part]
+
+
+def model_params(gen: dict, K: int, rng):
+    """(rates, freqs, alpha) a part's data are evolved under: its
+    `generating` group's own numbers, or for `"rates": "random"` a
     reversible matrix drawn from the seed."""
-    gen = config["generating"]
-    K = len(ALPHABETS[config["datatype"]])
     if gen.get("rates") == "random":
         rates = np.exp(rng.normal(0.0, 1.0, K * (K - 1) // 2))
         freqs = rng.dirichlet(np.full(K, 8.0))
@@ -177,15 +200,27 @@ def write_phylip(path: str, mat: np.ndarray, datatype: str) -> None:
 def problem(config: dict, trees: int, spr_moves: int,
             branch_lengths: bool = False) -> dict:
     """The cell's problem, from the configuration's `data_seed` alone:
-    the generating tree, the pattern matrix, `trees` topologies
-    `spr_moves` random SPR moves from the generating one (with the
-    branch lengths the moves leave them, if asked), the generating
-    model."""
-    ntaxa, npat = config["taxa"], config["patterns"]
-    rng = np.random.default_rng([config["data_seed"], ntaxa, npat])
+    the generating tree; for each part of `parts_of(config)`, in order, a
+    generating model and exactly its `patterns` distinct columns evolved
+    on the tree's lengths times the part's `rate`, concatenated; `trees`
+    topologies `spr_moves` random SPR moves from the generating one
+    (with the branch lengths the moves leave them, if asked).  With one
+    part the rng is drawn as it was before parts existed, call for
+    call."""
+    ntaxa = config["taxa"]
+    parts = parts_of(config)
+    widths = [p["patterns"] for p in parts]
+    rng = np.random.default_rng([config["data_seed"], ntaxa, sum(widths)])
     adj, lengths = random_tree(rng, ntaxa)
-    rates, freqs, alpha = model_params(config, rng)
-    mat = alignment(rng, adj, lengths, ntaxa, npat, rates, freqs, alpha)
+    K = len(ALPHABETS[config["datatype"]])
+    models, mats = [], []
+    for part in parts:
+        rates, freqs, alpha = model_params(part["generating"], K, rng)
+        rate = float(part["generating"].get("rate", 1.0))
+        scaled = {e: t * rate for e, t in lengths.items()}
+        mats.append(alignment(rng, adj, scaled, ntaxa, part["patterns"],
+                              rates, freqs, alpha))
+        models.append({"rates": rates, "freqs": freqs, "alpha": alpha})
     moved = []
     for _ in range(trees):
         other = {n: list(v) for n, v in adj.items()}
@@ -194,15 +229,18 @@ def problem(config: dict, trees: int, spr_moves: int,
             spr_move(rng, other, ntaxa, other_len)
         moved.append(newick(other, ntaxa,
                             other_len if branch_lengths else None))
-    return {"patterns": mat, "tree": newick(adj, ntaxa),
-            "moved_trees": moved,
-            "model": {"rates": rates, "freqs": freqs, "alpha": alpha}}
+    ends = np.cumsum(widths).tolist()
+    return {"patterns": np.concatenate(mats, axis=1),
+            "tree": newick(adj, ntaxa), "moved_trees": moved,
+            "models": models,
+            "bounds": [(e - w, e) for w, e in zip(widths, ends)]}
 
 
 def present(prob: dict, seed: int) -> dict:
-    """The problem as run `seed` sees it: its site columns in an order
-    drawn from the seed."""
-    order = np.random.default_rng([seed, 0x5EED]).permutation(
-        prob["patterns"].shape[1])
+    """The problem as run `seed` sees it: the site columns of each part
+    in an order drawn from the seed, every column in its own part."""
+    rng = np.random.default_rng([seed, 0x5EED])
+    order = np.concatenate([s + rng.permutation(e - s)
+                            for s, e in prob["bounds"]])
     return {**prob,
             "patterns": np.ascontiguousarray(prob["patterns"][:, order])}

@@ -24,7 +24,9 @@ Two numbers per state:
   lengths reads near 1.
 
 Sites are processed in blocks on a few threads so that 140 x 131,072
-fits in about 1.5 GB of host memory.
+fits in about 1.5 GB of host memory.  An alignment in several parts, a
+model each (and with `-M` a branch-length class each), is the sum of
+`evaluate` over the parts (`evaluate_parts`).
 """
 
 from __future__ import annotations
@@ -178,6 +180,32 @@ def evaluate(patterns: np.ndarray, weights, edges, ntips: int,
     if not want_derivs:
         return lnl, None, None
     return lnl, sum(p[1] for p in parts), sum(p[2] for p in parts)
+
+
+def evaluate_parts(patterns: np.ndarray, bounds, edges, ntips: int, models,
+                   ncat: int = 4, want_derivs: bool = True):
+    """The tree's lnL over an alignment in parts: `evaluate` on each
+    part's own columns `patterns[:, s:e]` with the part's own (rates,
+    freqs, alpha) and the z of its branch-length class, summed.  `edges`
+    = [(node_a, node_b, z_0, .., z_{C-1})]: with C = 1 every part reads
+    z_0; with C > 1 (`-M`) part k reads z_k.  Returns (lnl, the parts'
+    lnLs, d1 [C, E], d2 [C, E]), the derivatives summed over the parts of
+    a class (None without `want_derivs`)."""
+    C = len(edges[0]) - 2
+    lnls, d1, d2 = [], [None] * C, [None] * C
+    for k, ((s, e), (rates, freqs, alpha)) in enumerate(zip(bounds, models)):
+        c = k if C > 1 else 0
+        part_edges = [(row[0], row[1], row[2 + c]) for row in edges]
+        lnl, p1, p2 = evaluate(patterns[:, s:e], None, part_edges, ntips,
+                               rates, freqs, alpha, ncat, want_derivs)
+        lnls.append(lnl)
+        if want_derivs:
+            d1[c] = p1 if d1[c] is None else d1[c] + p1
+            d2[c] = p2 if d2[c] is None else d2[c] + p2
+    total = sum(lnls[1:], lnls[0])
+    if not want_derivs:
+        return total, lnls, None, None
+    return total, lnls, np.stack(d1), np.stack(d2)
 
 
 def newton_dz(edges, d1, d2, zmin: float, zmax: float) -> np.ndarray:

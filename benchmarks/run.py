@@ -11,6 +11,13 @@ the step kind and its parameters, `steps/<kind>.py` the step,
 `layers/<metric>.json` each per-layer metric with the reader under
 `readers/` that takes it, `correct/<cell>.json` the limits of the
 comparison with the plain reference, `peaks.json` the chip's peaks.
+The configuration also says what the alignment is: `parts` (a list of
+partitions with a model, a width and a generating model each; without
+it ONE part made of the configuration's own keys, `datagen.parts_of`)
+and `cli_args` (appended to the program's own command line, e.g.
+`-M`).  `--manifest FILE` reads another manifest than `BENCHMARK.json`
+(the tests' fixture, a draft cell): its `traffic/` and `correct/` files
+are looked for beside it first, then here.
 
 Set-up (counted in `setup_s`, process start to the window's first
 step): imports, inputs from `--seed` by the benchmark's own generator,
@@ -81,17 +88,35 @@ def read_json(*parts):
         return json.load(f)
 
 
-def find_cell(name: str):
+def cell_file(manifest: str, kind: str, name: str) -> str:
+    """`<kind>/<name>.json` beside the manifest where it is there, else
+    the benchmark's own."""
+    beside = os.path.join(os.path.dirname(os.path.join(ROOT, manifest)),
+                          kind, name + ".json")
+    return beside if os.path.isfile(beside) else os.path.join(
+        HERE, kind, name + ".json")
+
+
+def find_cell(name: str, manifest_file: str = "BENCHMARK.json"):
     """(manifest, cell, configuration, traffic) for a workload name."""
-    manifest = read_json(ROOT, "BENCHMARK.json")
+    manifest = read_json(ROOT, manifest_file)
     cells = {w["name"]: w for w in manifest["workloads"]}
     if name not in cells:
         fail(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
     entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     config = read_json(ROOT, entry["file"])
-    traffic = read_json(HERE, "traffic", cell["traffic"] + ".json")
+    traffic = read_json(cell_file(manifest_file, "traffic", cell["traffic"]))
     return manifest, cell, config, traffic
+
+
+def stated(config: dict) -> dict:
+    """A configuration that lists `parts` states their sum as `patterns`
+    and their count as `partitions`."""
+    if "parts" not in config:
+        return config
+    return {**config, "partitions": len(config["parts"]),
+            "patterns": sum(p["patterns"] for p in config["parts"])}
 
 
 def metrics_of(manifest, group: str, cell_name: str):
@@ -153,13 +178,19 @@ def make_inputs(config: dict, traffic: dict, tag: str, seed: int):
     byteFile; returns (datagen's dict, byteFile path)."""
     from examl_tpu.cli import parse as cli_parse
     os.makedirs(CACHE, exist_ok=True)
-    base = os.path.join(CACHE, f"problem-{tag}.npz")
+    # `problem-<tag>.npz`, the name before parts existed, held one model:
+    # such a file is left alone and the problem made again, to the same
+    # bytes (tests/benchmarks/test_partitioned.py pins them)
+    base = os.path.join(CACHE, f"problem-{tag}.parts.npz")
     if os.path.isfile(base):
         with np.load(base) as z:
             prob = {"patterns": z["patterns"], "tree": str(z["tree"]),
                     "moved_trees": [str(t) for t in z["moved_trees"]],
-                    "model": {"rates": z["rates"], "freqs": z["freqs"],
-                              "alpha": float(z["alpha"])}}
+                    "models": [{"rates": z[f"rates_{k}"],
+                                "freqs": z[f"freqs_{k}"],
+                                "alpha": float(z[f"alpha_{k}"])}
+                               for k in range(len(z["bounds"]))],
+                    "bounds": [tuple(b) for b in z["bounds"].tolist()]}
     else:
         prob = datagen.problem(
             config, traffic.get("trees", 0), traffic.get("spr_moves", 0),
@@ -167,7 +198,9 @@ def make_inputs(config: dict, traffic: dict, tag: str, seed: int):
         tmp = base + f".{os.getpid()}.npz"
         np.savez(tmp, patterns=prob["patterns"], tree=prob["tree"],
                  moved_trees=np.array(prob["moved_trees"], dtype=str),
-                 **prob["model"])
+                 bounds=np.array(prob["bounds"]),
+                 **{f"{key}_{k}": m[key]
+                    for k, m in enumerate(prob["models"]) for key in m})
         os.replace(tmp, base)
     gen = datagen.present(prob, seed)
     wd = os.path.join(CACHE, f"{tag}-{seed}")
@@ -176,10 +209,17 @@ def make_inputs(config: dict, traffic: dict, tag: str, seed: int):
     aln = os.path.join(wd, "aln")
     datagen.write_phylip(aln + ".phy", gen["patterns"], config["datatype"])
     argv = ["-s", aln + ".phy", "-n", aln, "-m", config["parse"]["model"]]
-    if config["parse"].get("partition_line"):
+    if "parts" in config:
+        lines = [f"{p['model']}, {p['name']} = {s + 1}-{e}"
+                 for p, (s, e) in zip(config["parts"], gen["bounds"])]
+    elif config["parse"].get("partition_line"):
+        lines = [config["parse"]["partition_line"].format(
+            patterns=config["patterns"])]
+    else:
+        lines = []
+    if lines:
         with open(aln + ".model", "w") as f:
-            f.write(config["parse"]["partition_line"].format(
-                patterns=config["patterns"]) + "\n")
+            f.write("\n".join(lines) + "\n")
         argv += ["-q", aln + ".model"]
     with contextlib.redirect_stdout(sys.stderr):
         rc = cli_parse.main(argv)
@@ -189,14 +229,16 @@ def make_inputs(config: dict, traffic: dict, tag: str, seed: int):
     return gen, aln + ".binary", wd
 
 
-def build_instance(bytefile: str):
+def build_instance(bytefile: str, cli_args=()):
     """The engine exactly as `cli.main._run` builds it: same argument
     parser, same sharding choice, same loader, same constructor (a copy
-    of chip_smoke.py's `cli_instance`, PR 23)."""
+    of chip_smoke.py's `cli_instance`, PR 23).  `cli_args` are the
+    configuration's own flags (`-M`), after the two every run has."""
     from examl_tpu.cli import main as cli
     from examl_tpu.instance import PhyloInstance
     from examl_tpu.parallel.launch import select_sharding
-    args = cli.build_argparser().parse_args(["-s", bytefile, "-n", "BENCH"])
+    args = cli.build_argparser().parse_args(
+        ["-s", bytefile, "-n", "BENCH", *cli_args])
     sharding = select_sharding(args, args.save_memory,
                                log=lambda m: print(m, file=sys.stderr))
     mult = sharding.num_devices if sharding else 1
@@ -223,23 +265,32 @@ def stated_precision(inst) -> dict:
 
 def capture(tree, inst):
     """What a step answered, read from the program's containers: every
-    edge with its z, and the model as the step left it.  The reference
-    is given z, alpha and (DNA) the exchangeabilities, which a step
-    optimises; frequencies and a protein table are read only to be held
-    against the benchmark's own (`model_table_err`)."""
-    edges = np.array([(p.number, q.number, p.z[0])
+    edge with the z of every branch-length class the instance has
+    (`[E, 2 + C]`; C = 1 without `-M`), every partition's model as the
+    step left it, and the partitions' lnLs as the step's last evaluation
+    left them.  The reference is given z, alpha and (DNA) the
+    exchangeabilities, which a step optimises; frequencies and a protein
+    table are read only to be held against the benchmark's own
+    (`model_table_err`)."""
+    C = inst.num_branch_slots
+    edges = np.array([(p.number, q.number, *p.z[:C])
                       for p, q in tree.all_branches()], dtype=np.float64)
-    (m,) = inst.models
-    return {"edges": edges, "rates": np.array(m.rates, dtype=np.float64),
-            "freqs": np.array(m.freqs, dtype=np.float64),
-            "alpha": float(m.alpha)}
+    return {"edges": edges,
+            "models": [{"rates": np.array(m.rates, dtype=np.float64),
+                        "freqs": np.array(m.freqs, dtype=np.float64),
+                        "alpha": float(m.alpha)} for m in inst.models],
+            "part_lnl": np.array(inst.per_partition_lnl, dtype=np.float64)}
 
 
 def state_key(lnl: float, st: dict) -> str:
+    """One part and one class hash the bytes they hashed before parts
+    existed, so `sample_states` draws the states it drew."""
     h = hashlib.sha1(np.float64(lnl).tobytes())
-    for k in ("edges", "rates", "freqs"):
-        h.update(st[k].tobytes())
-    h.update(np.float64(st["alpha"]).tobytes())
+    h.update(st["edges"].tobytes())
+    for m in st["models"]:
+        h.update(m["rates"].tobytes())
+        h.update(m["freqs"].tobytes())
+        h.update(np.float64(m["alpha"]).tobytes())
     return h.hexdigest()
 
 
@@ -325,8 +376,9 @@ def sample_states(records, spans, n: int, seed: int):
 
 def own_model(config: dict, patterns) -> dict:
     """What is no free parameter, made by the benchmark: empirical
-    frequencies from the generator's matrix, and the published table of
-    a protein model."""
+    frequencies from the generator's matrix (a part's own columns), and
+    the published table of a protein model.  `config` holds `states` and,
+    where the rates are not free, `exchangeabilities`."""
     own = {"freqs": reference.empirical_freqs(patterns, None,
                                               config["states"])}
     if config.get("exchangeabilities"):
@@ -347,29 +399,52 @@ def table_err(own: dict, st: dict) -> float:
     return err
 
 
-def check_states(records, chosen, patterns, config, limits):
-    """Run the f64 reference on the chosen states the timed steps left.
-    Returns {number: (reading, limit)} and whether all hold."""
+def check_states(records, chosen, gen, config, limits):
+    """Run the f64 reference on the chosen states the timed steps left:
+    the sum over the parts of `reference.evaluate` on the part's own
+    columns with its alpha, its free rates or its table, its OWN
+    frequencies and the z of its class.  `lnl_rel_err` is the largest of
+    the total's error and, where there are several, every part's (the
+    program's `per_partition_lnl[k]` against the reference's part k: a
+    model on the wrong partition that the total forgives does not pass);
+    `newton_dz_max` the largest over the classes; `model_table_err` the
+    largest over the parts.  Returns {number: (reading, limit)} and
+    whether all hold."""
     names = ("lnl_rel_err", "newton_dz_max", "model_table_err")
     # no step left a state: nothing was shown to be correct
     numbers = {k: 0.0 if chosen else float("inf") for k in names}
     dom = config["domain"]
-    own = own_model(config, patterns)
+    patterns, bounds = gen["patterns"], gen["bounds"]
+    owns = [own_model({"states": config["states"], **part},
+                      patterns[:, s:e])
+            for part, (s, e) in zip(datagen.parts_of(config), bounds)]
     for i in chosen:
         r = records[i]
         st = r["state"]
-        edges = [(int(a), int(b), float(z)) for a, b, z in st["edges"]]
+        edges = [(int(row[0]), int(row[1]), *map(float, row[2:]))
+                 for row in st["edges"]]
         t0 = time.time()
-        ref, d1, d2 = reference.evaluate(
-            patterns, None, edges, patterns.shape[0],
-            own.get("rates", st["rates"]), own["freqs"], st["alpha"],
+        ref, part_refs, d1, d2 = reference.evaluate_parts(
+            patterns, bounds, edges, patterns.shape[0],
+            [(own.get("rates", m["rates"]), own["freqs"], m["alpha"])
+             for own, m in zip(owns, st["models"])],
             config["rate_categories"])
-        got = {"lnl_rel_err": abs(r["lnl"] - ref) / abs(ref),
-               "newton_dz_max": float(reference.newton_dz(
-                   edges, d1, d2, dom["z_min"], dom["z_max"]).max()),
-               "model_table_err": table_err(own, st)}
+        errs = [abs(r["lnl"] - ref) / abs(ref)]
+        if len(owns) > 1:
+            errs += [abs(got - want) / abs(want)
+                     for got, want in zip(st["part_lnl"], part_refs)]
+        got = {"lnl_rel_err": float(max(errs)),
+               "newton_dz_max": max(float(reference.newton_dz(
+                   [(e[0], e[1], e[2 + c]) for e in edges], d1[c], d2[c],
+                   dom["z_min"], dom["z_max"]).max())
+                   for c in range(len(d1))),
+               "model_table_err": max(table_err(own, m)
+                                      for own, m in zip(owns, st["models"]))}
         print(f"reference: step {i} lnL {r['lnl']!r} reference {ref!r} "
               + " ".join(f"{k} {v:.3e}" for k, v in got.items())
+              + (f" (total {errs[0]:.3e}, largest part "
+                 f"{int(np.argmax(errs[1:]))} {max(errs[1:]):.3e})"
+                 if len(errs) > 1 else "")
               + f" ({time.time() - t0:.1f} s)", file=sys.stderr)
         for k, v in got.items():
             numbers[k] = max(numbers[k], v)
@@ -384,7 +459,8 @@ def check_states(records, chosen, patterns, config, limits):
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              rehearse: bool = False, keep_trace: str | None = None,
              control_env: dict | None = None,
-             data_seed: int | None = None) -> dict:
+             data_seed: int | None = None,
+             manifest_file: str = "BENCHMARK.json") -> dict:
     """One run of one cell; returns the result (the last line's dict).
     `control_env` and `data_seed` are for benchmarks/calibrate.py only:
     the first sets the program's lower-precision switches, and the run
@@ -395,10 +471,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     def mark(name):
         marks.append((name, time.time()))
 
-    manifest, cell_entry, config, traffic = find_cell(workload)
-    limits = read_json(HERE, "correct", workload + ".json")
+    manifest, cell_entry, config, traffic = find_cell(workload,
+                                                      manifest_file)
+    limits = read_json(cell_file(manifest_file, "correct", workload))
     if rehearse:
         config = {**config, **config["rehearse"], "rehearsed": True}
+    config = stated(config)
     dev, peak = claim_device(cell_entry["chips"], rehearse)
     device_init_s = dev.pop("init_s")
     mark("import+device")
@@ -418,16 +496,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     gen, bytefile, workdir = make_inputs(config, traffic, tag, seed)
     mark("inputs+parse")
     obs.reset()
-    inst, data = build_instance(bytefile)
+    inst, data = build_instance(bytefile, config.get("cli_args", ()))
     shutil.rmtree(workdir, ignore_errors=True)    # write little, keep less
     mark("load+engine")
-    got = sum(p.width for p in data.partitions)
-    if (data.ntaxa, got, len(data.partitions)) != (
-            config["taxa"], config["patterns"], config["partitions"]):
+    got = [p.width for p in data.partitions]
+    want = [p["patterns"] for p in datagen.parts_of(config)]
+    if (data.ntaxa, got, len(got)) != (config["taxa"], want,
+                                       config["partitions"]):
         fail(f"loaded {data.ntaxa} taxa x {got} patterns in "
-             f"{len(data.partitions)} partition(s); the configuration "
-             f"states {config['taxa']} x {config['patterns']} in "
-             f"{config['partitions']}")
+             f"{len(got)} partition(s); the configuration states "
+             f"{config['taxa']} x {want} in {config['partitions']}")
+    # zero-weight lanes that pad every partition to whole blocks
+    lanes = sum(b.num_sites for b in inst.buckets.values())
+    padding_share = 1.0 - sum(got) / lanes
     precision = stated_precision(inst)
     if control_env is None and not rehearse and any(
             precision[k] != v for k, v in config["precision"].items()):
@@ -450,7 +531,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         setup_s, device_init_s) + ", ".join(
         f"{b[0]} {b[1] - a[1]:.2f}" for a, b in zip(marks, marks[1:]))
         + "; compile_s %.2f" % snap0["counters"].get(
-            "engine.compile_seconds", 0.0), file=sys.stderr)
+            "engine.compile_seconds", 0.0)
+        + "; %d patterns in %d lanes" % (sum(got), lanes), file=sys.stderr)
     trace_dir = os.path.join(CACHE, f"trace-{tag}-{seed}") if trace else None
     if trace_dir:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -517,11 +599,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     result["step_seconds"] = [round(e - s, 4) for s, e in spans]
     result["device_init_s"] = device_init_s
     result["precision"] = precision
+    result["padding_share"] = padding_share
     if rehearse:
         result["rehearse"] = True
 
-    check, ok = check_states(records, chosen, gen["patterns"], config,
-                             limits)
+    t0 = time.time()
+    check, ok = check_states(records, chosen, gen, config, limits)
+    result["reference_s"] = time.time() - t0
     result["correct"] = bool(ok)
     result["check"] = check
     for name, (value, limit) in check.items():
@@ -539,9 +623,13 @@ def main(argv=None) -> int:
                     help="CPU, tiny sizes: control flow only (tests)")
     ap.add_argument("--keep-trace", metavar="DIR",
                     help="copy the traced run's .xplane.pb there")
+    ap.add_argument("--manifest", default="BENCHMARK.json", metavar="FILE",
+                    help="another manifest than BENCHMARK.json (a path "
+                    "from the checkout's root): a fixture, a draft cell")
     a = ap.parse_args(argv)
     result = run_cell(a.workload, a.seed, a.seconds, bool(a.trace),
-                      rehearse=a.rehearse, keep_trace=a.keep_trace)
+                      rehearse=a.rehearse, keep_trace=a.keep_trace,
+                      manifest_file=a.manifest)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -7,8 +7,8 @@ full=True)`, `branch.tree_evaluate(inst, tree, 2.0)`.  The trees are
 `trees` topologies, each `spr_moves` seeded SPR moves from the
 generating one, cycled; with the traffic file's `branch_lengths:
 generating` they carry the lengths the moves leave them, without it
-none (the parser's defaults).  The model in force is
-the generating one where the model has that parameter free
+none (the parser's defaults).  The model in force on each partition is
+its own generating one where the model has that parameter free
 (exchangeabilities of DNA, alpha), standing for what the first tree's
 `mod_opt` would have fitted.
 """
@@ -19,9 +19,9 @@ from __future__ import annotations
 def prepare(cell, params: dict) -> int:
     from examl_tpu.models.gtr import with_alpha, with_rates
     cell.newicks = cell.gen["moved_trees"][:params["trees"]]
-    gen = cell.gen["model"]
     models = []
-    for part, m in zip(cell.data.partitions, cell.initial_models):
+    for part, m, gen in zip(cell.data.partitions, cell.initial_models,
+                            cell.gen["models"]):
         if part.datatype.name != "AA" or part.model_name == "GTR":
             m = with_rates(m, gen["rates"])
         models.append(with_alpha(m, gen["alpha"]))
