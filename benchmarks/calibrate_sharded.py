@@ -24,9 +24,10 @@ need no mesh stay with `calibrate.py`.
   own) are untouched, so lnL is still the whole alignment's.
 * `--all-layers` (with `--trace 1`): read every per-layer metric of
   BENCHMARK.json in the traced run, also those whose `workloads` list
-  leaves the cell out (PR 28's seven list the one-chip cells, and a PR
-  that adds a cell may not edit an accepted list).  For PERF.md's
-  breakdown of the cell; a reader that finds nothing is left out.
+  or `chips` rule leaves the cell out (since PR 38 PR 28's seven carry
+  no list and are the cell's own; what is left out is the one-chip
+  rooflines and the search's).  For PERF.md's breakdown of the cell; a
+  reader that finds nothing is left out.
 
 One JSON line on stdout, appended to `chiprun_out/calibrate.jsonl`:
 seed, what was planted, `correct`, the numbers compared with their

@@ -9,7 +9,8 @@ One process, JAX touched only here.  Everything about a cell is data:
 `configs/<configuration>.json` holds the sizes, `traffic/<traffic>.json`
 the step kind and its parameters, `steps/<kind>.py` the step,
 `layers/<metric>.json` each per-layer metric with the reader under
-`readers/` that takes it, `correct/<cell>.json` the limits of the
+`readers/` that takes it (and, where it states `chips`, the cells that
+read it: `metrics_of`), `correct/<cell>.json` the limits of the
 comparison with the plain reference, `peaks.json` the chip's peaks.
 The configuration also says what the alignment is: `parts` (a list of
 partitions with a model, a width and a generating model each; without
@@ -120,8 +121,25 @@ def stated(config: dict) -> dict:
 
 
 def metrics_of(manifest, group: str, cell_name: str):
-    return [m for m in manifest[group]
-            if cell_name in m.get("workloads", [cell_name])]
+    """The metrics of a group that are read in a cell: every metric
+    without a `workloads` list, and one with a list where the list names
+    the cell.  A metric whose `layers/` file states `chips` is read in
+    every cell on as many chips, named by the list yet or not: the list
+    is then that rule over the manifest's cells, written out for the
+    driver (tests/benchmarks holds the two equal), and a cell added
+    later reads the metric by its own entry alone."""
+    chips = {w["name"]: w["chips"]
+             for w in manifest.get("workloads", [])}.get(cell_name)
+
+    def read_here(m):
+        layer = os.path.join(HERE, "layers", m["name"] + ".json")
+        if chips is not None and os.path.isfile(layer):
+            rule = read_json(layer).get("chips")
+            if rule is not None:
+                return rule == chips
+        return cell_name in m.get("workloads", [cell_name])
+
+    return [m for m in manifest[group] if read_here(m)]
 
 
 # -- device ------------------------------------------------------------------

@@ -38,6 +38,9 @@ CELLS = [w["name"] for w in MANIFEST["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# a deployment that waits for a public source: no cell of BENCHMARK.json,
+# run through a manifest of its own (PERF.md section 7)
+DRAFT = ["--manifest", "benchmarks/drafts/manifest.json"]
 
 
 def _py(script, args, timeout=600):
@@ -138,8 +141,13 @@ def test_cell_files_are_found_by_name(cell):
         assert lim["lower"] < lim["limit"] < lim["upper"], (cell, lim)
         assert lim["upper"] >= 3 * max(lim["lower"], 1e-300)
     if config["datatype"] == "AA":
-        assert os.path.isfile(os.path.join(
-            BENCH, "models", config["exchangeabilities"] + ".json"))
+        for part in datagen.parts_of(config):
+            assert os.path.isfile(os.path.join(
+                BENCH, "models", part["exchangeabilities"] + ".json"))
+    if "parts" in config:            # what the file states beside them
+        assert config["patterns"] == sum(p["patterns"]
+                                         for p in config["parts"])
+        assert config["partitions"] == len(config["parts"])
 
 
 @pytest.mark.parametrize("metric", [m["name"]
@@ -533,6 +541,17 @@ def test_a_cpu_without_rehearse_is_refused():
     ("aa140x16k.treeset4_bl", ["--control", "clv"]),
     ("dna140x131k.treeset1_bl", ["--fault", "freqs"]),
     ("aa140x16k.treeset4_bl", ["--fault", "freqs"]),
+    # ISSUE 38's cell, once a fault it can have
+    ("dna140x16k.treeset", ["--fault", "unchanged"]),
+    ("dna140x16k.treeset", ["--fault", "half"]),
+    ("dna140x16k.treeset", ["--fault", "altered"]),
+    ("dna140x16k.treeset", ["--fault", "freqs"]),
+    # and the draft of the partitioned protein deployment, through its
+    # own manifest (the two faults that only a partitioned cell can
+    # show: test_parts_and_treeset_cells.py)
+    ("aa140p8x16k.modopt", ["--fault", "unchanged", *DRAFT]),
+    ("aa140p8x16k.modopt", ["--fault", "half", *DRAFT]),
+    ("aa140p8x16k.modopt", ["--fault", "altered", *DRAFT]),
 ])
 def test_control_and_planted_faults_come_out_not_correct(cell, planted):
     """The rest of a run driven with the timed path broken underneath

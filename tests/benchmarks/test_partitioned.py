@@ -1,9 +1,11 @@
 """The harness reads a cell's partitions, models and flags from its
 configuration (ISSUE 37; CPU, tier-1).
 
-What is held here: the five accepted configurations' problems and two
-seeds' column orders are the bytes they were before `parts` existed
-(sha256 taken from the parent commit, 36ead58); `state_key` of a
+What is held here: every cell's problem and two seeds' column orders
+are the bytes `benchmarks/pins/<cell>.json` holds (ISSUE 38: a file a
+cell, so a later PR pins its cell by adding one; for the cells accepted
+before `parts` existed, sha256 taken from that parent commit, 36ead58);
+`state_key` of a
 one-part state is the parent's formula; a configuration in several
 parts gives each part exactly its distinct columns and `--seed` keeps
 every column in its part; the sum-of-parts reference against one
@@ -37,53 +39,29 @@ from benchmarks import run as bench  # noqa: E402
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     MANIFEST = json.load(_f)
 
-# sha256 of (problem, present(seed 7), present(seed 2**31 + 11)) from the
-# parent commit's datagen, by `_digests` below: at rehearse size for
-# every accepted cell, at full size for the 16,384-wide ones
-PARENT = {
-    "dna140x16k.modopt": (
-        "25ac832cc09c856dc49ee55736736777dee749be5790f36f26d1a0bbddd78153",
-        "14e3aecef6d88d3ae64684d9b43dc1640662a136043a3493bf5968212c08ecaa",
-        "ef8552682e6d81cf1f51406166abd2a2d23228d40d67b66877cf2bcba66710a9"),
-    "dna140x131k.treeset1_bl": (
-        "3a614823896a620fa43365cd1c435337abbd8a1971b3fc2bf13f88aea3614959",
-        "14e3aecef6d88d3ae64684d9b43dc1640662a136043a3493bf5968212c08ecaa",
-        "ef8552682e6d81cf1f51406166abd2a2d23228d40d67b66877cf2bcba66710a9"),
-    "aa140x16k.treeset4_bl": (
-        "56f8d7515f8b213e0d578734463153d7229074351f671ae0b30fcb9e33cf4b86",
-        "466514d52262c765140d66c3ee27583e30f559986bc0e9d9cc8953d14fd303fb",
-        "d4a260aff661fa4b38e230de9f5ba15e640caa18d7339e3b64a9fd22ec7a8df7"),
-    "dna140x262k.treeset-c4": (
-        "f6185e65fed735cfc6ad2015bca5963252e55ca8d2a23534c08b237f018db069",
-        "643991f855f59b5b3b03171d2de4c9a5e3a872c9967368dd09a4ad78eb98c3aa",
-        "6acab805f91370faa87e4872825c7feac7a1473347394b10e9c4465034f718a0"),
-    "dna140x16k.search": (
-        "c7e092a28ac06ded9320798f5df769178c6d0321ab8a48211fb88adb92c8962e",
-        "14e3aecef6d88d3ae64684d9b43dc1640662a136043a3493bf5968212c08ecaa",
-        "ef8552682e6d81cf1f51406166abd2a2d23228d40d67b66877cf2bcba66710a9"),
-    "dna49x131k.search": (
-        "c7e092a28ac06ded9320798f5df769178c6d0321ab8a48211fb88adb92c8962e",
-        "14e3aecef6d88d3ae64684d9b43dc1640662a136043a3493bf5968212c08ecaa",
-        "ef8552682e6d81cf1f51406166abd2a2d23228d40d67b66877cf2bcba66710a9"),
-}
-PARENT_FULL = {
-    "dna140x16k.modopt": (
-        "9f7fc2d856d66c106c925eaf0d465e23809426c34580774bd31f30633d0ddee8",
-        "814e1e45390e2ff3fc085a55fa7c52f8d06a6850063ad5f840f6a1abccc9fe3a",
-        "dd8cbd5fd9230bd9f896cbe19b1f5269e1040c1264d285c5953b94500cbe54a2"),
-    "aa140x16k.treeset4_bl": (
-        "45d317ca06a1570e6ec4f98e537a1cf668bc76491e6808ef6dca59027ed976a5",
-        "8e4002061a2f161a0c5b9f0bd6907c3494297dba07c8f519c19a5ebe0b1b359d",
-        "378891258b9c747d6ea92b4d6da390046bc7b905b3122a2cf122f7459b1f5984"),
-    "dna140x16k.search": (
-        "e038e80bfbbb2aff1b2511775cbaf838b9571dcd1c74c1829cfbde839e869b26",
-        "814e1e45390e2ff3fc085a55fa7c52f8d06a6850063ad5f840f6a1abccc9fe3a",
-        "dd8cbd5fd9230bd9f896cbe19b1f5269e1040c1264d285c5953b94500cbe54a2"),
-}
+# a deployment that waits for a public source (PERF.md section 7): no
+# cell of BENCHMARK.json, pinned beside its own manifest all the same
+DRAFT = "benchmarks/drafts/manifest.json"
+with open(os.path.join(REPO, DRAFT)) as _f:
+    DRAFTED = [w["name"] for w in json.load(_f)["workloads"]]
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+PINNED = [(cell, "BENCHMARK.json") for cell in CELLS] + [
+    (cell, DRAFT) for cell in DRAFTED]
 
 
-def _digests(cell: str, full: bool):
-    _, _, config, traffic = bench.find_cell(cell)
+def _width(cell: str, manifest_file: str) -> int:
+    return bench.stated(bench.find_cell(cell, manifest_file)[2])["patterns"]
+
+
+# the cells pinned at full size too: the 16,384-wide ones (wider, the
+# problem takes minutes to make)
+FULL = [(cell, mf) for cell, mf in PINNED if _width(cell, mf) <= 16384]
+
+
+def _digests(cell: str, full: bool, manifest_file: str = "BENCHMARK.json"):
+    """sha256 of (problem, present(seed 7), present(seed 2**31 + 11)):
+    patterns, trees, and every part's model in part order."""
+    _, _, config, traffic = bench.find_cell(cell, manifest_file)
     if not full:
         config = {**config, **config["rehearse"]}
     prob = datagen.problem(
@@ -96,27 +74,114 @@ def _digests(cell: str, full: bool):
         h.update(np.asarray(m["rates"], dtype=np.float64).tobytes())
         h.update(np.asarray(m["freqs"], dtype=np.float64).tobytes())
         h.update(np.float64(m["alpha"]).tobytes())
-    assert prob["bounds"] == [(0, config["patterns"])]
-    return (h.hexdigest(), *(
+    ends = np.cumsum([p["patterns"]
+                      for p in datagen.parts_of(config)]).tolist()
+    assert prob["bounds"] == list(zip([0, *ends[:-1]], ends))
+    return [h.hexdigest(), *(
         hashlib.sha256(datagen.present(prob, seed)["patterns"].tobytes())
-        .hexdigest() for seed in (7, 2**31 + 11)))
+        .hexdigest() for seed in (7, 2**31 + 11))]
 
 
-@pytest.mark.parametrize("cell", sorted(PARENT))
-def test_accepted_problem_and_column_orders_are_the_parents(cell):
+def _pin(cell: str, size: str, manifest_file: str = "BENCHMARK.json"):
+    """The digests `pins/<cell>.json` holds (beside the manifest first,
+    as a cell's traffic and `correct/` files): `rehearse`, and `full`
+    where the cell is 16,384 wide.  The six cells accepted before ISSUE
+    38 hold the strings this file held, taken from 36ead58's datagen.  A
+    cell without the file, or without the size, fails here with the
+    file's name: a later PR pins its cell by ADDING that file."""
+    path = bench.cell_file(manifest_file, "pins", cell)
+    if not os.path.isfile(path):
+        pytest.fail(
+            f"cell {cell} is not pinned: missing "
+            f"{os.path.relpath(path, REPO)}; this tree's digests are "
+            + json.dumps({"rehearse": _digests(cell, False, manifest_file)}))
+    with open(path) as f:
+        pin = json.load(f)
+    if size not in pin:
+        pytest.fail(f"{os.path.relpath(path, REPO)} holds no {size!r} "
+                    f"digests for cell {cell}")
+    return pin[size]
+
+
+@pytest.mark.parametrize("cell,mf", PINNED)
+def test_accepted_problem_and_column_orders_are_the_parents(cell, mf):
     """Another problem is another benchmark (another `data_seed` moved
-    cell 2 from 39 to 147 s a step): one part draws the rng as before."""
-    assert _digests(cell, full=False) == PARENT[cell]
+    cell 2 from 39 to 147 s a step): one part draws the rng as before,
+    and a cell's problem is what it was when the cell was accepted."""
+    assert _digests(cell, False, mf) == _pin(cell, "rehearse", mf)
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("cell", sorted(PARENT_FULL))
-def test_accepted_problem_at_full_size_is_the_parents(cell):
-    assert _digests(cell, full=True) == PARENT_FULL[cell]
+@pytest.mark.parametrize("cell,mf", FULL)
+def test_accepted_problem_at_full_size_is_the_parents(cell, mf):
+    assert _digests(cell, True, mf) == _pin(cell, "full", mf)
 
 
-def test_every_accepted_cell_is_pinned():
-    assert set(PARENT) == {w["name"] for w in MANIFEST["workloads"]}
+@pytest.mark.parametrize("cell,mf", PINNED)
+def test_every_accepted_cell_is_pinned(cell, mf):
+    """By a file of its own, well formed: three sha256 a size, `full`
+    exactly where the cell is 16,384 wide."""
+    with open(bench.cell_file(mf, "pins", cell)) as f:
+        pin = json.load(f)
+    assert set(pin) == {"rehearse"} | ({"full"} if (cell, mf) in FULL
+                                       else set())
+    for digests in pin.values():
+        assert len(digests) == 3
+        assert all(len(d) == 64 and set(d) <= set("0123456789abcdef")
+                   for d in digests)
+
+
+def test_a_ninth_cell_is_pinned_by_adding_files_and_editing_none(tmp_path):
+    """A manifest with one more workload (a traffic file of its own over
+    an accepted configuration): without `pins/<cell>.json` the pin test
+    fails and names the file; with it, written from the digests the
+    message gives, it holds; the accepted cells' pins are still found."""
+    cell, mf = "dna140x16k.treeset2", str(tmp_path / "BENCHMARK.json")
+    manifest = {**MANIFEST, "workloads": MANIFEST["workloads"] + [
+        {"name": cell, "config": "dna140x16k", "traffic": "treeset2",
+         "chips": 1, "why": "a test's: two trees without branch lengths"}]}
+    with open(mf, "w") as f:
+        json.dump(manifest, f)
+    os.mkdir(tmp_path / "traffic")
+    with open(tmp_path / "traffic" / "treeset2.json", "w") as f:
+        json.dump({"kind": "treeset", "trees": 2, "spr_moves": 3,
+                   "check_states": 1}, f)
+    with pytest.raises(pytest.fail.Exception,
+                       match=r"pins/dna140x16k\.treeset2\.json") as exc:
+        _pin(cell, "rehearse", mf)
+    given = json.loads(str(exc.value).split("digests are ")[1])
+    os.mkdir(tmp_path / "pins")
+    with open(tmp_path / "pins" / (cell + ".json"), "w") as f:
+        json.dump(given, f)
+    assert _digests(cell, False, mf) == _pin(cell, "rehearse", mf)
+    # two trees and no lengths: another problem than any accepted cell's,
+    # under cell 1's column orders
+    mine, accepted = given["rehearse"], _pin("dna140x16k.modopt", "rehearse",
+                                             mf)
+    assert mine[0] != accepted[0] and mine[1:] == accepted[1:]
+    with pytest.raises(pytest.fail.Exception, match="holds no 'full'"):
+        _pin(cell, "full", mf)
+    # and it reads the metrics its kind of cell reads, named in no list:
+    # both rooflines by its `chips`, and in a rehearsed traced run's line
+    # PR 28's seven, the gradient slots and the compiled programs
+    names = {m["name"] for m in bench.metrics_of(manifest, "per_layer",
+                                                 cell)}
+    assert names == {m["name"] for m in bench.metrics_of(
+        MANIFEST, "per_layer", "dna140x16k.treeset")}
+    assert {"traverse_roofline", "gradient_roofline"} <= names
+    os.mkdir(tmp_path / "correct")
+    with open(os.path.join(BENCH, "correct", "dna140x16k.treeset.json")) as f:
+        (tmp_path / "correct" / (cell + ".json")).write_text(f.read())
+    proc, lines = _py("run.py", ["--workload", cell, "--seed", "38",
+                                 "--seconds", "1", "--trace", "1",
+                                 "--rehearse"], manifest=mf)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    rec = json.loads(lines[-1])
+    assert rec["correct"] is True and rec["steps"] % 2 == 0
+    assert {"stage_ms", "staged_arrays_per_step", "launch_ms", "wait_ms",
+            "set_models_ms", "opt_control_ms", "trav_evals_per_step",
+            "grad_slots_per_step", "compiled_programs"} <= set(
+        rec["metrics"])
 
 
 def test_state_key_of_one_part_and_one_class_is_the_parents_formula():
@@ -344,13 +409,13 @@ def test_parts_derivatives_against_finite_differences_under_two_classes():
 # -- the fixture through run.py --rehearse ----------------------------------------
 
 
-def _py(script, args, timeout=600):
+def _py(script, args, timeout=600, manifest=FIXTURE):
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_COMPILATION_CACHE_DIR", "EXAML_COMPILE_CACHE",
                         "XLA_FLAGS")}
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
-        [sys.executable, os.path.join(BENCH, script), "--manifest", FIXTURE,
+        [sys.executable, os.path.join(BENCH, script), "--manifest", manifest,
          *args], env=env, cwd=REPO, capture_output=True, text=True,
         timeout=timeout)
     return proc, [ln for ln in proc.stdout.splitlines() if ln.strip()]

@@ -56,7 +56,10 @@ def test_manifest_entries_of_the_cell(cell):
     assert 0 < traffic["rescore_rel_tol"] < 1e-3
     for name in NEW_METRICS:
         (m,) = [m for m in MANIFEST["per_layer"] if m["name"] == name]
-        assert m["workloads"] == sorted(CELLS)
+        # `compiled_programs` reads a counter every engine has: every
+        # cell since ISSUE 38, so no list
+        assert m.get("workloads") == (None if name == "compiled_programs"
+                                      else sorted(CELLS))
 
 
 def test_the_new_configuration_is_the_searchs_own():

@@ -22,7 +22,10 @@ from test_benchmark import BENCH, MANIFEST, _py  # noqa: E402  (puts the
 SPAN_METRICS = ("stage_ms", "staged_arrays_per_step", "launch_ms",
                 "wait_ms", "set_models_ms", "opt_control_ms",
                 "trav_evals_per_step")
-CELLS = ["dna140x16k.modopt", "aa140x16k.treeset4_bl"]
+# every cell of the manifest, and any that a later PR adds: since ISSUE
+# 38 the seven carry no `workloads` list (every step of every kind is
+# made of the phases they read), so no name is typed here
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
 
 
 def _spec(metric):
@@ -48,7 +51,7 @@ def test_rehearsed_modopt_traced_run_reports_the_span_metric(traced_modopt,
     assert math.isfinite(m["value"]) and m["value"] >= 0
     assert m["unit"] == _spec(metric)["unit"]
     entry = next(e for e in MANIFEST["per_layer"] if e["name"] == metric)
-    assert entry["workloads"] == CELLS and entry["moves"] == "step_s"
+    assert "workloads" not in entry and entry["moves"] == "step_s"
 
 
 def test_span_metrics_tile_the_step_and_count_no_second_twice(
@@ -99,11 +102,11 @@ def test_reader_sums_fields_over_matching_timers_and_reads_nothing_absent():
     assert reader.read(run, {"timers": ["^host_schedule$"]}) is None
 
 
-def test_span_metrics_stay_out_of_the_device_bound_cell():
+@pytest.mark.parametrize("cell", CELLS)
+def test_span_metrics_are_read_in_every_cell(cell):
+    """Until ISSUE 38 they listed cells 1 and 3; the phases are the
+    engine's own in every step kind, so the device-bound, the four-chip
+    and the search cells read them too (PERF.md section 3)."""
     from benchmarks import run
-    names = [m["name"] for m in run.metrics_of(
-        MANIFEST, "per_layer", "dna140x131k.treeset1_bl")]
-    assert not set(names) & set(SPAN_METRICS)
-    for cell in CELLS:
-        assert set(SPAN_METRICS) <= {m["name"] for m in run.metrics_of(
-            MANIFEST, "per_layer", cell)}
+    assert set(SPAN_METRICS) <= {m["name"] for m in run.metrics_of(
+        MANIFEST, "per_layer", cell)}
