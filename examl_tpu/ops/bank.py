@@ -213,9 +213,11 @@ def enumerate_families(mode: str = "d", psr: bool = False,
             fams.append("universal")
         fams.append("fast")
     if not save_memory and e.get("EXAML_GRAD_SMOOTH") != "0":
-        # Whole-tree gradient smoothing (ops/gradient.py): one program
-        # per bucketed (steps, width, chunks) shape — like the scan
-        # tier, a small closed family whose key is shape, not topology.
+        # Whole-tree gradient smoothing (ops/gradient.py): ONE program
+        # an engine from 0.5 MiB a row (one entry an outroot step: the
+        # (steps, width, chunks) key holds nothing of the topology), a
+        # few step buckets under it — like the scan tier, a small
+        # closed family whose key is shape, not topology.
         fams.append("grad")
     if psr:
         fams.append("rate_scan")
@@ -408,9 +410,11 @@ def warm_family(inst, tree, family: str) -> None:
                 e.universal_force = v
         return
     if family == "grad":
-        # The whole-tree gradient pass over the run's own tree: the
-        # bucketed (steps, width, chunks) shapes this compiles are the
-        # exact shapes every smoothing sweep of the search reuses.
+        # The whole-tree gradient pass over the run's own tree.  From
+        # 0.5 MiB a row this compiles THE gradient program of the engine
+        # (n steps of one entry whatever tree the search moves to);
+        # under it, the start tree's step bucket, which most sweeps of
+        # the search reuse.
         from examl_tpu.optimize.branch import tree_gradients
         inst.evaluate(tree, full=True)
         tree_gradients(inst, tree)

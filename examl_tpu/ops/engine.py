@@ -1944,12 +1944,15 @@ class LikelihoodEngine:
 
     def grad_wave_cap(self) -> int:
         """Entries an outroot step of this engine's gradient program may
-        hold (`gradient.wave_cap`), from the sites a row holds THERE:
-        the arena's blocks x lanes, a shard's under the mesh, where
-        `_grad_impl` runs inside `shard_map`."""
+        hold (`gradient.wave_cap`), from the bytes a row of the outroot
+        arena holds THERE: the arena's blocks x lanes, a shard's under
+        the mesh, where `_grad_impl` runs inside `shard_map`, times
+        R x K values of the compute dtype."""
         from examl_tpu.ops import gradient
         shards = 1 if self.sharding is None else self.sharding.site_shards
-        return gradient.wave_cap(self.B * self.lane // shards)
+        return gradient.wave_cap(
+            self.B * self.lane // shards * self.R * self.K
+            * np.dtype(self.dtype).itemsize)
 
     def _grad_structure(self, flat):
         from examl_tpu.ops import gradient
@@ -2029,9 +2032,10 @@ class LikelihoodEngine:
         The jit key is shape-only, ("grad", steps, width, chunks), and
         topology ships as runtime data.  The chunks are
         ceil(E / GRAD_CHUNK), a constant of the engine; the width
-        follows the row's sites (`grad_wave_cap`); the steps are n at
-        width 1 (one program an engine, whatever the tree) and a
-        `bucket_len` of the packed waves above it (a few by topology).
+        follows the row's bytes (`grad_wave_cap`): 1 from 0.5 MiB a row,
+        where the steps are n (ONE program an engine, whatever the
+        tree), 8 under it with a `bucket_len` of the packed waves (a
+        few programs by topology).
         `engine.grad_slots` counts the slots both loops ran and
         `engine.grad_live_slots` those that held an entry or an edge.
         """

@@ -34,18 +34,21 @@ threshold/multiplier as newview) but tracks no counts.
 Both loops run the tree's live slots, not a rounded-up count.  The
 edge loop has ceil(E / GRAD_CHUNK) chunks, and E = 2 * ntips - 3 for
 every full traversal: a constant of the engine.  The outroot loop's
-step width W follows the sites a row holds in the program that runs
-(`wave_cap`): 8 entries a step up to 16,384 sites, where a step
-amortises a loop iteration over small rows; 1 from 131,072, where a
-row is megabytes, `take_rows` reads rows one by one anyway and a wide
-step is mostly scratch rows (a tree's root-side waves hold one to
-three entries).  At W = 1 the step count is n = ntips - 2 whatever
-the topology; above it the steps are bucketed (`bucket_len`).  The
-jitted gradient program is keyed ("grad", L, W, n_chunks), and
-therefore eligible for the exported program bank (ops/export_bank.py:
-a restart deserializes the compiled gradient pass instead of
-recompiling it): one program an engine at W = 1, a few step buckets
-by topology above it; topology ships as data.
+step width W follows the bytes a row of the outroot arena holds in the
+program that runs (`wave_cap`): ONE entry a step from 0.5 MiB a row
+(8,192 DNA or 1,639 protein patterns under GAMMA4 in f32), where a
+one-entry step reads its rows by a dynamic slice, scatters one row and
+runs no scratch slot (a tree's root-side waves hold one to three
+entries); 8 under it, where a loop iteration's fixed cost (11 to 20 us
+on a v5e) is no longer small beside a row's bytes and a wide step
+amortises it over the wide waves of a deep tree.  At W = 1 the step
+count is n = ntips - 2 whatever the topology; under the threshold the
+steps are bucketed (`bucket_len`).  The jitted gradient program is
+keyed ("grad", L, W, n_chunks), and therefore eligible for the
+exported program bank (ops/export_bank.py: a restart deserializes the
+compiled gradient pass instead of recompiling it): ONE program an
+engine from 0.5 MiB a row, whatever tree a search moves to, a few step
+buckets by topology under it; topology ships as data.
 """
 
 from __future__ import annotations
@@ -65,12 +68,26 @@ from examl_tpu.utils import bucket_len, next_pow2, z_slots
 GRAD_CHUNK = 32
 
 
-def wave_cap(sites: int) -> int:
-    """Entries an outroot step may hold, from the sites (blocks x lanes)
-    of a row in the program that runs (a shard's under the mesh): a
-    step moves about what 8 entries move at `kernels.ONE_PIECE_SITES`
-    sites, and never fewer than one entry."""
-    return max(1, min(8, 8 * kernels.ONE_PIECE_SITES // sites))
+# Bytes of an outroot-arena row from which an outroot step holds ONE
+# entry: the measured crossing (v5e, PERF.md section 6, PR 39).  On a
+# 140-taxon tree the pass costs 11.4 ms at one entry a step for 14.0 at
+# eight at 1 MiB a row, 55 for 92 at 5 MiB, 6.7 for 9.0 at 0.5 MiB,
+# 7.2 for 6.9 at 0.625 MiB (K = 20), 4.1 for 3.65 at 0.25 MiB, 2.7 for
+# 1.8 at 0.125 MiB: one entry wins or ties from 0.5 MiB and loses
+# under it.  On a deep tree (2,000 taxa, 96 KiB a row) it costs 49.0
+# ms for 25.0 at eight (34.4 at two, 27.7 at four, 23.6 at sixteen):
+# the wide step stays under the threshold, and by the 140-taxon tree's
+# step and slot costs at 0.5 MiB (27 us a one-entry step, 147 us an
+# 8-entry one) the deep tree ties there too (1,998 steps for 384).
+ONE_ENTRY_ROW_BYTES = 1 << 19
+
+
+def wave_cap(row_bytes: int) -> int:
+    """Entries an outroot step may hold, from the bytes of an
+    outroot-arena row (blocks x lanes x R x K x itemsize) in the program
+    that runs (a shard's under the mesh): one from
+    `ONE_ENTRY_ROW_BYTES`, eight under it."""
+    return 1 if row_bytes >= ONE_ENTRY_ROW_BYTES else 8
 
 
 class GradStructure:

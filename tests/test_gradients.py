@@ -173,17 +173,17 @@ TREE_KINDS = ("caterpillar", "balanced", "random")
 
 
 @pytest.mark.parametrize("kind", TREE_KINDS)
-@pytest.mark.parametrize("cap", [1, 2, 8])
-def test_grad_structure_holds_live_slots_in_order(cap, kind):
-    """`GradStructure` at outroot step widths 1, 2 and 8: every entry
+@pytest.mark.parametrize("cap,n_taxa", [(1, 41), (8, 41), (1, 140)])
+def test_grad_structure_holds_live_slots_in_order(cap, n_taxa, kind):
+    """`GradStructure` at the two outroot step widths the rule gives
+    (`gradient.wave_cap`: 1 from 0.5 MiB a row, 8 under it): every entry
     sits in exactly one slot, an entry reads its `up_row` only after
     the step that wrote it (the two root rows are there before the
     loop), padding slots touch the scratch row alone, the edge loop has
     ceil(E / GRAD_CHUNK) chunks, and one entry a step means n steps
-    whatever the topology."""
+    whatever the topology, at 41 taxa and at the cells' 140."""
     from examl_tpu.ops import gradient
     from examl_tpu.tree.topology import Tree
-    n_taxa = 41                                   # E = 79: three chunks
     names = [f"t{i}" for i in range(n_taxa)]
     newick = _shaped_newick(kind, n_taxa)
     if newick is None:
@@ -209,7 +209,8 @@ def test_grad_structure_holds_live_slots_in_order(cap, kind):
                 assert row not in written
                 written[row] = t
     assert len(written) == 2 * n + 2 and gs.scratch not in written
-    assert gs.n_chunks == -(-E // gradient.GRAD_CHUNK) == 3
+    assert gs.n_chunks == -(-E // gradient.GRAD_CHUNK) == {41: 3, 140: 9}[
+        n_taxa]
     assert (~gs.edge_pad).sum() == E
     # an edge a written row, but one for the root edge's two
     assert sorted(gs.edge_x_row[~gs.edge_pad]) == \
@@ -249,13 +250,16 @@ def _per_branch_derivatives(inst, tree, slot):
 @pytest.mark.parametrize("arm", ["gamma", "per_partition_branches", "psr"])
 def test_whole_tree_gradients_equal_at_every_wave_cap(arm, kind,
                                                       monkeypatch):
-    """(d1, d2) of `whole_tree_gradients` with one, two and eight
-    entries an outroot step are one result (f64 here: 1e-12 of the
-    largest derivative), and the per-branch `sumtable` /
-    `nr_derivatives` path's on the branches checked: the step width
-    moves scratch work, not an entry's or an edge's arithmetic."""
+    """(d1, d2) of `whole_tree_gradients` with eight entries an outroot
+    step, with one (the reversed waves laid end to end) and with one in
+    another valid order (plain reversed post-order) are one result (f64
+    here: 1e-12 of the largest derivative), and the per-branch
+    `sumtable` / `nr_derivatives` path's on the branches checked: the
+    step width and the order inside a wave move scratch work, not an
+    entry's or an edge's arithmetic."""
     from examl_tpu.ops import gradient
     from examl_tpu.optimize.branch import tree_gradients
+    from examl_tpu.tree.topology import FlatTraversal
     from examl_tpu.utils import next_pow2
     inst = _arm_instance(arm)
     ntaxa = len(inst.alignment.taxon_names)
@@ -269,8 +273,18 @@ def test_whole_tree_gradients_equal_at_every_wave_cap(arm, kind,
     widest = next_pow2(int(max(tree.flat_full_traversal(
         tree.centroid_branch()).wave_sizes)))
     assert widest >= 2
-    got = {}
-    for cap in (8, 2, 1):
+    build = gradient.build_structure
+
+    def plain(flat, cap):
+        """Every entry a wave of its own: plain reversed post-order."""
+        return build(FlatTraversal(
+            flat.parent, flat.left, flat.right, flat.zl, flat.zr,
+            np.ones(flat.n, dtype=np.int64), flat.ntips), cap)
+
+    got, orders = {}, {}
+    for name, cap, builder in (("waves8", 8, build), ("waves1", 1, build),
+                               ("plain1", 1, plain)):
+        monkeypatch.setattr(gradient, "build_structure", builder)
         for eng in inst.engines.values():
             monkeypatch.setattr(eng, "grad_wave_cap", lambda cap=cap: cap)
             eng._grad_structs.clear()
@@ -287,38 +301,122 @@ def test_whole_tree_gradients_equal_at_every_wave_cap(arm, kind,
             * (gs.n_steps * gs.wave_w + gs.n_chunks * gradient.GRAD_CHUNK)
         if cap == 1:
             assert gs.n_steps == ntaxa - 2
-        got[cap] = (d1, d2)
-    assert np.isfinite(got[8][0]).all() and np.abs(got[8][0]).max() > 0
-    for cap in (1, 2):
-        for a, b in zip(got[cap], got[8]):
+        got[name], orders[name] = (d1, d2), gs.pk[~gs.pk_pad].tolist()
+    assert orders["plain1"] == list(range(ntaxa - 3, -1, -1))
+    if kind != "caterpillar":                     # its waves are entries
+        assert orders["waves1"] != orders["plain1"]
+    assert sorted(orders["waves8"]) == sorted(orders["waves1"])
+    ref = got["waves8"]
+    assert np.isfinite(ref[0]).all() and np.abs(ref[0]).max() > 0
+    for name in ("waves1", "plain1"):
+        for a, b in zip(got[name], ref):
             np.testing.assert_allclose(a, b, rtol=1e-12,
                                        atol=1e-12 * np.abs(b).max())
     for k in (0, 3, len(slots) - 1):
         r1, r2 = _per_branch_derivatives(inst, tree, slots[k])
-        for cap in (1, 2, 8):
-            np.testing.assert_allclose(got[cap][0][k], r1, rtol=1e-9,
-                                       atol=1e-9 * np.abs(got[8][0]).max())
-            np.testing.assert_allclose(got[cap][1][k], r2, rtol=1e-9,
-                                       atol=1e-9 * np.abs(got[8][1]).max())
+        for d1, d2 in got.values():
+            np.testing.assert_allclose(d1[k], r1, rtol=1e-9,
+                                       atol=1e-9 * np.abs(ref[0]).max())
+            np.testing.assert_allclose(d2[k], r2, rtol=1e-9,
+                                       atol=1e-9 * np.abs(ref[1]).max())
 
 
 @pytest.mark.parametrize("sites,cap", [
-    (128, 8), (16_384, 8), (16_512, 7), (32_768, 4), (65_536, 2),
-    (131_072, 1), (262_144, 1)])
-def test_wave_cap_follows_the_rows_sites(sites, cap):
-    """An outroot step moves about what eight entries move at one
-    gathered piece (`kernels.ONE_PIECE_SITES`), never fewer than one
-    entry, and the engine asks with its own arena's blocks x lanes."""
+    (128, 8), (2_048, 8), (3_968, 8), (4_096, 1), (8_192, 1),
+    (65_536, 1), (262_144, 1)])
+def test_wave_cap_follows_the_rows_bytes(sites, cap):
+    """One entry an outroot step from 0.5 MiB a row of the outroot arena
+    (`gradient.ONE_ENTRY_ROW_BYTES`: the crossing the chip read, PERF.md
+    section 6, PR 39), eight under it; the engine asks with its own
+    arena's blocks x lanes x R x K values of its compute dtype, f64
+    here, so 4,096 DNA sites are the chip's 8,192 in f32.  At one entry
+    a step both loops run n + 32 x n_chunks slots a pass whatever the
+    tree."""
     from examl_tpu.ops import gradient
-    assert gradient.wave_cap(sites) == cap
+    assert gradient.wave_cap(sites * 4 * 4 * 8) == cap
+    assert gradient.wave_cap(8_192 * 4 * 4 * 4) == 1        # DNA, f32
+    assert gradient.wave_cap(8_064 * 4 * 4 * 4) == 8
+    assert gradient.wave_cap(1_664 * 4 * 20 * 4) == 1       # protein, f32
+    assert gradient.wave_cap(1_536 * 4 * 20 * 4) == 8
     inst = PhyloInstance(correlated_dna(6, 60))
     (eng,) = inst.engines.values()
+    assert (eng.R, eng.K, np.dtype(eng.dtype).itemsize) == (4, 4, 8)
     assert eng.grad_wave_cap() == 8               # one block of 128
     eng.B = sites // eng.lane
     assert eng.grad_wave_cap() == cap
     tree = inst.random_tree(seed=2)
     flat = tree.flat_full_traversal(tree.centroid_branch())
-    assert eng._grad_structure(flat).wave_w <= cap
+    gs = eng._grad_structure(flat)
+    assert gs.wave_w <= cap
+    if cap == 1:
+        assert gs.n_steps * gs.wave_w + gs.n_chunks * gradient.GRAD_CHUNK \
+            == flat.n + 32 * gs.n_chunks
+
+
+def _spr_moved_newicks(ntaxa, trees, moves, seed):
+    """`trees` topologies `moves` random SPR moves from one random tree
+    (the benchmark's tree-set generator; tips t1..t<ntaxa>)."""
+    from benchmarks import datagen
+    rng = np.random.default_rng(seed)
+    adj, _ = datagen.random_tree(rng, ntaxa)
+    out = []
+    for _ in range(trees):
+        other = {n: list(v) for n, v in adj.items()}
+        for _ in range(moves):
+            datagen.spr_move(rng, other, ntaxa)
+        out.append(datagen.newick(other, ntaxa))
+    return out
+
+
+def test_one_gradient_program_an_engine_whatever_the_tree():
+    """From 0.5 MiB a row (24 taxa x 4,096 DNA patterns in f64 here) the
+    gradient program's shapes hold nothing of the topology: four
+    SPR-moved trees of one engine compile it ONCE, and every pass runs
+    n + 32 x n_chunks slots.  Eight entries a step (a narrow row's
+    width) packs four such trees of 140 taxa into different step
+    counts: the programs a search used to meet uncompiled."""
+    from examl_tpu.io.alignment import build_alignment_data
+    from examl_tpu.ops import gradient
+    from examl_tpu.optimize.branch import tree_gradients
+    ntaxa = 24
+    rng = np.random.default_rng(3)
+    names = [f"t{i + 1}" for i in range(ntaxa)]
+    seqs = ["".join("ACGT"[c] for c in rng.integers(0, 4, 4096))
+            for _ in names]
+    inst = PhyloInstance(build_alignment_data(names, seqs))
+    (eng,) = inst.engines.values()
+    assert eng.B * eng.lane == 4096 and eng.grad_wave_cap() == 1
+
+    def grad_compiles():
+        t = obs.registry().snapshot()["timers"]
+        return t.get("engine.compile_seconds.grad", {"count": 0})["count"]
+
+    newicks = _spr_moved_newicks(ntaxa, 4, 5, seed=39)
+    c0, s0 = grad_compiles(), obs.counter("engine.grad_slots")
+    keys = set()
+    for nw in newicks:
+        tree = inst.tree_from_newick(nw)
+        inst.evaluate(tree, full=True)
+        _, d1, _ = tree_gradients(inst, tree)
+        assert np.isfinite(d1).all()
+        flat = tree.flat_full_traversal(tree.centroid_branch())
+        keys.add(flat.topo_key)
+    n_chunks = -(-(2 * ntaxa - 3) // gradient.GRAD_CHUNK)
+    assert len(keys) == 4                         # four topologies
+    assert grad_compiles() - c0 == 1
+    assert [k for k in eng._fast_jit_cache if k[0] == "grad"] == \
+        [("grad", ntaxa - 2, 1, n_chunks)]
+    assert obs.counter("engine.grad_slots") - s0 == \
+        4 * (ntaxa - 2 + 32 * n_chunks)
+    from examl_tpu.tree.topology import Tree
+    wide = [f"t{i + 1}" for i in range(140)]
+    packed = set()
+    for nw in _spr_moved_newicks(140, 4, 5, seed=39):
+        tree = Tree.from_newick(nw, wide, 1)
+        flat = tree.flat_full_traversal(tree.centroid_branch())
+        assert gradient.build_structure(flat, 1).n_steps == 138
+        packed.add(gradient.build_structure(flat, 8).n_steps)
+    assert len(packed) > 1, packed
 
 
 def test_gradient_bitwise_stable_across_invalidation():

@@ -267,11 +267,13 @@ def test_sharded_whole_tree_gradients_match(sharded):
 
 def test_sharded_engine_takes_the_wave_cap_from_a_shards_row(sharded,
                                                              monkeypatch):
-    """The outroot step width follows the sites a row holds in the
+    """The outroot step width follows the bytes a row holds in the
     program that runs: inside the `shard_map` a chip sees its shard's
     blocks, a quarter of the engine's, and `grad_wave_cap` asks with
-    those.  At 262,144 global patterns that is two entries a step (a
-    shard's 65,536) where one device would take one."""
+    those.  With b1 blocks to the threshold's row, an engine of
+    4 x b1 - 4 blocks takes eight entries a step on four shards (a
+    shard's row is one block short) where one device takes one; at
+    4 x b1 the shards take one too."""
     from examl_tpu.ops import gradient
     from examl_tpu.optimize.branch import tree_gradients
     _, inst4, _, newick = sharded
@@ -288,9 +290,15 @@ def test_sharded_engine_takes_the_wave_cap_from_a_shards_row(sharded,
     inst4.evaluate(tree, full=True)
     tree_gradients(inst4, tree)
     assert seen == [eng.B * eng.lane // 4]        # traced once, a shard's
-    assert eng.grad_wave_cap() == gradient.wave_cap(seen[0]) == 8
-    monkeypatch.setattr(eng, "B", 262_144 // eng.lane)
-    assert eng.grad_wave_cap() == gradient.wave_cap(65_536) == 2
+    block = eng.lane * eng.R * eng.K * np.dtype(eng.dtype).itemsize
+    assert eng.grad_wave_cap() == gradient.wave_cap(
+        seen[0] // eng.lane * block) == 8
+    b1 = -(-gradient.ONE_ENTRY_ROW_BYTES // block)
+    monkeypatch.setattr(eng, "B", 4 * b1 - 4)
+    assert eng.grad_wave_cap() == gradient.wave_cap((b1 - 1) * block) == 8
+    monkeypatch.setattr(eng, "B", 4 * b1)
+    assert eng.grad_wave_cap() == gradient.wave_cap(b1 * block) == 1
+    monkeypatch.setattr(eng, "B", 4 * b1 - 4)
     monkeypatch.setattr(eng, "sharding", None)
     assert eng.grad_wave_cap() == 1
 

@@ -18,6 +18,8 @@ fixtures: one process at a time may load the TPU's library, so the call
 must not happen at import, in conftest, or in an autouse fixture.
 """
 
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -34,15 +36,23 @@ from examl_tpu.ops import fastpath  # noqa: E402
 
 HBM_BYTES = 16 * 1000 ** 3        # one v5e chip: 16 GB (Cloud TPU docs)
 NTAXA = 140
-# The compiler's temporaries for the gradient program while both loops
-# ran the next power of two's slots (PR 35's tree, this file's own
-# compiles): the live-slot shapes hold no more, to the MiB the loops'
-# index tables move.  (PR 36 reads 3,950,129,152 / 3,176,473,088 /
-# 3,996,271,104: the peak is the edge chunk's 32 rows beside the
-# outroot arena, which no step width changes at 131,072.)
-GRAD_TEMP_PR35 = {"dna131k": 3_950_031_872 + 2 ** 20,
-                  "dna65k_chip": 3_243_680_256 + 2 ** 20,
-                  "aa16k": 3_996_271_104 + 2 ** 20}
+# The most temporaries the compiler may count for the gradient program,
+# to the MiB the loops' index tables move: at 131,072 and a chip's
+# 65,536 what it counted while both loops ran the next power of two's
+# slots (PR 35's tree, this file's own compiles; PR 36 read
+# 3,950,129,152 / 3,176,473,088: the peak is the edge chunk's 32 rows
+# beside the outroot arena, which no step width changes at 131,072).
+GRAD_TEMP_MAX = {"dna131k": 3_950_031_872 + 2 ** 20,
+                 "dna65k_chip": 3_243_680_256 + 2 ** 20,
+                 # the 8-entry steps PR 39 took away, ('grad', 28, 8,
+                 # 9), here and on the chip; the one-entry programs read
+                 # 411,315,712 and 3,403,522,048, here and on the chip
+                 "dna16k": 811_964_928 + 2 ** 20,
+                 "aa16k": 3_996_271_104 + 2 ** 20}
+# sha256 (first 16) of `jit__grad_impl`'s lowered text at 131,072
+# patterns on a CPU device, x64 off, since PR 36 (PERF.md section 6):
+# one entry a step there before and after PR 39, so not a letter moved.
+GRAD_TEXT_PR36 = {NTAXA: "22eeddda69dad6bf", 49: "ab1572ab8449bb08"}
 
 
 @pytest.fixture(scope="module")
@@ -118,7 +128,8 @@ def _grad_call(eng, p, flat, st, blocks: int, sharding=None):
     """(jitted gradient pass, its arguments, its structure) as
     `whole_tree_gradients` dispatches them (ops/gradient.py) on an
     engine of `blocks` blocks under `sharding`: the structure's outroot
-    step width follows the row's sites there (`Engine.grad_wave_cap`)."""
+    step width follows the row's bytes there (`Engine.grad_wave_cap`:
+    one entry a step from 0.5 MiB a row)."""
     from examl_tpu.ops import gradient
     from examl_tpu.ops.kernels import OutrootTraversal
     eng._install_row_map(st)
@@ -165,6 +176,20 @@ def _as_shapes(eng, args, blocks: int, place):
     return jax.tree.map(leaf, args)
 
 
+def _on(one_chip):
+    """`place` for a one-chip compile, as the engine lowers: its arrays
+    bring no sharding into the lowering, so the arenas carry none here
+    either, and the small replicated operands carry the described
+    device, which is what sends the compile there.  A `sharding=` on
+    `clv` pins that parameter (`sharding={replicated}`, no propagation
+    to it), and for the one-entry K = 20 gradient program this
+    compiler's `memory_analysis()` then counts 4,605,826,048 B of
+    temporaries where the engine's own lowering reads 3,403,522,048,
+    here and on the chip, though both buffer assignments hold the same
+    3.27 GB to 49 KB (PERF.md section 6, PR 39's review round)."""
+    return lambda kind: one_chip if kind == "replicated" else None
+
+
 def _fits(compiled) -> dict:
     m = compiled.memory_analysis()
     sizes = {"arguments": m.argument_size_in_bytes,
@@ -208,14 +233,16 @@ def _arena_sized_in_loops(text: str, elems: int) -> list:
     return found
 
 
-def _reads_rows_by_index(compiled, arena_elems: int) -> None:
+def _reads_rows_by_index(compiled, arena_elems: int,
+                         gathers: int = 0) -> None:
     """What `kernels.take_rows` is for: the compiler was handed no
-    gather of an arena, so it cut none into pieces by slicing the
-    arena, and no loop holds a copy of one."""
+    gather of an arena (or kept `gathers` one-piece ones, where a row is
+    one piece and `take_rows` hands them over), so it cut none into
+    pieces by slicing the arena, and no loop holds a copy of one."""
     text = compiled.as_text()
     assert " while(" in text                     # the walk has loops to see
     assert operand_slices(text) == 0
-    assert arena_gathers(text) == 0
+    assert arena_gathers(text) == gathers
     assert _arena_sized_in_loops(text, arena_elems) == []
 
 
@@ -237,7 +264,7 @@ def test_chunk_evaluate_program_140x131072_dna(one_chip, chip_compile):
     _, eng, _, p, flat, st = _one_block_engine("DNA")
     fn, args = _chunk_eval_call(eng, p, flat, st)
     compiled = fn.lower(*_as_shapes(eng, args, 1024,
-                                    lambda kind: one_chip)).compile()
+                                    _on(one_chip))).compile()
     sizes = _fits(compiled)
     arena = eng.num_rows * 1024 * 128 * 16 * 4
     assert sizes["arguments"] > arena            # the real-width arena
@@ -256,28 +283,41 @@ def test_gradient_pass_131072_dna(one_chip, chip_compile, ntaxa, shapes):
     fn, args, gs = _grad_call(eng, p, flat, st, 1024)
     assert (gs.n_steps, gs.wave_w, gs.n_chunks) == shapes
     compiled = fn.lower(*_as_shapes(eng, args, 1024,
-                                    lambda kind: one_chip)).compile()
+                                    _on(one_chip))).compile()
     sizes = _fits(compiled)
     outroot = (2 * ntaxa - 1) * 1024 * 128 * 16 * 4
     # 140 taxa: 5.19 GB while the rows were gathered (32 operand
     # slices), 3.95 GB read by index in steps of 8 entries and 16
     # chunks (PR 32 to 35)
-    assert outroot <= sizes["temporaries"] <= GRAD_TEMP_PR35["dna131k"]
+    assert outroot <= sizes["temporaries"] <= GRAD_TEMP_MAX["dna131k"]
     _reads_rows_by_index(compiled, eng.num_rows * 1024 * 128 * 16)
+    cpu = SingleDeviceSharding(jax.devices("cpu")[0])
+    text = fn.lower(*_as_shapes(eng, args, 1024, lambda kind: cpu)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        GRAD_TEXT_PR36[ntaxa]
 
 
-def test_gradient_pass_140x16384_protein(one_chip, chip_compile):
-    """K = 20 at 128 blocks: `take_rows` hands the compiler the row
-    gathers (a row is one piece), and the compiler lowers them to loops
-    of dynamic slices itself: the same three findings."""
-    _, eng, _, p, flat, st = _one_block_engine("AA")
+@pytest.mark.parametrize("datatype,cell,states,gathers", [
+    ("DNA", "dna16k", 4, 2), ("AA", "aa16k", 20, 0)])
+def test_gradient_pass_140x16384(one_chip, chip_compile, datatype, cell,
+                                 states, gathers):
+    """128 blocks, DNA (a row is 1 MiB) and K = 20 (5 MiB): one entry an
+    outroot step since PR 39, n steps whatever the tree, its one-row
+    reads dynamic slices; the edge loop's `take_rows` still hands the
+    compiler its two 32-row gathers (a row is one piece): at K = 20 the
+    compiler lowers them to loops of dynamic slices itself, on DNA it
+    keeps them, whole.  No operand slice, no arena in a loop, and
+    fewer temporaries than the 8-entry step's (411,315,712 for
+    811,964,928 on DNA, 3,403,522,048 for 3,996,271,104 at K = 20,
+    here and on the chip)."""
+    _, eng, _, p, flat, st = _one_block_engine(datatype)
     fn, args, gs = _grad_call(eng, p, flat, st, 128)
-    # a row is one piece: today's 8 entries a step, bucketed; 9 chunks
-    assert gs.wave_w == 8 and gs.n_chunks == 9
+    assert (gs.n_steps, gs.wave_w, gs.n_chunks) == (NTAXA - 2, 1, 9)
     compiled = fn.lower(*_as_shapes(eng, args, 128,
-                                    lambda kind: one_chip)).compile()
-    assert _fits(compiled)["temporaries"] <= GRAD_TEMP_PR35["aa16k"]
-    _reads_rows_by_index(compiled, eng.num_rows * 128 * 128 * 80)
+                                    _on(one_chip))).compile()
+    assert _fits(compiled)["temporaries"] <= GRAD_TEMP_MAX[cell]
+    _reads_rows_by_index(compiled, eng.num_rows * 128 * 128 * 4 * states,
+                         gathers)
 
 
 def test_chunk_evaluate_program_reads_rows_by_index(one_chip, chip_compile):
@@ -288,7 +328,7 @@ def test_chunk_evaluate_program_reads_rows_by_index(one_chip, chip_compile):
     _, eng, _, p, flat, st = _one_block_engine("DNA")
     fn, args = _chunk_eval_call(eng, p, flat, st)
     compiled = fn.lower(*_as_shapes(eng, args, 1024,
-                                    lambda kind: one_chip)).compile()
+                                    _on(one_chip))).compile()
     # 1.77 GB while the rows were gathered (one copy of the arena),
     # 0.54 GB read by index
     assert _fits(compiled)["temporaries"] < 1.0e9
@@ -302,7 +342,7 @@ def test_chunk_evaluate_program_140x16384_protein(one_chip, chip_compile):
     assert eng.K == 20
     fn, args = _chunk_eval_call(eng, p, flat, st)
     compiled = fn.lower(*_as_shapes(eng, args, 128,
-                                    lambda kind: one_chip)).compile()
+                                    _on(one_chip))).compile()
     _fits(compiled)
 
 
@@ -362,9 +402,9 @@ def test_site_sharded_gradient_program_262144_one_all_reduce(
     _, eng, _, p, flat, st = _one_block_engine("DNA")
     blocks = 262144 // 128
     # sh: what select_sharding gives there; a shard's row holds 65,536
-    # sites, so two entries a step
+    # sites, 4 MiB, so one entry a step (two before PR 39)
     _, args, gs = _grad_call(eng, p, flat, st, blocks, sh)
-    assert gs.wave_w == 2 and gs.n_chunks == 9
+    assert (gs.n_steps, gs.wave_w, gs.n_chunks) == (NTAXA - 2, 1, 9)
     compiled = eng._grad_program().lower(*_as_shapes(
         eng, args, blocks, lambda kind: getattr(sh, kind))).compile()
     sizes = _fits(compiled)
@@ -374,7 +414,7 @@ def test_site_sharded_gradient_program_262144_one_all_reduce(
     outroot = (2 * NTAXA - 1) * (blocks // 4) * 128 * 16 * 4
     # 3.75 GB a chip while the rows were gathered (16 operand slices),
     # 3.24 GB read by index
-    assert outroot <= sizes["temporaries"] <= GRAD_TEMP_PR35["dna65k_chip"]
+    assert outroot <= sizes["temporaries"] <= GRAD_TEMP_MAX["dna65k_chip"]
     _reads_rows_by_index(compiled, eng.num_rows * (blocks // 4) * 128 * 16)
     text = compiled.as_text()
     assert "jit__grad_impl" in text.split("\n", 1)[0]   # the trace's name
@@ -398,7 +438,7 @@ def test_newton_program_compiles(one_chip, chip_compile):
             jnp.full(C, 16, dtype=jnp.int32), jnp.zeros(C, dtype=bool),
             eng.models, eng.block_part, eng.weights, eng.tips, None)
     compiled = jax.jit(eng._newton_impl).lower(
-        *_as_shapes(eng, args, 128, lambda kind: one_chip)).compile()
+        *_as_shapes(eng, args, 128, _on(one_chip))).compile()
     _fits(compiled)
 
 
@@ -457,7 +497,7 @@ def test_spr_scan_programs_at_the_search_cells_sizes(
     eng._install_row_map(st)
     fn, args = _scan_call(inst, eng, tree, thorough, scan_rows)
     compiled = fn.lower(*_as_shapes(eng, args, blocks,
-                                    lambda kind: one_chip)).compile()
+                                    _on(one_chip))).compile()
     sizes = _fits(compiled)
     arena = eng.num_rows * blocks * 128 * 16 * 4   # with the scan region
     assert sizes["arguments"] > arena
