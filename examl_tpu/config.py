@@ -130,13 +130,21 @@ def enable_persistent_compilation_cache():
     and for CPU backends in a `host_feature_fingerprint()` partition of
     it; with no fingerprint the CPU cache is DISABLED rather than risk
     serving another microarchitecture's executables (SIGILL — the
-    round-5 bench killer).  EXAML_COMPILE_CACHE=0 disables.
+    round-5 bench killer).  EXAML_COMPILE_CACHE=0 disables.  Cache or
+    none, the `jax.*` compile-pipeline counters (obs/programs.py) count
+    from here on.
 
     Returns the cache path, or None when disabled/unavailable.
     """
     import os
     import re
     import sys
+
+    # Every caller arms the cache before its first program (the CLI, the
+    # benchmark, the tests): the place to start counting what JAX
+    # traces, lowers and compiles, the eager programs of the load too.
+    from examl_tpu.obs import programs
+    programs.install_listener()
 
     root = compile_cache_root()
     jax_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")

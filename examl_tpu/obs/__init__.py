@@ -16,16 +16,16 @@ instruments are gettime() deltas and ExaML_info prints):
   `EXAML_TRACE_DIR`) a Chrome-trace/Perfetto B/E pair naming its parent
   and the dispatch's sequence number.  The span tree of the timed path
   (`opt:` > `engine:<family>` > schedule / stage / launch / wait,
-  `compile:<family>` under launch) is drawn in `obs/trace.py`;
+  `first_call:<family>` > lower / `compile:<family>` / analyze under
+  launch) is drawn in `obs/trace.py`; JAX's own trace / lower / compile
+  events of every program are `jax.*` counters (`obs/programs.py`);
 * a **run ledger** (`obs.ledger`): append-only per-rank JSONL event
   stream (compiles, phases, faults, checkpoint cycles, supervisor
   decisions, probe verdicts), merged by rank 0 into one ordered gang
   timeline at exit;
 * the shared **roofline traffic model** (`obs.traffic`): the one
   bytes-per-traversal definition the engine uses, plus the
-  dispatch-bound vs bandwidth-meaningful regime classifier;
-* a **dispatch-timing helper** (`obs.timing`): every rep lands in the
-  histogram; windows are ledger-audited.
+  dispatch-bound vs bandwidth-meaningful regime classifier.
 
 This module is the flat facade the rest of the runtime imports:
 
@@ -50,7 +50,6 @@ from examl_tpu.obs.ledger import (  # noqa: F401
     merge as merge_ledger, read_events as read_ledger)
 from examl_tpu.obs.metrics import (  # noqa: F401
     maybe_autoflush, set_autoflush)
-from examl_tpu.obs.timing import time_dispatch  # noqa: F401
 from examl_tpu.obs.trace import (  # noqa: F401
     enable as enable_tracing, enabled as tracing_enabled,
     finalize as finalize_tracing, instant, merge_summary, read_events,
@@ -96,6 +95,11 @@ def snapshot() -> dict:
     rows = _programs.table()
     if rows:
         snap["programs"] = rows
+    # ... and the newest compile-pipeline events with the span each fell
+    # in: what compiled late, and inside which dispatch.
+    events = _programs.jit_events()
+    if events:
+        snap["jit_events"] = events
     return snap
 
 
@@ -105,7 +109,11 @@ def snapshot_counters() -> dict:
 
 
 def reset() -> None:
+    """Clear the registry (the `jax.*` counters with it) and the kept
+    compile-pipeline events; listeners and collectors stay."""
     _metrics.registry().reset()
+    from examl_tpu.obs import programs as _programs
+    _programs.clear_jit_events()
 
 
 # -- operator log sink ------------------------------------------------------

@@ -40,10 +40,25 @@ seconds (`self_s`: duration less child spans'):
   engine:tree/schedule, engine:set_models    engine work between dispatches
   engine:<family>                            one dispatch, tiled by
     engine:<family>/schedule|stage|launch|wait   its four phases
-  compile:<family>                           under launch, first calls
+  first_call:<family>                        under launch: a program's
+    first_call:<family>/lower                  whole first call, tiled by
+    compile:<family>                           the observatory's prelower,
+    first_call:<family>/analyze                the jitted call (the compile
+                                               or the cache load), and the
+                                               program table's row
   search:*, phase:*, fleet:*                 off the benchmark's timed path
 
-  engine.compile_count, engine.compile_seconds[.family]
+  engine.compile_count, engine.compile_seconds[.family]   the jitted
+                               first call alone (the `compile:` span)
+  engine.first_call            timer: the whole guarded first call (fed
+                               by the `first_call:<family>` span)
+  program.obs                  timer: what the program observatory's
+                               analysis costs (the `.../analyze` span)
+  jax.trace_*, jax.lower_*, jax.backend_compile_* (_seconds, _count),
+  jax.trace_lower_seconds, jax.cache_hits/misses/retrieval_seconds
+                               JAX's own compile-pipeline events of
+                               EVERY program, guarded or eager
+                               (obs/programs.py: outermost seconds only)
   engine.compile_count.bank_phase      first calls inside the bank phase
   engine.first_calls.banked/unbanked[.family]   post-bank first calls
   engine.first_calls.degraded_inprocess[.family]   deadline-degraded

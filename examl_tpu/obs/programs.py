@@ -32,11 +32,15 @@ Three consumers, all fed from the one registry:
 
 Deep analysis needs a `Compiled`, and jax's jit path does not expose
 the executable it cached — so the observatory AOT-compiles the traced
-lowering once per first call (`lowered.compile()`, timed into
-`program.analyze_seconds`; with a persistent XLA cache armed this is a
-cache deserialize, not a second codegen).  `EXAML_PROGRAM_OBS=rows`
-keeps registry rows but skips that compile; `0` disables the
-observatory.  Exported-bank hits get their analyses free: a
+lowering once per first call (`lowered.compile()`; with a persistent
+XLA cache armed this is a cache deserialize, not a second codegen).
+What the observatory costs a run is timed by two spans of the first-call
+path (`obs/trace.py` draws it): `first_call:<family>/lower` around
+`prelower` and `first_call:<family>/analyze` around `record` (that
+compile, the analyses, the text scans, the row), the second also into
+the timer `program.obs`.  `EXAML_PROGRAM_OBS=rows` keeps registry rows
+but skips the lowering and that compile; `0` disables the observatory
+(neither span opens).  Exported-bank hits get their analyses free: a
 deserialized executable answers `cost_analysis()` directly, which is
 how a zero-compile cold start still populates the table.
 
@@ -52,6 +56,7 @@ error.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -60,6 +65,7 @@ from typing import Dict, List, Optional, Tuple
 
 from examl_tpu.obs import ledger as _ledger
 from examl_tpu.obs import metrics as _metrics
+from examl_tpu.obs import trace as _trace
 
 ENV_VAR = "EXAML_PROGRAM_OBS"
 
@@ -84,7 +90,6 @@ _STATE: Dict[str, object] = {
     "collector": False,
     "listener": False,
 }
-_XLA_CACHE_HITS = [0]
 
 
 def _env_str(name: str, default: str) -> str:
@@ -131,35 +136,126 @@ def reset() -> None:
             pass
 
 
-# -- compile-source attribution ----------------------------------------------
-# jax's persistent compilation cache announces hits through the
-# monitoring event '/jax/compilation_cache/cache_hits'; counting them
-# around a first call is the only non-invasive way to tell a fresh
-# codegen from a cache deserialize.  Registration is best-effort: a
-# jax without the hook just reports every in-process compile as
-# "fresh".
+# -- JAX's compile pipeline, as registry counters ----------------------------
+# `jax.monitoring` reports every program of the process, guarded by the
+# engine or eager (`jnp.full` at a new shape): its trace to a jaxpr, its
+# lowering to MLIR, the backend compile (on a persistent-cache hit: the
+# retrieval) and the cache's own hits and misses.  One listener a kind
+# (duration, event and, for nesting, scalar: `log_elapsed_time` fires
+# `record_scalar(event, start)` on entry and the duration on exit) turns
+# them into counters:
+#
+#   jax.trace_seconds / jax.trace_count      jaxpr_trace_duration
+#   jax.lower_seconds / jax.lower_count      jaxpr_to_mlir_module_duration:
+#                                            one a program that reaches the
+#                                            compiler, THE count of new
+#                                            programs
+#   jax.backend_compile_seconds / _count     backend_compile_duration
+#   jax.trace_lower_seconds                  raised by trace and lower both
+#   jax.cache_hits / jax.cache_misses / jax.cache_retrieval_seconds
+#
+# Inner jitted functions fire the trace event inside their caller's
+# (`matmul` inside `f`) and the outer duration holds theirs, and an eager
+# operation met while tracing lowers and compiles inside that trace: so
+# SECONDS are counted only of an event that is outermost on its thread,
+# whatever its kind, and the three sums are wall seconds; `trace_count`
+# counts outermost traces, the other two every event.  The observatory's
+# own `lowered.compile()` (`_record`) is kept out of the counters: it is
+# `program.obs`'s, not the program's.  The newest JIT_EVENTS_KEPT events
+# (inner traces left out) are kept with the innermost open `obs.span` of
+# their thread, for `obs.snapshot()["jit_events"]` and, with the JSONL
+# writer on, as instants: which program compiled late, in which dispatch.
+# Registration is best-effort: a jax without the hooks counts nothing
+# and reports every in-process compile as "fresh".
 
-def _install_listener() -> None:
+JIT_EVENTS_KEPT = 64
+_PIPELINE = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "jax.cache_hits",
+    "/jax/compilation_cache/cache_misses": "jax.cache_misses",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_jit_events: collections.deque = collections.deque(maxlen=JIT_EVENTS_KEPT)
+_jit_tls = threading.local()    # .depth: open pipeline events; .own: the
+                                # observatory's analysis compile is running
+
+
+def _on_scalar(event, value, **kw):
+    if event in _PIPELINE:
+        _jit_tls.depth = getattr(_jit_tls, "depth", 0) + 1
+
+
+def _on_duration(event, seconds, **kw):
+    own = getattr(_jit_tls, "own", False)
+    if event == _CACHE_RETRIEVAL:
+        if not own:
+            _metrics.registry().inc("jax.cache_retrieval_seconds", seconds)
+        return
+    kind = _PIPELINE.get(event)
+    if kind is None:
+        return
+    depth = _jit_tls.depth = max(0, getattr(_jit_tls, "depth", 1) - 1)
+    if kind == "trace" and depth:
+        return
+    if not own:
+        reg = _metrics.registry()
+        reg.inc(f"jax.{kind}_count")
+        if not depth:
+            reg.inc(f"jax.{kind}_seconds", seconds)
+            if kind != "backend_compile":
+                reg.inc("jax.trace_lower_seconds", seconds)
+    row = {"event": kind, "fun_name": kw.get("fun_name"),
+           "seconds": round(float(seconds), 6), "span": _trace.current()}
+    _jit_events.append(row)
+    _trace.instant(f"jit:{kind}", row)
+
+
+def _on_event(event, **kw):
+    name = _CACHE_EVENTS.get(event)
+    if name is not None and not getattr(_jit_tls, "own", False):
+        _metrics.registry().inc(name)
+
+
+def install_listener() -> None:
+    """Register the three listeners, once a process (`obs.reset()`
+    clears what they counted and keeps them).  Called where the compile
+    cache is armed (`config.enable_persistent_compilation_cache`, before
+    the CLI's and the benchmark's first program), so the eager programs
+    of the load are counted too, and at the latest by the first guarded
+    call (`xla_cache_hits`)."""
     if _STATE["listener"]:
         return
     _STATE["listener"] = True
     try:
         import jax.monitoring as _mon
-
-        def _on_event(event, **kw):
-            if event == "/jax/compilation_cache/cache_hits":
-                _XLA_CACHE_HITS[0] += 1
-
+        _mon.register_scalar_listener(_on_scalar)
+        _mon.register_event_duration_secs_listener(_on_duration)
         _mon.register_event_listener(_on_event)
     except Exception:                        # noqa: BLE001 — optional hook
         pass
 
 
 def xla_cache_hits() -> int:
-    """Monotone count of persistent-cache hits seen so far (installs
-    the monitoring listener on first use)."""
-    _install_listener()
-    return _XLA_CACHE_HITS[0]
+    """Persistent-cache hits counted since the last `obs.reset()`
+    (`jax.cache_hits`; installs the listeners on first use).  Counting
+    them around a first call is the only non-invasive way to tell a
+    fresh codegen from a cache deserialize."""
+    install_listener()
+    return int(_metrics.registry().counter("jax.cache_hits"))
+
+
+def jit_events() -> List[dict]:
+    """The newest JIT_EVENTS_KEPT compile-pipeline events (copies),
+    oldest first: {event, fun_name, seconds, span}."""
+    return [dict(r) for r in list(_jit_events)]
+
+
+def clear_jit_events() -> None:
+    _jit_events.clear()
 
 
 # -- the fallback-not-crash analysis ladder ----------------------------------
@@ -177,11 +273,12 @@ def prelower(fn, args, family: str):
     backend refusals) or deep analysis is off."""
     if mode() != "deep":
         return None
-    try:
-        return fn.lower(*args)
-    except Exception:                        # noqa: BLE001 — ladder rung
-        _metrics.registry().inc("program.analysis_missing.lower")
-        return None
+    with _trace.span(f"first_call:{family}/lower", cat="compile"):
+        try:
+            return fn.lower(*args)
+        except Exception:                    # noqa: BLE001 — ladder rung
+            _metrics.registry().inc("program.analysis_missing.lower")
+            return None
 
 
 def _cost_analysis(compiled, row: dict) -> None:
@@ -359,16 +456,20 @@ def record(family: str, key, source: str, compile_s: float,
            lowered=None, compiled=None) -> Optional[dict]:
     """One registry row per (family, jit key): called by the engine's
     first-call guard (lowered: the pre-dispatch trace; the analysis
-    compile runs here, timed) and by the export bank's load ladder
-    (compiled: the deserialized executable — analyses are free).
+    compile runs here, inside the analyze span) and by the export
+    bank's load ladder (compiled: the deserialized executable —
+    analyses are free).
     Never raises; returns the row (or None when disabled)."""
     if not enabled():
         return None
-    try:
-        return _record(family, key, source, compile_s, lowered, compiled)
-    except Exception:                        # noqa: BLE001 — observability
-        _metrics.registry().inc("program.analysis_missing.record")
-        return None
+    with _trace.span(f"first_call:{family}/analyze", cat="compile",
+                     also="program.obs"):
+        try:
+            return _record(family, key, source, compile_s, lowered,
+                           compiled)
+        except Exception:                    # noqa: BLE001 — observability
+            _metrics.registry().inc("program.analysis_missing.record")
+            return None
 
 
 def _record(family, key, source, compile_s, lowered, compiled):
@@ -377,13 +478,13 @@ def _record(family, key, source, compile_s, lowered, compiled):
            "key": str(key)[:200], "source": source,
            "compile_s": round(float(compile_s), 4)}
     if compiled is None and lowered is not None and mode() == "deep":
-        t0 = time.perf_counter()
+        _jit_tls.own = True
         try:
             compiled = lowered.compile()
         except Exception:                    # noqa: BLE001 — ladder rung
             _missing("compile", row)
-        reg.observe("program.analyze_seconds",
-                    time.perf_counter() - t0)
+        finally:
+            _jit_tls.own = False
     if compiled is not None:
         _analyze(compiled, row)
     if lowered is not None:

@@ -36,8 +36,21 @@ The span tree on the timed path (names are the timers' names too):
           engine:<family>/schedule    also feeds the `host_schedule` timer
           engine:<family>/stage       host values -> device arguments
           engine:<family>/launch      the jitted call until it returns
-            compile:<family>          a first call's compile
+            first_call:<family>       a program's whole first call (also
+                                      feeds the `engine.first_call` timer)
+              first_call:<family>/lower    trace + lowering for the program
+                                      table (obs/programs.prelower; not
+                                      opened under EXAML_PROGRAM_OBS=rows|0)
+              compile:<family>        the jitted call: the compile or the
+                                      cache load, and the launch
+              first_call:<family>/analyze  the table's row: analysis
+                                      compile, analyses, text scans (also
+                                      feeds `program.obs`; not under =0)
           engine:<family>/wait        the blocking read-back
+
+JAX's own trace / lower / compile events of any program, guarded or
+eager, are counters (`jax.*`, obs/programs.py) and, with the JSONL
+writer on, `jit:<kind>` instants naming the span they fell in.
 
 Design constraints of the JSONL file, all from the round-4 postmortem
 (a compile wedged in `recv` with no visibility into which program or
@@ -206,6 +219,12 @@ class span:
             except Exception:    # noqa: BLE001 - tracing never fails a run
                 pass
         return False
+
+
+def current() -> Optional[str]:
+    """The name of this thread's innermost open span, or None."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1].name if stack else None
 
 
 def _default_procid() -> int:

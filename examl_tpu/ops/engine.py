@@ -939,7 +939,9 @@ class LikelihoodEngine:
         registry (engine.compile_count / engine.compile_seconds
         [.family]) and emits a `compile:<family>` span — a wedged
         compile leaves the span's unmatched "B" event as the trace's
-        last line.
+        last line.  Around it the `first_call:<family>` span (timer
+        `engine.first_call`) holds the whole first call: the
+        observatory's prelower before and its analysis after.
 
         Under `--bank` (ops/bank.py) this watchdog is the LAST line of
         defense, not the first: every family compiles ahead of time in
@@ -963,6 +965,16 @@ class LikelihoodEngine:
                     obs.inc("engine.collectives", state["collectives"])
                 return fn(*args)
             state["first"] = False
+            # The whole first call, prelower to the program table's row,
+            # is one span with three children (obs/trace.py draws the
+            # tree): `compile:<family>` and `engine.compile_seconds`
+            # hold the jitted call ALONE; its trace and lowering land in
+            # `first_call:<family>/lower` while the observatory is deep.
+            with obs.span(f"first_call:{family}", cat="compile",
+                          also="engine.first_call"):
+                return first_call(*args)
+
+        def first_call(*args):
             import os as _os
             import threading
 

@@ -316,24 +316,6 @@ def test_supervisor_partial_counters_staleness_gate(tmp_path):
     assert s._partial_counters(0.0) is None          # full exit snapshot
 
 
-# -- time_dispatch: all reps + audited window --------------------------------
-
-
-def test_time_dispatch_records_every_rep_and_ledger_window(tmp_path):
-    ledger.enable(str(tmp_path), proc=0)
-    obs.reset()
-    best = obs.time_dispatch(lambda: None, reps=5, warmup=2,
-                             name="td.unit")
-    t = obs.snapshot()["timers"]["td.unit"]
-    assert t["count"] == 5              # every rep, not best-of-N only
-    assert t["min_s"] <= best <= t["max_s"]
-    assert t["p50_s"] is not None
-    (ev,) = [e for e in ledger.read_events(
-        str(tmp_path / "ledger.p0.jsonl")) if e["kind"] == "dispatch.window"]
-    assert ev["reps"] == 5 and ev["warmup"] == 2
-    assert ev["best_s"] <= ev["total_s"]
-
-
 # -- report tools ------------------------------------------------------------
 
 

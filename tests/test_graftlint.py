@@ -256,9 +256,9 @@ def test_gl003_device_side_asarray_is_clean():
 def test_gl003_registered_seam_may_block():
     # The same blocking pattern inside a registered seam (path AND
     # function name must match config.SYNC_SEAMS) is the measurement.
-    p = project([("examl_tpu/obs/timing.py",
+    p = project([("examl_tpu/fleet/batch.py",
                   HOST_SYNC_BAD.replace("def evaluate",
-                                        "def time_dispatch"))])
+                                        "def collect"))])
     assert check_host_sync(p) == []
 
 

@@ -66,18 +66,6 @@ def test_registry_collector_runs_at_snapshot_and_unregisters():
     assert len(calls) == 2             # dropped after returning False
 
 
-def test_time_dispatch_records_into_registry():
-    before = obs.counter("x")          # unrelated; just exercise facade
-    del before
-    reg = obs.registry()
-    t0 = reg.snapshot()["timers"].get("test.dispatch", {}).get("count", 0)
-    best = obs.time_dispatch(lambda: time.sleep(0.001), reps=3, warmup=1,
-                             name="test.dispatch")
-    assert best >= 0.0005
-    t1 = reg.snapshot()["timers"]["test.dispatch"]["count"]
-    assert t1 - t0 == 3                # warmup is untimed
-
-
 # -- trace JSONL -------------------------------------------------------------
 
 

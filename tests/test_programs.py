@@ -237,8 +237,7 @@ def test_deep_mode_compiles_the_lowering_and_times_it():
     row = programs.record("fast", "k", "fresh", 0.1, lowered=low)
     assert low.compile_calls == 1
     assert row["bytes_accessed"] == 8.0
-    t = obs.registry().snapshot_light()["timers"].get(
-        "program.analyze_seconds")
+    t = obs.registry().snapshot_light()["timers"].get("program.obs")
     assert t and t["count"] >= 1
 
 

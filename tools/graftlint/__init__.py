@@ -22,7 +22,7 @@ stdlib `ast` (no jax import, seconds not minutes):
                                registered pad pickers)
     GL003  hidden host-sync    float()/.item()/bool()/np.asarray on a
                                dispatch result outside the registered
-                               blocking trav-eval / time_dispatch seams
+                               blocking trav-eval seams
     GL004  env-var registry    EXAML_* reads vs tools/graftlint/
                                envregistry.py and the README flag
                                tables: unregistered, dead and

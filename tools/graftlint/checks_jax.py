@@ -17,7 +17,7 @@ PR2/PR5/PR10 line of work exists to prevent.
 GL003 keeps dispatch asynchronous: `float()`/`.item()`/`bool()`/
 `np.asarray` on a dispatch result blocks the host, and only the
 registered blocking trav-eval seams (whose wall time IS the traffic-
-window measurement) and `time_dispatch` are allowed to do that.
+window measurement) are allowed to do that.
 """
 
 from __future__ import annotations
@@ -404,7 +404,7 @@ def check_host_sync(project: Project) -> List[Finding]:
                     "GL003", f.path, node.lineno,
                     f"host sync {sync} on a dispatch result in "
                     f"{fn.name}() — only the registered blocking "
-                    "trav-eval seams and time_dispatch may block "
+                    "trav-eval seams may block "
                     "(register the seam in tools/graftlint/config.py "
                     "if this blocking is the measurement)",
                     f"{f.path}::host-sync::{fn.name}::{src}"))

@@ -8,8 +8,8 @@ failure a fallback-round measurement window cannot afford.  This check
 diffs the two directions:
 
 * EMITTED: every constant (or f-string-prefix) dotted name passed to
-  `obs.inc/gauge/observe/timer`, a span's `also=` timer,
-  `time_dispatch(name=...)` and the ledger-event emitters, across the lint targets — plus the dotted
+  `obs.inc/gauge/observe/timer`, a span's `also=` timer and the
+  ledger-event emitters, across the lint targets — plus the dotted
   constants of the registered EMIT_SURFACES (the jax-free supervisor
   writes counter names as raw snapshot-dict keys).
 * CONSUMED: `obs.counter(...)` reads in runtime code, plus every
@@ -66,8 +66,7 @@ def _emits(lf) -> List[Tuple[str, int, bool]]:
             continue
         cn = call_name(node) or ""
         last = cn.rsplit(".", 1)[-1]
-        is_metric = last in config.OBS_EMIT_METHODS or \
-            last == "time_dispatch"
+        is_metric = last in config.OBS_EMIT_METHODS
         is_ledger = last in config.LEDGER_EMIT_METHODS
         if last in config.OBS_SPAN_METHODS:
             # A span feeds the timer of its own name (never dotted) and,

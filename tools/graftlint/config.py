@@ -68,8 +68,6 @@ SYNC_SEAMS = (
     # Fleet weights-batch evaluation: per-job host lnL rows feed the
     # fsync'd results journal at the batch boundary.
     ("examl_tpu/fleet/batch.py", "eval_weights_batch"),
-    # The ONE dispatch stopwatch (obs/timing.py): blocking is its job.
-    ("examl_tpu/obs/timing.py", "time_dispatch"),
 )
 
 

@@ -38,7 +38,7 @@ _KEY_TIMER_PREFIXES = ("dispatch", "host_schedule",
                        "bank.export_load_seconds",
                        "bank.export_write_seconds",
                        "engine.compile_seconds.", "engine.grad_pass",
-                       "phase.", "program.analyze_seconds")
+                       "phase.", "engine.first_call", "program.obs")
 
 
 def _fmt_s(v) -> str:
@@ -184,6 +184,33 @@ def render_programs(out, snap: dict) -> None:
             + "  (cross-shard ops in the compiled HLO; a fabric "
               "program carries exactly 1 — the site-axis lnL "
               "all-reduce)")
+
+
+def render_jit_events(out, snap: dict) -> None:
+    """What JAX traced, lowered and compiled (obs/programs.py): the
+    `jax.*` counters of every program, guarded or eager, then the newest
+    events with the span each fell in — what compiled late, and inside
+    which dispatch, without a profiler."""
+    c = snap.get("counters") or {}
+    events = snap.get("jit_events") or []
+    if not events and "jax.lower_count" not in c:
+        return
+    out("")
+    out("JIT events (jax.monitoring; seconds of outermost events only):")
+    out("  " + "  ".join(
+        f"{kind}={int(c.get(f'jax.{kind}_count', 0))}"
+        f"/{_fmt_s(c.get(f'jax.{kind}_seconds', 0.0))}"
+        for kind in ("trace", "lower", "backend_compile"))
+        + f"  cache hits={int(c.get('jax.cache_hits', 0))}"
+        f" misses={int(c.get('jax.cache_misses', 0))}"
+        f" retrieval={_fmt_s(c.get('jax.cache_retrieval_seconds', 0.0))}")
+    if events:
+        out(f"  {'event':16s} {'seconds':>8s}  {'fun_name':28s} span")
+    for e in events:
+        out(f"  {str(e.get('event', '?')):16s} "
+            f"{_fmt_s(e.get('seconds')):>8s}  "
+            f"{str(e.get('fun_name') or '-')[:28]:28s} "
+            f"{e.get('span') or '-'}")
 
 
 def render_memory(out, snap: dict) -> None:
@@ -591,6 +618,7 @@ def render(metrics: dict, events: list, bench: dict,
         render_roofline(out, [], "no artifact")
     render_timers(out, metrics)
     render_programs(out, metrics)
+    render_jit_events(out, metrics)
     render_memory(out, metrics)
     render_bank(out, metrics)
     render_fleet(out, metrics, events)
