@@ -180,7 +180,11 @@ class BatchEvaluator:
             st = prev.st
         else:
             with obs.timer("host_schedule"):
-                st = fastpath.build_structure(flat, self.ntips)
+                # One structure serves every engine: the one-entry tail
+                # only where every engine's row is that wide.
+                st = fastpath.build_structure(
+                    flat, self.ntips,
+                    min(e.trav_row_bytes() for e in self.engines))
         pj = PreparedJob(tree, p, flat, st, ("fast", st.profile), z)
         if prev is not None and prev.gs is not None \
                 and prev.flat.topo_key == flat.topo_key:
@@ -520,16 +524,20 @@ class BatchEvaluator:
         ("uni", alphabet, table_bucket, slot_bucket) — a pure function
         of the job's BUCKETED universal-table shape, so topologies
         with entirely different profiles group together.  None for a
-        job without a fast-path structure — the driver falls back to
-        solo routing."""
+        job without a fast-path structure or with a layout the
+        interpreter cannot run — the driver falls back to solo
+        routing."""
         from examl_tpu.ops import universal
         if prep.st is None:
             return None
         eng = self.engines[0]
-        ent = eng._universal_entry(
-            prep.st.profile, np.asarray(prep.st.base),
-            (prep.st.lidx, prep.st.ridx, prep.st.lcode, prep.st.rcode),
-            cache_key=prep.flat.topo_key)
+        try:
+            ent = eng._universal_entry(
+                prep.st.profile, np.asarray(prep.st.base),
+                (prep.st.lidx, prep.st.ridx, prep.st.lcode, prep.st.rcode),
+                cache_key=prep.flat.topo_key)
+        except universal.UniversalIneligible:
+            return None
         table = ent["table"]
         return ("uni", universal.alphabet_key(),
                 bucket_len(table.n_chunks), bucket_len(table.slots))

@@ -236,7 +236,8 @@ def chunk_layout_info() -> dict:
     from examl_tpu.ops import fastpath, universal
     mw, cap = fastpath.MIN_WIDTH, fastpath.CHUNK_CAP
     info = {"min_width": mw, "chunk_cap": cap,
-            "tail_width": fastpath.TAIL_WIDTH}
+            "tail_width": fastpath.TAIL_WIDTH,
+            "one_entry_row_bytes": fastpath.ONE_ENTRY_ROW_BYTES}
     # Universal-interpreter coverage: whether the zero-recompile tier
     # is on and how big its closed class alphabet is — a manifest
     # reader can tell at a glance that this cache serves ANY topology

@@ -1208,22 +1208,41 @@ class LikelihoodEngine:
         self._universal_tables.clear()
         self._grad_structs.clear()
 
+    def trav_row_bytes(self) -> int:
+        """Bytes of a CLV-arena row in the traversal program that runs
+        (blocks x lanes x R x K x the arena's itemsize; a shard's under
+        the mesh): what selects the one-entry tail
+        (`fastpath.ONE_ENTRY_ROW_BYTES`).  0 where the universal
+        interpreter may take the dispatch (forced, or novel profiles
+        routed to it): it runs uniform floor-width steps only, so its
+        layouts keep the 8-wide tail."""
+        if not self.universal_off and (self.universal_force
+                                       or self.route_novel_to_universal):
+            return 0
+        shards = 1 if self.sharding is None else self.sharding.site_shards
+        return (self.B * self.lane // shards * self.R * self.K
+                * np.dtype(self.storage_dtype).itemsize)
+
     def _fast_structure(self, flat):
         from examl_tpu.ops import fastpath
-        st = self._sched_cache.get(flat.topo_key)
+        # Keyed by the row too: routing to the interpreter, switched on
+        # after a structure was cached, changes the layout it needs.
+        row_bytes = self.trav_row_bytes()
+        key = (flat.topo_key, row_bytes)
+        st = self._sched_cache.get(key)
         if st is not None:
-            self._sched_cache.move_to_end(flat.topo_key)
+            self._sched_cache.move_to_end(key)
             obs.inc("engine.sched_cache.hit")
             return st
         obs.inc("engine.sched_cache.miss")
-        st = fastpath.build_structure(flat, self.ntips)
+        st = fastpath.build_structure(flat, self.ntips, row_bytes)
         assert st.max_write <= self.num_rows - 1, \
             (st.max_write, self.num_rows)
         if self.sharding is not None:
             st = st._replace(**self._replicated(
                 {f: getattr(st, f)
                  for f in ("base", "lidx", "ridx", "lcode", "rcode")}))
-        self._sched_cache[flat.topo_key] = st
+        self._sched_cache[key] = st
         while len(self._sched_cache) > self._sched_cache_cap:
             self._sched_cache.popitem(last=False)
             obs.inc("engine.sched_cache.evictions")
@@ -1310,6 +1329,10 @@ class LikelihoodEngine:
                 st, flat, self.num_branch_slots, self.dtype,
                 placement=self.sharding and self.sharding.replicated)
         self._note_fast_program(st.profile)
+        # Write slots the program runs (padding and replays included)
+        # beside the entries they hold, as engine.grad_slots.
+        obs.inc("engine.trav_slots", fastpath.profile_slots(st.profile))
+        obs.inc("engine.trav_live_slots", flat.n)
         if p_num is None:
             fn = self._fast_fn_flat(st.profile, with_eval=False)
             with self._phase("launch"):

@@ -56,10 +56,21 @@ moves bound it:
    run's final step, which is idempotent (a chunk reads only rows
    written strictly before it and rewrites its own rows with identical
    values), so no scratch arithmetic leaks into real rows.
+   Where a CLV-arena row holds `ONE_ENTRY_ROW_BYTES` or more (a shard's
+   row under a mesh), the narrow tail from where its first scan group
+   would start runs instead as ONE `lax.scan` of one entry a step, in
+   wave order: no width-8 slots, no pad sub-chunks for absent kinds
+   (the root-side waves hold one to eight entries, so an 8-wide step
+   per kind was 4-7 padding slots a real row).  A step's kind is data
+   (a tip child's row index is -1 and reads no row), the compute is a
+   `lax.switch` over the three kinds of `chunk_applier`'s kernel at
+   W = 1, and the one-row arena write stays outside it; the length
+   buckets (`bucket_len`) and replays repeat the final entry.
 
 The resulting `profile` is a tuple of segments — `("u", kind, width)`
 for an unrolled block, `("s", glen, ((kind, width), ...))` for a scan
-group — and IS the jit key: program length is O(#segments) ~ O(log n)
+group, `("e", L)` for the one-entry tail — and IS the jit key: program
+length is O(#segments) ~ O(log n)
 (measured: 50k taxa, 1,511 raw chunks -> ~70 unrolled blocks + ~35 scan
 groups), and execution order equals wave order chunk for chunk, so the
 bounded program's lnL is bit-identical to the unbounded unroll.
@@ -94,6 +105,22 @@ TAIL_WIDTH = 64      # waves whose chunks all bucket <= this join the
                      # scanned tail
 MIN_SCAN = 4         # shorter runs stay unrolled (replay padding would
                      # dominate them)
+
+# Bytes of a CLV-arena row (blocks x lanes x R x K x itemsize, a shard's
+# under a mesh) from which the scanned tail runs ONE entry a step (the
+# ("e", L) segment) instead of 8-wide per-kind chunks: the narrowest row
+# the chip probe measured (v5e, PERF.md section 6, PR 41).  A traversal
+# call on the benchmark's 140-taxon tree, today's tail -> one entry, ms:
+# 3.215 -> 2.253 at 1 MiB, 8.995 -> 4.943 at 2, 17.34 -> 10.39 at 4,
+# 48.48 -> 22.18 at 8 (DNA), 25.40 -> 15.80 at 5 MiB (protein, the row
+# read as two half-rows); on the 49-taxon tree 1.993 -> 1.044 at 1 MiB,
+# 24.63 -> 5.04 at 8.  One entry wins by half the tail's time at 1 MiB
+# (in place, in the 16,384-DNA cells, by a fifth: about 21 us a step);
+# under it not measured, so the 8-wide tail stays there.  An engine that
+# may hand a dispatch to the universal interpreter, whose steps are all
+# floor-width, plans the 8-wide tail whatever its row
+# (`Engine.trav_row_bytes`).
+ONE_ENTRY_ROW_BYTES = 1 << 20
 
 
 def slack_rows(ntips: int) -> int:
@@ -160,10 +187,14 @@ class FastStructure(NamedTuple):
 
 def iter_profile_chunks(profile):
     """Yield (kind, width) for every chunk in execution order, scan
-    groups expanded step-major (incl. replay steps)."""
+    groups expanded step-major (incl. replay steps); a one-entry step's
+    kind is data, not profile: (None, 1)."""
     for seg in profile:
         if seg[0] == "u":
             yield seg[1], seg[2]
+        elif seg[0] == "e":
+            for _ in range(seg[1]):
+                yield None, 1
         else:
             _, glen, subs = seg
             for _ in range(glen):
@@ -172,12 +203,13 @@ def iter_profile_chunks(profile):
 
 
 def profile_stats(profile) -> Tuple[int, int, int]:
-    """(unrolled_blocks, scan_groups, total_chunks) of a profile —
+    """(unrolled_blocks, scan_groups, total_chunks) of a profile (a
+    one-entry tail is a scan group) —
     unrolled_blocks + scan_groups is the program's operation count (the
     launch-latency floor per traversal); total_chunks counts every
     chunk incl. scan steps (the raw work-unit count)."""
     un = sum(1 for s in profile if s[0] == "u")
-    sc = sum(1 for s in profile if s[0] == "s")
+    sc = sum(1 for s in profile if s[0] != "u")
     total = sum(1 for _ in iter_profile_chunks(profile))
     return un, sc, total
 
@@ -194,15 +226,17 @@ class _Chunk:
     """Planner-internal chunk record (host only)."""
 
     __slots__ = ("kind", "W", "spans", "real", "pad", "replay_of",
-                 "base", "slot")
+                 "entry", "base", "slot")
 
-    def __init__(self, kind, W, spans, pad=False, replay_of=None):
+    def __init__(self, kind, W, spans, pad=False, replay_of=None,
+                 entry=False):
         self.kind = kind
         self.W = W
         self.spans = spans          # [(lo, hi)] into sorted-entry order
         self.real = sum(hi - lo for lo, hi in spans)
         self.pad = pad              # writes only slack rows
         self.replay_of = replay_of  # index into the final chunk list
+        self.entry = entry          # a step of the one-entry tail
         self.base = -1
         self.slot = -1
 
@@ -220,11 +254,13 @@ def _bucket_w(s: int, mw: int) -> int:
 
 def _plan_layout(kinds: np.ndarray, sizes: np.ndarray, gwave: np.ndarray,
                  starts: np.ndarray, child_key: np.ndarray,
-                 n: int) -> _Layout:
+                 n: int, one_entry: bool = False) -> _Layout:
     """Plan the chunk/segment layout from the (wave, kind)-sorted group
     table.  `child_key[g]` is the max (wave*3+kind) sort key over group
     g's inner children's defining entries (-1 when all children are
-    tips/external) — the vectorized dependency oracle for coalescing."""
+    tips/external) — the vectorized dependency oracle for coalescing.
+    `one_entry`: the narrow tail waves from where today's first tail
+    scan group starts run as ONE ("e", L) segment, an entry a step."""
     G = len(kinds)
     mw, cap, tailw = MIN_WIDTH, CHUNK_CAP, TAIL_WIDTH
 
@@ -338,16 +374,37 @@ def _plan_layout(kinds: np.ndarray, sizes: np.ndarray, gwave: np.ndarray,
                                      pad=chunks[src].pad,
                                      replay_of=src))
 
+    def emit_entries(tail):
+        # One entry a step, in wave order (any order inside a chunk:
+        # its entries are independent); replays repeat the final entry.
+        steps = [_Chunk(c.kind, 1, [(i, i + 1)], entry=True)
+                 for c in tail for lo, hi in c.spans for i in range(lo, hi)]
+        blen = bucket_len(len(steps))
+        profile.append(("e", blen))
+        chunks.extend(steps)
+        last = len(chunks) - 1
+        chunks.extend(_Chunk(chunks[last].kind, 1, chunks[last].spans,
+                             replay_of=last, entry=True)
+                      for _ in range(blen - len(steps)))
+
     run_sig: Optional[tuple] = None
     run_steps: List[List[_Chunk]] = []
+    run_tail = True             # the run holds tail waves only
+    entries: Optional[List[_Chunk]] = None  # the one-entry tail, gathering
 
     def flush():
-        nonlocal run_sig, run_steps
+        nonlocal run_sig, run_steps, run_tail
         if run_steps:
             emit_run(run_sig, run_steps)
-        run_sig, run_steps = None, []
+        run_sig, run_steps, run_tail = None, [], True
 
     for item in stream:
+        if entries is not None:
+            if item[0] == "t":
+                entries.extend(c for c in item[2] if not c.pad)
+                continue
+            emit_entries(entries)
+            entries = None
         if item[0] == "h":
             for c in item[1]:
                 sig = ((c.kind, c.W),)
@@ -355,13 +412,21 @@ def _plan_layout(kinds: np.ndarray, sizes: np.ndarray, gwave: np.ndarray,
                     flush()
                     run_sig = sig
                 run_steps.append([c])
+                run_tail = False
         else:
             _, sig, step = item
             if sig != run_sig:
                 flush()
                 run_sig = sig
             run_steps.append(step)
+            if one_entry and run_tail and len(run_steps) == MIN_SCAN:
+                # Where today's first tail scan group starts, the rest
+                # of the narrow tail runs one entry a step.
+                entries = [c for st in run_steps for c in st if not c.pad]
+                run_sig, run_steps = None, []
     flush()
+    if entries is not None:
+        emit_entries(entries)
 
     return _finish_layout(tuple(profile), chunks, n)
 
@@ -389,7 +454,8 @@ def _finish_layout(profile, chunks, n: int) -> _Layout:
                    max_write=max_write)
 
 
-def _layout_from_arrays(wave_id, el, er, lt, rt, child_nodes_key, n):
+def _layout_from_arrays(wave_id, el, er, lt, rt, child_nodes_key, n,
+                        one_entry: bool = False):
     """Shared planner front-end: group the (wave, kind)-sorted entries
     and plan.  Returns (layout, order, skey-derived group table pieces)
     where `order` is the (wave, kind) stable sort permutation."""
@@ -411,7 +477,8 @@ def _layout_from_arrays(wave_id, el, er, lt, rt, child_nodes_key, n):
                     child_nodes_key[er[order]])
     child_key = (np.maximum.reduceat(ck, starts) if n
                  else np.empty(0, np.int64))
-    layout = _plan_layout(kinds, sizes, gwave, starts, child_key, n)
+    layout = _plan_layout(kinds, sizes, gwave, starts, child_key, n,
+                          one_entry)
     return layout, order
 
 
@@ -461,8 +528,14 @@ def _pack_structure(layout: _Layout, order, el, er, lt, rt, swap, parent,
     rcode = np.zeros(P, np.int32)
     z_src = np.full(P, -1, np.int64)
     z_swap = np.zeros(P, bool)
-    lidx[dst] = np.where(lt_f, 0, row_of[el_f])
-    ridx[dst] = np.where(rt_f, 0, row_of[er_f])
+    # A tip child of a one-entry step reads no row: -1 marks it, and
+    # the step's kind is the count of its children's rows (run_segments).
+    entry = [c for c in layout.chunks if c.entry]
+    es = np.asarray([c.slot for c in entry], np.int64)
+    no_row = np.zeros(P, np.int32)
+    no_row[es] = -1
+    lidx[dst] = np.where(lt_f, no_row[dst], row_of[el_f])
+    ridx[dst] = np.where(rt_f, no_row[dst], row_of[er_f])
     lcode[dst] = np.where(lt_f, el_f - 1, 0)
     rcode[dst] = np.where(rt_f, er_f - 1, 0)
     z_src[dst] = final
@@ -473,15 +546,21 @@ def _pack_structure(layout: _Layout, order, el, er, lt, rt, swap, parent,
         s = layout.chunks[c.replay_of].slot
         for arr in (lidx, ridx, lcode, rcode, z_src, z_swap):
             arr[c.slot:c.slot + c.W] = arr[s:s + c.W]
+    # Wave order is an execution order one entry at a time: a one-entry
+    # step reads only rows that earlier steps wrote.
+    assert (np.maximum(lidx[es], ridx[es])
+            < np.asarray([c.base for c in entry], np.int64)).all()
     base = np.asarray([c.base for c in layout.chunks], np.int32)
     return row_of, base, lidx, ridx, lcode, rcode, z_src, z_swap, dst
 
 
-def build_structure(flat, ntips: int) -> FastStructure:
+def build_structure(flat, ntips: int, row_bytes: int = 0) -> FastStructure:
     """Vectorized schedule-structure build from a FlatTraversal: numpy
     sort/scatter over the whole traversal (this is what makes a
     120k-taxon schedule build array-rate) into the bounded chunk layout
-    of the module docstring."""
+    of the module docstring.  `row_bytes`, the bytes of a CLV-arena row
+    in the program that runs, selects the one-entry tail from
+    `ONE_ENTRY_ROW_BYTES`."""
     n = flat.n
     left = flat.left
     right = flat.right
@@ -497,7 +576,8 @@ def build_structure(flat, ntips: int) -> FastStructure:
     node_key = np.full(2 * ntips - 1, -1, dtype=np.int64)
     node_key[flat.parent] = wave_id * 3 + kind
     layout, order = _layout_from_arrays(
-        wave_id, el, er, lt, rt, node_key, n)
+        wave_id, el, er, lt, rt, node_key, n,
+        row_bytes >= ONE_ENTRY_ROW_BYTES)
     (row_of, base, lidx, ridx, lcode, rcode, z_src, z_swap,
      _dst) = _pack_structure(layout, order, el, er, lt, rt, swap,
                              flat.parent, 2 * ntips - 1)
@@ -552,6 +632,8 @@ def structure_chunks(st: FastStructure, zl, zr) -> Tuple[FastChunk, ...]:
     metas = []
     off = cidx = 0
     for kind, W in iter_profile_chunks(st.profile):
+        if kind is None:        # one-entry step: its children's rows
+            kind = int(li[off] >= 0) + int(ri[off] >= 0)
         views += [li[off:off + W], ri[off:off + W],
                   lc[off:off + W], rc[off:off + W],
                   zl[off:off + W], zr[off:off + W]]
@@ -585,6 +667,37 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
     HI = jax.lax.Precision.HIGHEST
     minlik, two_e, _ = kernels.scale_constants(cdt, scale_exp)
 
+    def take(arena, idx):
+        """Rows `idx` of an arena: `take_rows` for a chunk; a one-entry
+        step's one row by a dynamic slice, which hands the dot the
+        arena's own layout.  The v5e keeps a K = 4 arena's K
+        second-minor, so the dot reads R x K in place; a K = 20 arena
+        keeps R there, and for a one-row slice of it the compiler
+        copies the WHOLE arena into the dot's layout and back, three
+        times a call (PERF.md section 6, PR 41).  Where K > R the row
+        comes as a gather of its two halves of the rate axis instead,
+        which the compiler expands, as it does the head's gathers,
+        into slices copied into a block of the dot's layout: the row's
+        bytes, not the arena's."""
+        if idx.shape[0] > 1:
+            return kernels.take_rows(arena, idx)
+        if arena.ndim == 5 and arena.shape[4] > arena.shape[3] \
+                and arena.shape[3] % 2 == 0:
+            h = arena.shape[3] // 2
+            i = idx[0]
+            starts = jnp.stack([jnp.stack([i, jnp.zeros_like(i)]),
+                                jnp.stack([i, jnp.full_like(i, h)])])
+            dn = jax.lax.GatherDimensionNumbers(
+                offset_dims=(1, 2, 3, 4), collapsed_slice_dims=(0,),
+                start_index_map=(0, 3))
+            halves = jax.lax.gather(
+                arena, starts, dn, (1,) + arena.shape[1:3]
+                + (h, arena.shape[4]),
+                mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+            # [2, B, lane, R/2, K] -> [1, B, lane, R, K]
+            return jnp.moveaxis(halves, 0, 2).reshape((1,) + arena.shape[1:])
+        return jax.lax.dynamic_slice_in_dim(arena, idx[0], 1)
+
     def tip_child(p, code, B, RK):
         # ump[w,m,c,(r a)] = sum_k tipvec[c,k] P[w,m,r,a,k]; contracted
         # against exact one-hot code vectors (MIC umpX generalization).
@@ -605,7 +718,7 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
         W = idx.shape[0]
         pb = jnp.einsum("wmrak,rs->wmrksa", p, eyeR).reshape(W, M, RK, RK)
         pb = pb[:, block_part]                              # [W,B,RK,RK]
-        x = kernels.take_rows(clv, idx).astype(cdt)
+        x = take(clv, idx).astype(cdt)
         x = x.reshape(W, B, lane, RK)
         return jax.lax.dot_general(x, pb,
                                    (((3,), (2,)), ((0, 1), (0, 1))),
@@ -632,30 +745,33 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
         elif ch.kind == 1:
             yl = tip_child(pl, ch.lcode, B, RK)
             yr = inner_child(pr, ch.ridx, clv, B, lane, RK)
-            sc = kernels.take_rows(scaler, ch.ridx)
+            sc = take(scaler, ch.ridx)
         else:
             yl = inner_child(pl, ch.lidx, clv, B, lane, RK)
             yr = inner_child(pr, ch.ridx, clv, B, lane, RK)
-            sc = (kernels.take_rows(scaler, ch.lidx)
-                  + kernels.take_rows(scaler, ch.ridx))
+            sc = take(scaler, ch.lidx) + take(scaler, ch.ridx)
         v = yl * yr                                         # [W,B,lane,RK]
         needs = jnp.max(jnp.abs(v), axis=3) < minlik
         v = jnp.where(needs[..., None], v * two_e, v)
         sc = sc + needs.astype(jnp.int32)
         return v.reshape(W, B, lane, R_, K), sc
 
+    def write(clv, scaler, v, sc, base):
+        """The contiguous in-place arena write of computed rows."""
+        z0 = jnp.zeros((), base.dtype if hasattr(base, "dtype")
+                       else jnp.int32)
+        clv = jax.lax.dynamic_update_slice(
+            clv, v.astype(clv.dtype), (base, z0, z0, z0, z0))
+        scaler = jax.lax.dynamic_update_slice(scaler, sc, (base, z0, z0))
+        return clv, scaler
+
     @jax.named_scope("examl/newview")
     def apply(clv, scaler, ch: FastChunk):
         v, sc = values(clv, scaler, ch)
-        z0 = jnp.zeros((), ch.base.dtype if hasattr(ch.base, "dtype")
-                       else jnp.int32)
-        clv = jax.lax.dynamic_update_slice(
-            clv, v.astype(clv.dtype), (ch.base, z0, z0, z0, z0))
-        scaler = jax.lax.dynamic_update_slice(scaler, sc,
-                                              (ch.base, z0, z0))
-        return clv, scaler
+        return write(clv, scaler, v, sc, ch.base)
 
     apply.values = values
+    apply.write = write
     return apply
 
 
@@ -692,6 +808,14 @@ def run_segments(profile, base, lidx, ridx, lcode, rcode, zl, zr,
     def window(a, o, w):
         return jax.lax.slice_in_dim(a, o, o + w)
 
+    def entry_branch(kind):
+        def branch(c, s, li, ri, lc, rc, zl_, zr_):
+            return apply.values(c, s, FastChunk(kind, 1, None, li, ri, lc,
+                                                rc, zl_, zr_))
+        return branch
+
+    entry_branches = [entry_branch(k) for k in (0, 1, 2)]
+
     for seg in profile:
         if seg[0] == "u":
             _, k, W = seg
@@ -703,7 +827,10 @@ def run_segments(profile, base, lidx, ridx, lcode, rcode, zl, zr,
             off += W
             coff += 1
             continue
-        _, glen, subs = seg
+        if seg[0] == "e":
+            glen, subs = seg[1], ((None, 1),)
+        else:
+            _, glen, subs = seg
         SW = sum(w for _, w in subs)
         ns = len(subs)
         span = glen * SW
@@ -729,7 +856,22 @@ def run_segments(profile, base, lidx, ridx, lcode, rcode, zl, zr,
                 o += W
             return (c, s), None
 
-        (clv, scaler), _ = jax.lax.scan(body, (clv, scaler), xs)
+        def entry_body(carry, x):
+            # One entry a step; its kind is data: the count of its
+            # children's rows (-1 marks a tip child, which reads none).
+            # Only the computing is switched; the write of the one row
+            # stays outside the conditional (ops/universal.py: XLA
+            # copies carry buffers written inside cond branches).
+            c, s = carry
+            b, li, ri, lc, rc, zl_, zr_ = x
+            kind = ((li[0] >= 0).astype(jnp.int32)
+                    + (ri[0] >= 0).astype(jnp.int32))
+            v, sc = jax.lax.switch(kind, entry_branches, c, s, li, ri,
+                                   lc, rc, zl_, zr_)
+            return apply.write(c, s, v, sc, b[0]), None
+
+        (clv, scaler), _ = jax.lax.scan(
+            entry_body if seg[0] == "e" else body, (clv, scaler), xs)
         off += span
         coff += glen * ns
     return clv, scaler

@@ -129,13 +129,17 @@ def build_table(profile, base: np.ndarray,
     ladder widths are all multiples of the floor, chunk entries are
     independent, and every per-entry op in the kernel batches over the
     width axis, so sub-steps compute bit-identical rows.  Raises
-    UniversalIneligible for widths off the `knobs` ladder or an empty
-    profile."""
+    UniversalIneligible for widths off the `knobs` ladder, a one-entry
+    tail or an empty profile."""
     from examl_tpu.ops import fastpath
 
     if knobs is None:
         knobs = alphabet_key()
     mw, cap = knobs
+    if any(seg[0] == "e" for seg in profile):
+        raise UniversalIneligible(
+            "one-entry tail ('e' segment): a step's kind is data and its "
+            "width 1, off the interpreter's uniform-width alphabet")
     kinds_w = list(fastpath.iter_profile_chunks(profile))
     if not kinds_w:
         raise UniversalIneligible("empty traversal")

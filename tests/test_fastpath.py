@@ -480,3 +480,232 @@ def test_program_gauges_published(sdata):
             + g["engine.scan_groups" + tag]
             == g["engine.dispatches_per_traversal" + tag])
     assert g["engine.program_chunks" + tag] <= 256
+
+
+# -- the one-entry tail (PR 41) ----------------------------------------------
+# From `fastpath.ONE_ENTRY_ROW_BYTES` a row, the narrow tail waves from
+# where the first tail scan group starts run as ONE ("e", L) segment:
+# one entry a step in wave order, its kind data (a tip child's row
+# index is -1), replays repeating the final entry.
+
+from examl_tpu.tree.topology import Tree  # noqa: E402
+from examl_tpu.utils import bucket_len  # noqa: E402
+
+
+def _random_flat(ntaxa, seed=3):
+    tree = Tree.random([f"t{i}" for i in range(ntaxa)], seed=seed)
+    p = tree.centroid_branch()
+    if tree.is_tip(p.number):
+        p = p.back
+    return tree.flat_full_traversal(p)
+
+
+def _entry_steps(st):
+    """(first chunk index, first slot, L) of a structure's ("e", L)
+    segment, the profile's last."""
+    *head, (tag, L) = st.profile
+    assert tag == "e" and all(seg[0] != "e" for seg in head)
+    return (sum(1 for _ in fastpath.iter_profile_chunks(head)),
+            fastpath.profile_slots(head), L)
+
+
+FIELDS = ("base", "lidx", "ridx", "lcode", "rcode")
+
+
+@pytest.mark.parametrize("ntaxa", [49, 140, 2000])
+def test_threshold_selects_the_one_entry_tail(ntaxa):
+    """Under the threshold a structure is today's, byte for byte; at it
+    the head is today's up to the first tail scan group and the rest is
+    one ("e", L) segment of L = bucket_len(n_tail) one-slot steps."""
+    flat = _random_flat(ntaxa)
+    today = fastpath.build_structure(flat, ntaxa)
+    below = fastpath.build_structure(flat, ntaxa,
+                                     fastpath.ONE_ENTRY_ROW_BYTES - 1)
+    at = fastpath.build_structure(flat, ntaxa, fastpath.ONE_ENTRY_ROW_BYTES)
+    assert below.profile == today.profile
+    for f in FIELDS:
+        assert np.array_equal(np.asarray(getattr(below, f)),
+                              np.asarray(getattr(today, f))), f
+    for f in ("row_of", "z_src", "z_swap"):
+        assert np.array_equal(getattr(below, f), getattr(today, f)), f
+    assert below.max_write == today.max_write
+    c0, s0, L = _entry_steps(at)
+    k = len(at.profile) - 1
+    assert at.profile[:k] == today.profile[:k]
+    assert today.profile[k][0] == "s" and len(today.profile[k][2]) > 1
+    n_tail = flat.n - int(np.asarray(at.base)[c0])
+    assert L == bucket_len(n_tail)
+    assert fastpath.profile_slots(at.profile) == s0 + L < \
+        fastpath.profile_slots(today.profile)
+    assert at.max_write <= today.max_write
+
+
+@pytest.mark.parametrize("ntaxa", [49, 140, 2000])
+def test_one_entry_tail_layout(ntaxa):
+    """The ("e", L) segment writes every tail entry exactly once, each
+    after the entries whose rows it reads (the planner asserts it too);
+    a tip child's slot reads no arena row; replays only rewrite their
+    source row; `row_of` still maps every node."""
+    flat = _random_flat(ntaxa)
+    n = flat.n
+    st = fastpath.build_structure(flat, ntaxa, fastpath.ONE_ENTRY_ROW_BYTES)
+    c0, s0, L = _entry_steps(st)
+    base = np.asarray(st.base)[c0:]
+    li, ri, lc, rc = (np.asarray(getattr(st, f))[s0:] for f in FIELDS[1:])
+    src, swap = st.z_src[s0:], st.z_swap[s0:]
+    assert base.shape[0] == L == li.shape[0]
+    n_tail = n - int(base[0])
+    real = slice(0, n_tail)
+    # every tail row once, in step order; every tail entry once
+    assert np.array_equal(base[real], np.arange(n - n_tail, n))
+    assert sorted(src[real]) == sorted(set(range(n)) - set(st.z_src[:s0]))
+    assert np.array_equal(st.row_of[flat.parent[src[real]]], base[real])
+    # children: tips read no row (-1), inner children earlier rows
+    left = np.where(swap, flat.right[src], flat.left[src])
+    right = np.where(swap, flat.left[src], flat.right[src])
+    for child, idx, code in ((left, li, lc), (right, ri, rc)):
+        tip = child <= ntaxa
+        assert (idx[tip] == -1).all() and (code[tip] == child[tip] - 1).all()
+        assert (idx[~tip] == st.row_of[child[~tip]]).all()
+        assert (idx[~tip] < base[~tip]).all()
+    assert ((li < 0) | (ri >= 0)).all()       # canonical: the tip is left
+    # replays: the final real step again, rows and all
+    for a in (base, li, ri, lc, rc, src, swap):
+        assert (a[n_tail:] == a[n_tail - 1]).all()
+    # the arena map is whole
+    assert np.array_equal(np.sort(st.row_of[st.row_of >= 0]), np.arange(n))
+    assert (st.row_of[flat.parent] >= 0).all()
+    assert fastpath.profile_slots(st.profile) == st.z_src.shape[0]
+
+
+def _partitioned(nparts=4, ntaxa=24, width=60, seed=5):
+    """DNA in `nparts` partitions: one engine, M = nparts (block_part)."""
+    import os
+    import tempfile
+
+    from examl_tpu.io.partitions import parse_partition_file
+    rng = np.random.default_rng(seed)
+    seqs = ["".join("ACGT"[c] for c in rng.integers(0, 4, nparts * width))
+            for _ in range(ntaxa)]
+    with tempfile.NamedTemporaryFile("w", suffix=".model",
+                                     delete=False) as f:
+        for k in range(nparts):
+            f.write(f"DNA, g{k} = {k * width + 1}-{(k + 1) * width}\n")
+        path = f.name
+    try:
+        return build_alignment_data([f"t{i}" for i in range(ntaxa)], seqs,
+                                    specs=parse_partition_file(path))
+    finally:
+        os.unlink(path)
+
+
+ONE_ENTRY_CASES = {
+    "DNA": (lambda: _synth(), {}),
+    "AA": (lambda: _synth(datatype="AA"), {}),
+    "parts4": (_partitioned, {}),
+    "per_partition_branches": (_partitioned,
+                               {"per_partition_branches": True}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_ENTRY_CASES))
+def test_one_entry_tail_matches_today_and_scan(case, monkeypatch):
+    """f64: the one-entry tail's lnL against today's tail and against
+    the scan tier, to 1e-12 relative (K = 4, K = 20, four partitions in
+    one engine through `block_part`, per-partition branch lengths)."""
+    make, kw = ONE_ENTRY_CASES[case]
+    data = make()
+    got = {}
+    for mode, threshold in (("one_entry", 0), ("today", 1 << 62),
+                            ("scan", 1 << 62)):
+        monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", threshold)
+        inst, _, got[mode] = _eval(data, force_scan=mode == "scan", **kw)
+        (eng,) = inst.engines.values()
+        fast = [k[1] for k in eng._fast_jit_cache if k[0] == "fast"]
+        if mode == "scan":
+            assert not fast
+        else:
+            assert len(fast) == 1 and \
+                (fast[0][-1][0] == "e") == (mode == "one_entry")
+    if case.startswith("parts"):
+        assert eng.num_parts == 4
+    assert got["one_entry"] == pytest.approx(got["today"], rel=1e-12)
+    assert got["one_entry"] == pytest.approx(got["scan"], rel=1e-12)
+
+
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_one_entry_segment_matches_unrolled_bitwise(datatype):
+    """The ("e", L) scan switches over the step's kind and runs the
+    chunk kernel at W = 1: rows and scalers bit-equal to the unrolled
+    `run_chunks` over the same steps, whose kinds the host reads from
+    the -1 marks (`structure_chunks`)."""
+    inst = PhyloInstance(_synth(datatype=datatype))
+    (eng,) = inst.engines.values()
+    tree = inst.random_tree(3)
+    p = tree.centroid_branch()
+    flat = tree.flat_full_traversal(p.back if tree.is_tip(p.number) else p)
+    n = inst.alignment.ntaxa
+    st = fastpath.build_structure(flat, n, fastpath.ONE_ENTRY_ROW_BYTES)
+    _entry_steps(st)
+    zl, zr = fastpath.refresh_z(st, flat, eng.num_branch_slots, eng.dtype)
+    chunks = fastpath.structure_chunks(st, zl, zr)
+    assert {c.kind for c in chunks if c.width == 1} == {1, 2}
+    c1, s1 = fastpath.run_chunks(
+        eng.models, eng.block_part, eng.tips, jnp.array(eng.clv),
+        jnp.array(eng.scaler), chunks, eng.scale_exp, eng.fast_precision)
+    apply = fastpath.chunk_applier(eng.models, eng.block_part, eng.tips,
+                                   eng.scale_exp, eng.fast_precision)
+    c2, s2 = fastpath.run_segments(
+        st.profile, st.base, st.lidx, st.ridx, st.lcode, st.rcode,
+        zl, zr, jnp.array(eng.clv), jnp.array(eng.scaler), apply)
+    rows = np.sort(st.row_of[st.row_of >= 0])
+    assert (np.asarray(c1)[rows] == np.asarray(c2)[rows]).all()
+    assert (np.asarray(s1)[rows] == np.asarray(s2)[rows]).all()
+
+
+@pytest.mark.parametrize("mode", ["force", "route"])
+def test_one_entry_tail_stays_off_the_interpreter_path(sdata, monkeypatch,
+                                                       mode):
+    """The universal interpreter's alphabet is uniform-width: it refuses
+    a one-entry tail by name, so an engine that may hand a dispatch to
+    it (forced, or novel profiles routed there) plans the 8-wide tail
+    whatever its row, and the interpreter runs it with the one-entry
+    tail's lnL."""
+    from examl_tpu.ops import universal
+    with pytest.raises(universal.UniversalIneligible, match="one-entry"):
+        universal.build_table((("u", 0, 8), ("e", 4)), np.zeros(5))
+    monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", 1)
+    inst = PhyloInstance(sdata)
+    (eng,) = inst.engines.values()
+    tree = inst.random_tree(3)
+    lnl_e = inst.evaluate(tree, full=True)
+    assert [st.profile[-1][0] for st in eng._sched_cache.values()] == ["e"]
+    setattr(eng, {"force": "universal_force",
+                  "route": "route_novel_to_universal"}[mode], True)
+    assert eng.trav_row_bytes() == 0
+    c0 = obs.counter("engine.universal_ineligible")
+    u0 = obs.counter("engine.universal_dispatches")
+    lnl_u = inst.evaluate(tree, full=True)
+    assert eng._last_universal
+    assert obs.counter("engine.universal_ineligible") == c0
+    assert obs.counter("engine.universal_dispatches") > u0
+    assert lnl_u == pytest.approx(lnl_e, rel=1e-12)
+
+
+def test_trav_slots_count_the_program_slots(sdata, monkeypatch):
+    """`engine.trav_slots` rises by the write slots the program ran
+    (padding and replays included), `engine.trav_live_slots` by the
+    entries: the one-entry tail takes its padding slots away."""
+    got = {}
+    for threshold in (1 << 62, 0):
+        monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", threshold)
+        s0 = obs.counter("engine.trav_slots")
+        l0 = obs.counter("engine.trav_live_slots")
+        inst, _, _ = _eval(sdata)
+        (eng,) = inst.engines.values()
+        st = next(iter(eng._sched_cache.values()))
+        assert obs.counter("engine.trav_slots") - s0 == \
+            fastpath.profile_slots(st.profile)
+        assert obs.counter("engine.trav_live_slots") - l0 == st.num_rows
+        got[threshold] = fastpath.profile_slots(st.profile)
+    assert got[0] < got[1 << 62]

@@ -129,6 +129,23 @@ def test_tree_batch_bit_identical_gamma():
         assert float(per_part[j].sum()) == lnl     # BITWISE
 
 
+def test_tree_batch_one_entry_tail(monkeypatch):
+    """The vmapped chunk program runs the one-entry tail (its per-step
+    `lax.switch` becomes a select under vmap) where every engine's row
+    is that wide: the batch equals the one-at-a-time evaluations, which
+    run it too."""
+    from examl_tpu.ops import fastpath
+    monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", 0)
+    data = correlated_dna(14, 200, seed=3)
+    inst = PhyloInstance(data)
+    ev, group = _profile_group(inst)
+    assert all(prep.key[1][-1][0] == "e" for _, prep in group)
+    singles = [inst.evaluate(t, full=True) for t, _ in group]
+    per_part = ev.eval_batch([prep for _, prep in group])
+    for j, lnl in enumerate(singles):
+        assert float(per_part[j].sum()) == pytest.approx(lnl, rel=1e-12)
+
+
 def test_tree_batch_bit_identical_per_partition_branches():
     """C>1 (-M): per-partition branch lengths ride the batched z axis."""
     from examl_tpu.io.partitions import parse_partition_file

@@ -359,17 +359,25 @@ def test_device_degraded_init_survives(monkeypatch):
 # -- batched universal (select_n) --------------------------------------------
 
 
-def test_unibatch_bit_identical_and_measured(monkeypatch):
+@pytest.mark.parametrize("one_entry", [False, True])
+def test_unibatch_bit_identical_and_measured(monkeypatch, one_entry):
     """The vmapped select_n universal interpreter scores mixed-profile
     novel jobs bit-identically to solo switch-based routing.  The
     measured CPU verdict (driver.py): ~3x per-step compute makes it a
     dispatch-bound-only win, so it is OPT-IN (EXAML_FLEET_UNIBATCH=1)
     and `fleet.universal_retrace` counts the solo dispatches a batched
-    program would merge."""
+    program would merge.  With every row past the one-entry threshold
+    the routed layouts keep the 8-wide tail the interpreter runs, and a
+    one-entry layout gets no batch key: it dispatches solo."""
     from examl_tpu import obs
     from examl_tpu.fleet.driver import FleetDriver
     from examl_tpu.fleet.jobs import make_jobs
-    data = correlated_dna(10, 160, seed=1)
+    from examl_tpu.ops import fastpath
+    if one_entry:
+        monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", 1)
+    # 40 taxa: wide enough that the jobs' trees plan a one-entry tail.
+    data = correlated_dna(40, 60, seed=1) if one_entry \
+        else correlated_dna(10, 160, seed=1)
 
     def run(unibatch):
         if unibatch:
@@ -390,6 +398,12 @@ def test_unibatch_bit_identical_and_measured(monkeypatch):
     batched = run(True)
     assert obs.counter("fleet.uni_batches") > uni0
     assert batched == solo                   # bitwise, not tolerance
+    if one_entry:
+        inst = PhyloInstance(data)
+        ev = inst.batch_evaluator()
+        prep = ev.prepare(inst.random_tree(1))
+        assert prep.st.profile[-1][0] == "e"
+        assert ev.unibatch_key(prep) is None
 
 
 # -- supervisor: fleet gangs are NOT lockstep --------------------------------

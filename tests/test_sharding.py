@@ -347,6 +347,44 @@ def test_sharded_traversal_reads_by_index_bitwise(problem, monkeypatch):
     assert np.array_equal(clv_a, clv_b) and np.array_equal(sc_a, sc_b)
 
 
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_sharded_one_entry_tail_matches(datatype, monkeypatch):
+    """The one-entry tail under GSPMD (a `lax.switch` a step over the
+    site-sharded arena, on the two meshes): lnL against the sharded
+    engine's today's tail and the one-device one-entry tail, 1e-12
+    relative in f64, on 40 taxa (a tree with a scanned tail).  The
+    engine asks the rule with a shard's row."""
+    from examl_tpu.io.alignment import build_alignment_data
+    from examl_tpu.ops import fastpath
+    rng = np.random.default_rng(41)
+    alphabet = {"DNA": "ACGT", "AA": "ARNDCQEGHILKMFPSTWYV"}[datatype]
+    names = [f"t{i}" for i in range(40)]
+    data = build_alignment_data(
+        names, ["".join(alphabet[c] for c in rng.integers(0, len(alphabet),
+                                                          300))
+                for _ in names], datatype_name=datatype)
+    newick = PhyloInstance(data).random_tree(seed=3).to_newick(names)
+    got = {}
+    for mesh in sorted(MESHES):
+        for threshold in (0, 1 << 62):
+            monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", threshold)
+            inst = PhyloInstance(data, block_multiple=4,
+                                 sharding=MESHES[mesh]())
+            (eng,) = inst.engines.values()
+            got[mesh, threshold] = inst.evaluate(
+                inst.tree_from_newick(newick), full=True)
+            prof = next(iter(eng._sched_cache.values())).profile
+            assert (prof[-1][0] == "e") == (threshold == 0)
+            assert eng.trav_row_bytes() * 4 == eng.B * eng.lane * eng.R \
+                * eng.K * np.dtype(eng.storage_dtype).itemsize
+    monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", 0)
+    one = PhyloInstance(data)
+    got["one"] = one.evaluate(one.tree_from_newick(newick), full=True)
+    for key, lnl in got.items():
+        assert np.isfinite(lnl)
+        assert lnl == pytest.approx(got["one"], rel=1e-12), key
+
+
 def test_sharded_tree_evaluate_makes_gradient_passes(sharded):
     """`tree_evaluate` on a site-sharded instance smooths with
     whole-tree gradient passes (O(1) dispatches a sweep, no fallback, no

@@ -423,6 +423,74 @@ def test_site_sharded_gradient_program_262144_one_all_reduce(
     _one_all_reduce_outside_loops(text)
 
 
+def _one_entry_structure(eng, flat, blocks: int, sharding=None):
+    """The structure the engine builds at `blocks` blocks a row under
+    `sharding`: from `fastpath.ONE_ENTRY_ROW_BYTES` a row (a shard's),
+    its narrow tail is one ("e", L) segment."""
+    eng.sched_cache_invalidate()
+    eng.B, eng.sharding = blocks, sharding
+    st = eng._fast_structure(flat)
+    eng.B, eng.sharding = 1, None
+    return st
+
+
+def _arena_copies(text: str, shape: tuple) -> int:
+    """Whole-arena `copy` instructions: a change of the arena's layout."""
+    head = "= f32[" + ",".join(str(d) for d in shape) + "]"
+    return sum(1 for ln in text.splitlines() if head in ln and " copy(" in ln)
+
+
+@pytest.mark.parametrize("datatype,blocks", [("DNA", 1024), ("AA", 128),
+                                             ("DNA", 128)])
+def test_one_entry_tail_program_reads_rows_by_index(one_chip, chip_compile,
+                                                    datatype, blocks):
+    """The chunk+evaluate program with the one-entry tail at cell 2's
+    row (140 x 131,072 DNA, 8 MiB), cell 3's (140 x 16,384 protein,
+    5 MiB) and the narrow cells' (140 x 16,384 DNA, 1 MiB, the
+    threshold): a step reads its inner children's rows by dynamic
+    slices (a protein row as its two halves of the rate axis), so the
+    program holds no operand slice, no arena-sized value in a loop but
+    the in-place writes, at 131,072 no gather of an arena, and no more
+    whole-arena layout copies than today's tail's program (a one-row
+    slice of the protein arena made the compiler add three)."""
+    _, eng, _, p, flat, today = _one_block_engine(datatype)
+    st = _one_entry_structure(eng, flat, blocks)
+    assert st.profile[-1][0] == "e", st.profile
+    assert today.profile[-1][0] == "s", today.profile
+    texts = []
+    for s in (today, st):
+        fn, args = _chunk_eval_call(eng, p, flat, s)
+        compiled = fn.lower(*_as_shapes(eng, args, blocks,
+                                        _on(one_chip))).compile()
+        texts.append(compiled.as_text())
+    _fits(compiled)
+    arena = (eng.num_rows, blocks, 128, 4, eng.K)
+    _reads_rows_by_index(compiled, int(np.prod(arena)),
+                         0 if blocks * 128 > 128 * 128 else
+                         arena_gathers(texts[1]))
+    assert _arena_copies(texts[1], arena) <= _arena_copies(texts[0], arena)
+
+
+def test_site_sharded_one_entry_tail_program_262144(topo, chip_compile):
+    """The four-chip deployment's traversal with the one-entry tail
+    (a shard's row is 4 MiB; forced here whatever the threshold): the
+    step's `lax.switch` over the sharded arena brings no collective into
+    a loop, and the root lnL sum stays the one collective; rows by
+    index, no operand slice."""
+    from examl_tpu.parallel.sharding import make_mesh, site_sharding
+    sh = site_sharding(make_mesh(devices=topo.devices[:4]))
+    _, eng, _, p, flat, _ = _one_block_engine("DNA")
+    blocks = 262144 // 128
+    st = fastpath.build_structure(flat, NTAXA, fastpath.ONE_ENTRY_ROW_BYTES)
+    assert st.profile[-1][0] == "e", st.profile
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    compiled = fn.lower(*_as_shapes(
+        eng, args, blocks, lambda kind: getattr(sh, kind))).compile()
+    assert _fits(compiled)["temporaries"] < 0.5e9
+    _reads_rows_by_index(compiled, eng.num_rows * (blocks // 4) * 128 * 16)
+    _one_all_reduce_outside_loops(compiled.as_text())
+
+
 def test_newton_program_compiles(one_chip, chip_compile):
     """`_newton_impl` (fused partial traversal + per-branch Newton: what
     `local_smooth`, -S pools and multi-process meshes smooth with) at

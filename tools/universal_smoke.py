@@ -75,8 +75,9 @@ def distinct_profile_trees(inst, want: int):
         p = tree.centroid_branch()
         if tree.is_tip(p.number):
             p = p.back
-        st = fastpath.build_structure(tree.flat_full_traversal(p),
-                                      inst.alignment.ntaxa)
+        st = fastpath.build_structure(
+            tree.flat_full_traversal(p), inst.alignment.ntaxa,
+            min(e.trav_row_bytes() for e in inst.engines.values()))
         if st.profile in seen:
             continue
         seen.add(st.profile)
