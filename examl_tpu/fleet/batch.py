@@ -433,7 +433,8 @@ class BatchEvaluator:
                  weights, tips):
             apply = fastpath.chunk_applier(dm, block_part, tips,
                                            eng.scale_exp,
-                                           eng.fast_precision)
+                                           eng.fast_precision,
+                                           eng.site_shards)
             clv, scaler = universal.run_universal(
                 alpha, cls, slot, cbase, lidx, ridx, lcode, rcode, zl,
                 zr, clv, scaler, apply.values, select=True)

@@ -57,9 +57,26 @@ def rates_to_matrix(rates: np.ndarray, states: int) -> np.ndarray:
 def sanitize_freqs(freqs: np.ndarray) -> np.ndarray:
     """Clamp to FREQ_MIN and renormalize.  Applied ONCE when parameters are
     installed into a ModelParams so the eigendecomposition and the kernels
-    (site likelihoods, sumtables) always see the same distribution."""
+    (site likelihoods, sumtables) always see the same distribution.
+
+    Where a state was floored, renormalizing leaves it just under the
+    floor (1/1001 for one state), and `eigen_gtr` sanitizes again: the
+    eigensystem would then be built on other frequencies than the ones
+    the root is weighted with (1e-5 of a small protein gene's lnL that
+    lacks several amino acids).  So such states are held AT the floor
+    and the others scaled to the rest, which a second call leaves as it
+    is; a vector nothing was floored in is the one it always was."""
     freqs = np.maximum(np.asarray(freqs, dtype=np.float64), FREQ_MIN)
-    return freqs / freqs.sum()
+    freqs = freqs / freqs.sum()
+    low = freqs < FREQ_MIN
+    while low.any():
+        freqs = np.where(low, FREQ_MIN, freqs * (1.0 - low.sum() * FREQ_MIN)
+                         / freqs[~low].sum())
+        more = (freqs < FREQ_MIN) & ~low
+        if not more.any():
+            break
+        low |= more
+    return freqs
 
 
 def sanitize_rates(rates: np.ndarray) -> np.ndarray:

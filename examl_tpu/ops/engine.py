@@ -433,6 +433,11 @@ class LikelihoodEngine:
         import weakref
 
         obs.inc("engine.instances")
+        # The bucket's packed site axis against its live patterns: the
+        # zero-weight lanes that pad every partition to whole blocks
+        # (parallel/packing.py) are 1 - patterns / lanes of every row.
+        obs.inc("engine.site_lanes", self.bucket.num_sites)
+        obs.inc("engine.site_patterns", self._patterns_true)
         # Unique per engine: two same-state engines in one process must
         # not alias each other's gauges — the ordinal disambiguates.
         seq = LikelihoodEngine._obs_seq
@@ -1219,9 +1224,14 @@ class LikelihoodEngine:
         if not self.universal_off and (self.universal_force
                                        or self.route_novel_to_universal):
             return 0
-        shards = 1 if self.sharding is None else self.sharding.site_shards
-        return (self.B * self.lane // shards * self.R * self.K
+        return (self.B * self.lane // self.site_shards * self.R * self.K
                 * np.dtype(self.storage_dtype).itemsize)
+
+    @property
+    def site_shards(self) -> int:
+        """How many ways the mesh cuts the site (block) axis; 1 without
+        one."""
+        return 1 if self.sharding is None else self.sharding.site_shards
 
     def _fast_structure(self, flat):
         from examl_tpu.ops import fastpath
@@ -1512,7 +1522,8 @@ class LikelihoodEngine:
                 zl, zr, dm, block_part, tips):
             apply = fastpath.chunk_applier(dm, block_part, tips,
                                            self.scale_exp,
-                                           self.fast_precision)
+                                           self.fast_precision,
+                                           self.site_shards)
             return universal.run_universal(
                 alpha, cls, slot, cbase, lidx, ridx, lcode, rcode, zl,
                 zr, clv, scaler, apply.values)
@@ -1538,7 +1549,8 @@ class LikelihoodEngine:
         hot chunks plus lax.scan long-tail groups."""
         from examl_tpu.ops import fastpath
         apply = fastpath.chunk_applier(dm, block_part, tips,
-                                       self.scale_exp, self.fast_precision)
+                                       self.scale_exp, self.fast_precision,
+                                       self.site_shards)
         return fastpath.run_segments(profile, base, lidx, ridx, lcode,
                                      rcode, zl, zr, clv, scaler, apply)
 
@@ -1984,9 +1996,8 @@ class LikelihoodEngine:
         the mesh, where `_grad_impl` runs inside `shard_map`, times
         R x K values of the compute dtype."""
         from examl_tpu.ops import gradient
-        shards = 1 if self.sharding is None else self.sharding.site_shards
         return gradient.wave_cap(
-            self.B * self.lane // shards * self.R * self.K
+            self.B * self.lane // self.site_shards * self.R * self.K
             * np.dtype(self.dtype).itemsize)
 
     def _grad_structure(self, flat):

@@ -653,12 +653,14 @@ def structure_chunks(st: FastStructure, zl, zr) -> Tuple[FastChunk, ...]:
 
 
 def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
-                  tips: kernels.TipState, scale_exp: int, precision):
+                  tips: kernels.TipState, scale_exp: int, precision,
+                  site_shards: int = 1):
     """The single-chunk kernel body (traced): P-build + child
     contractions + product + rescale + contiguous arena write.  Shared
     by the unrolled blocks, the lax.scan group bodies, and the
     reference `run_chunks` loop, so every execution strategy performs
-    the identical arithmetic."""
+    the identical arithmetic.  `site_shards`: how many ways a mesh cuts
+    the block axis the program sees whole (GSPMD)."""
     M = models.eign.shape[0]
     C = tips.table.shape[0]
     cdt = tips.table.dtype        # COMPUTE dtype; the arena may store
@@ -671,31 +673,14 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
         """Rows `idx` of an arena: `take_rows` for a chunk; a one-entry
         step's one row by a dynamic slice, which hands the dot the
         arena's own layout.  The v5e keeps a K = 4 arena's K
-        second-minor, so the dot reads R x K in place; a K = 20 arena
-        keeps R there, and for a one-row slice of it the compiler
-        copies the WHOLE arena into the dot's layout and back, three
-        times a call (PERF.md section 6, PR 41).  Where K > R the row
-        comes as a gather of its two halves of the rate axis instead,
-        which the compiler expands, as it does the head's gathers,
-        into slices copied into a block of the dot's layout: the row's
-        bytes, not the arena's."""
+        second-minor, so the dot reads R x K in place; a K = 20 arena's
+        row comes by `take_row`, which keeps the compiler from copying
+        the whole arena for it."""
         if idx.shape[0] > 1:
             return kernels.take_rows(arena, idx)
         if arena.ndim == 5 and arena.shape[4] > arena.shape[3] \
                 and arena.shape[3] % 2 == 0:
-            h = arena.shape[3] // 2
-            i = idx[0]
-            starts = jnp.stack([jnp.stack([i, jnp.zeros_like(i)]),
-                                jnp.stack([i, jnp.full_like(i, h)])])
-            dn = jax.lax.GatherDimensionNumbers(
-                offset_dims=(1, 2, 3, 4), collapsed_slice_dims=(0,),
-                start_index_map=(0, 3))
-            halves = jax.lax.gather(
-                arena, starts, dn, (1,) + arena.shape[1:3]
-                + (h, arena.shape[4]),
-                mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
-            # [2, B, lane, R/2, K] -> [1, B, lane, R, K]
-            return jnp.moveaxis(halves, 0, 2).reshape((1,) + arena.shape[1:])
+            return kernels.take_row(arena, idx[0], site_shards)
         return jax.lax.dynamic_slice_in_dim(arena, idx[0], 1)
 
     def tip_child(p, code, B, RK):

@@ -181,6 +181,53 @@ def take_rows(arena: jax.Array, idx: jax.Array) -> jax.Array:
     return rows.reshape(idx.shape + arena.shape[1:])
 
 
+def take_row(arena: jax.Array, i: jax.Array, shards: int = 1) -> jax.Array:
+    """`arena[i][None]`, bit for bit: ONE row [1, B, lane, R, K] of a
+    K > R arena (protein), R even; the traversal's one-entry step.
+
+    A one-row dynamic slice hands the dot the arena's own layout, and
+    the v5e keeps a K = 20 arena's R second-minor: for the slice the
+    compiler copies the WHOLE arena into the dot's layout and back,
+    three times a call (PERF.md section 6).  So the row comes as
+    a gather of its two halves of the rate axis, which the compiler
+    expands into slices copied into a block of the dot's layout: the
+    row's bytes, not the arena's.  A gather wider than `ONE_PIECE_SITES`
+    it would cut by slicing the whole arena (`take_rows`: a 156-block
+    arena ten times slower a step, PERF.md section 6), so a wider row
+    is gathered in n pieces of at most that many sites along the block
+    axis, each half of the rate axis, and joined again.
+
+    n counts a SHARD's blocks (`shards`, the site axis' mesh): under
+    GSPMD the shape seen here is the global one, and a shard's row of
+    one piece keeps the gather that indexes no block, the axis the mesh
+    cuts.  A shard's row of more pieces would index it; no deployment
+    is there (PERF.md section 7)."""
+    _, B, lane, R, K = arena.shape
+    h = R // 2
+    n = -(-(B // shards) // max(1, ONE_PIECE_SITES // lane))
+    # n pieces of b blocks, the last one moved back to end at B (it
+    # overlaps the one before where n * b > B); one piece indexes no block
+    b = -(-B // n)
+    firsts = [min(j * b, B - b) for j in range(n)]
+    axes = (0, 3) if n == 1 else (0, 1, 3)
+    starts = jnp.stack([
+        jnp.stack([i, jnp.full_like(i, r)] if n == 1 else
+                  [i, jnp.full_like(i, s), jnp.full_like(i, r)])
+        for s in firsts for r in (0, h)])
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1, 2, 3, 4), collapsed_slice_dims=(0,),
+        start_index_map=axes)
+    pieces = jax.lax.gather(
+        arena, starts, dn, (1, b, lane, h, K),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    # [n x 2, b, lane, R/2, K] -> [n, b, lane, R, K] -> the row
+    pieces = jnp.moveaxis(pieces.reshape(n, 2, b, lane, h, K),
+                          1, 3).reshape(n, b, lane, R, K)
+    return jnp.concatenate(
+        [pieces[j, j * b - s:min((j + 1) * b, B) - s]
+         for j, s in enumerate(firsts)])[None]
+
+
 def gather_child(tips: TipState, clv: jax.Array, scaler: jax.Array,
                  idx: jax.Array, ntips: int):
     """CLV + scaler of child nodes given 0-based node indices idx [...].

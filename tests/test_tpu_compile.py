@@ -152,11 +152,12 @@ def _grad_call(eng, p, flat, st, blocks: int, sharding=None):
     return jax.jit(eng._grad_impl), args, gs
 
 
-def _as_shapes(eng, args, blocks: int, place):
-    """`args` as ShapeDtypeStructs with the block axis scaled from 1 to
-    `blocks` (axis 1 of clv / scaler / tips.codes / tips.masks, axis 0
-    of block_part / weights); `place(kind)` gives each leaf's sharding, kind being the
-    SiteSharding attribute the engine would place it with."""
+def _as_shapes(eng, args, blocks: int, place, built: int = 1):
+    """`args` as ShapeDtypeStructs with the block axis scaled from
+    `built` to `blocks` (axis 1 of clv / scaler / tips.codes /
+    tips.masks, axis 0 of block_part / weights); `place(kind)` gives each
+    leaf's sharding, kind being the SiteSharding attribute the engine
+    would place it with."""
     block_axis = {id(eng.clv): (1, "clv"), id(eng.scaler): (1, "scaler"),
                   id(eng.tips.codes): (1, "scaler"),
                   id(eng.tips.masks): (1, "scaler"),
@@ -168,7 +169,7 @@ def _as_shapes(eng, args, blocks: int, place):
         shape, kind = tuple(x.shape), "replicated"
         if id(x) in block_axis:
             ax, kind = block_axis[id(x)]
-            assert shape[ax] == 1
+            assert shape[ax] == built
             shape = shape[:ax] + (blocks,) + shape[ax + 1:]
         dtype = jax.dtypes.canonicalize_dtype(x.dtype)
         return jax.ShapeDtypeStruct(shape, dtype, sharding=place(kind))
@@ -584,3 +585,98 @@ def test_spr_scan_programs_at_the_search_cells_sizes(
     want = "jit_spr_thorough_impl" if thorough else "jit_spr_scan_impl"
     assert want in name
     assert not re.search(r"^jit_impl(_eval)?\(", want + "(1)")
+
+
+# The gene-partitioned protein cell `aa144p58x16k.modopt`: 144 taxa, 58
+# LG+GAMMA genes of 64 to 977 patterns, each padded to whole blocks: 156
+# blocks (19,968 lanes) and M = 58 models stacked in one K = 20 engine.
+PARTS_TAXA, PARTS_M, PARTS_BLOCKS = 144, 58, 156
+# What this compiler counted for the two programs at that shape (f32,
+# x64 off), to the MiB.  The chunk program read 3,152,284,672 and four
+# operand slices while its one-entry step gathered a protein row wider
+# than 128 blocks whole (`kernels.take_row`).
+PARTS_TEMP_MAX = {"chunk": 1_398_950_400 + 2 ** 20,
+                  "grad": 4_298_854_912 + 2 ** 20}
+
+
+def _partitioned_engine():
+    """144 taxa x 58 protein partitions of 100 random sites (one block
+    each, LG with empirical frequencies) on CPU, f32, with a full
+    traversal's structure planned at the cell's 156 blocks a row."""
+    from examl_tpu.io.partitions import PartitionSpec
+    rng = np.random.default_rng(9)
+    names = [f"t{i}" for i in range(PARTS_TAXA)]
+    seqs = ["".join("ARNDCQEGHILKMFPSTWYV"[c]
+                    for c in rng.integers(0, 20, PARTS_M * 100))
+            for _ in names]
+    specs = [PartitionSpec(f"gene{k + 1}", "AA", "LG",
+                           np.arange(k * 100, (k + 1) * 100),
+                           empirical_freqs=True) for k in range(PARTS_M)]
+    inst = PhyloInstance(build_alignment_data(names, seqs, specs),
+                         dtype=jnp.float32)
+    (eng,) = inst.engines.values()
+    assert (eng.B, eng.num_parts, eng.K) == (PARTS_M, PARTS_M, 20)
+    eng.cache_put = lambda key, fn: fn       # hand back the raw jax.jit
+    tree = inst.random_tree(3)
+    p = tree.centroid_branch()
+    flat = tree.flat_full_traversal(p)
+    eng.B = PARTS_BLOCKS
+    st = eng._fast_structure(flat)
+    return eng, p, flat, st
+
+
+@pytest.mark.parametrize("program", ["chunk", "grad"])
+def test_partitioned_protein_programs_at_the_cells_shape(
+        one_chip, chip_compile, program):
+    """The two programs of the partitioned cell's step, the chunk
+    traversal with its root evaluation and the whole-tree gradient pass,
+    at 144 x 156 blocks with 58 models: each block reads its own gene's
+    model (`block_part`), and the compiler still cuts no arena into
+    operand slices, gathers no arena row, puts no arena-sized value in a
+    loop and adds no collective on one chip."""
+    eng, p, flat, st = _partitioned_engine()
+    if program == "chunk":
+        fn, args = _chunk_eval_call(eng, p, flat, st)
+    else:
+        fn, args, gs = _grad_call(eng, p, flat, st, PARTS_BLOCKS)
+        assert (gs.n_steps, gs.wave_w) == (PARTS_TAXA - 2, 1)
+    assert eng.models.ev.shape[0] == PARTS_M     # the stacked models
+    compiled = fn.lower(*_as_shapes(eng, args, PARTS_BLOCKS, _on(one_chip),
+                                    built=PARTS_M)).compile()
+    sizes = _fits(compiled)
+    text = compiled.as_text()
+    _reads_rows_by_index(compiled,
+                         eng.num_rows * PARTS_BLOCKS * 128 * 4 * 20)
+    for op in ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter"):
+        assert f" {op}(" not in text and f" {op}-start(" not in text
+    assert sizes["temporaries"] <= PARTS_TEMP_MAX[program]
+
+
+def test_site_sharded_partitioned_protein_chunk_program(topo, chip_compile):
+    """The partitioned cell's traversal with its block axis cut four
+    ways (GSPMD), 39 of the 156 blocks a chip: `kernels.take_row` counts
+    its pieces from a SHARD's row, so the one-entry step keeps the
+    gather of the two rate halves that indexes no block.  Counted from
+    the global 156 blocks it took two pieces along the sharded axis, and
+    this compiler added 10 all-gathers and 9 all-to-alls, all 19 in
+    loops, and 1,356,472,320 B of temporaries; here the root lnL sum
+    stays the one collective, outside every loop (621,116,928 B)."""
+    from examl_tpu.parallel.sharding import make_mesh, site_sharding
+    sh = site_sharding(make_mesh(devices=topo.devices[:4]))
+    eng, p, flat, _ = _partitioned_engine()
+    eng.sharding = sh                 # read where the program is traced
+    assert eng.site_shards == 4
+    # planned as the engine plans it there: a shard's row, 1.6 MB
+    st = fastpath.build_structure(flat, PARTS_TAXA, eng.trav_row_bytes())
+    assert st.profile[-1][0] == "e", st.profile
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    compiled = fn.lower(*_as_shapes(eng, args, PARTS_BLOCKS,
+                                    lambda kind: getattr(sh, kind),
+                                    built=PARTS_M)).compile()
+    assert _fits(compiled)["temporaries"] <= 621_116_928 + 2 ** 20
+    text = compiled.as_text()
+    assert operand_slices(text) == 0
+    assert _arena_sized_in_loops(
+        text, eng.num_rows * (PARTS_BLOCKS // 4) * 128 * 4 * 20) == []
+    _one_all_reduce_outside_loops(text)
