@@ -438,6 +438,9 @@ class LikelihoodEngine:
         # (parallel/packing.py) are 1 - patterns / lanes of every row.
         obs.inc("engine.site_lanes", self.bucket.num_sites)
         obs.inc("engine.site_patterns", self._patterns_true)
+        from examl_tpu.ops import fastpath
+        obs.gauge("engine.model_groups", fastpath.tail_p_models(
+            self.models.eign.shape[0], self.site_shards))
         # Unique per engine: two same-state engines in one process must
         # not alias each other's gauges — the ordinal disambiguates.
         seq = LikelihoodEngine._obs_seq
@@ -1343,6 +1346,10 @@ class LikelihoodEngine:
         # beside the entries they hold, as engine.grad_slots.
         obs.inc("engine.trav_slots", fastpath.profile_slots(st.profile))
         obs.inc("engine.trav_live_slots", flat.n)
+        if any(seg[0] == "e" for seg in st.profile) and \
+                fastpath.tail_p_models(self.models.eign.shape[0],
+                                       self.site_shards):
+            obs.inc("engine.grouped_dispatches")   # its tail's P built first
         if p_num is None:
             fn = self._fast_fn_flat(st.profile, with_eval=False)
             with self._phase("launch"):

@@ -65,7 +65,10 @@ moves bound it:
    (a tip child's row index is -1 and reads no row), the compute is a
    `lax.switch` over the three kinds of `chunk_applier`'s kernel at
    W = 1, and the one-row arena write stays outside it; the length
-   buckets (`bucket_len`) and replays repeat the final entry.
+   buckets (`bucket_len`) and replays repeat the final entry.  With
+   more than one model the tail's transition matrices, all its
+   entries', are built before its scan and each step takes its own
+   (`tail_p_models`).
 
 The resulting `profile` is a tuple of segments — `("u", kind, width)`
 for an unrolled block, `("s", glen, ((kind, width), ...))` for a scan
@@ -84,6 +87,7 @@ layout unrolled: the reference the tests hold `run_segments` to.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -652,6 +656,14 @@ def structure_chunks(st: FastStructure, zl, zr) -> Tuple[FastChunk, ...]:
 # -- execution ---------------------------------------------------------------
 
 
+def tail_p_models(M: int, site_shards: int) -> int:
+    """How many models' transition matrices a chunk program's one-entry
+    tail builds before its scan (`chunk_applier`'s `tail_p`): all M
+    where there are more than one and no mesh cuts the block axis, else
+    0 (each step builds its own)."""
+    return M if M > 1 and site_shards == 1 else 0
+
+
 def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
                   tips: kernels.TipState, scale_exp: int, precision,
                   site_shards: int = 1):
@@ -660,7 +672,18 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
     by the unrolled blocks, the lax.scan group bodies, and the
     reference `run_chunks` loop, so every execution strategy performs
     the identical arithmetic.  `site_shards`: how many ways a mesh cuts
-    the block axis the program sees whole (GSPMD)."""
+    the block axis the program sees whole (GSPMD).
+
+    `apply.tail_p` builds the transition matrices of a one-entry tail's
+    every entry ahead of its scan (`run_segments`), whose steps then
+    take their own by `values(..., pl, pr)`; None where
+    `tail_p_models` is 0: with one model a step builds its R matrices
+    in a few small dots, and a mesh's programs stay as they were.  With
+    M models a step builds M x R of them, 232 batched 20 x 20 x 20
+    products a child for 58 protein genes, which the v5e runs one small
+    matrix at a time; the same einsum over all the tail's entries at
+    once took 3.0 ms off that cell's 38.4 ms traversal call (PERF.md
+    section 6)."""
     M = models.eign.shape[0]
     C = tips.table.shape[0]
     cdt = tips.table.dtype        # COMPUTE dtype; the arena may store
@@ -710,18 +733,21 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
                                    precision=precision)
 
     @jax.named_scope("examl/newview")
-    def values(clv, scaler, ch: FastChunk):
+    def values(clv, scaler, ch: FastChunk, pl=None, pr=None):
         """The chunk's COMPUTED rows, no write: (v [W, B, lane, R, K]
         in the compute dtype, sc [W, B, lane]).  Split out of `apply`
         so the universal interpreter (ops/universal.py) can run the
         identical arithmetic inside a `lax.switch` branch while the
         arena write stays OUTSIDE the conditional — XLA copies carry
         buffers that are written inside cond branches (measured 7.6x
-        on CPU), but read-only operands flow through for free."""
+        on CPU), but read-only operands flow through for free.  `pl`,
+        `pr`: the children's [W, M, R, K, K] transition matrices where
+        they were built before the step (`tail_p`)."""
         rows, B, lane, R_, K = clv.shape
         RK = R_ * K
-        pl = kernels.p_matrices_wave(models, ch.zl)         # [W,M,R,K,K]
-        pr = kernels.p_matrices_wave(models, ch.zr)
+        if pl is None:
+            pl = kernels.p_matrices_wave(models, ch.zl)     # [W,M,R,K,K]
+            pr = kernels.p_matrices_wave(models, ch.zr)
         W = ch.width
         if ch.kind == 0:
             yl = tip_child(pl, ch.lcode, B, RK)
@@ -757,6 +783,8 @@ def chunk_applier(models: kernels.DeviceModels, block_part: jax.Array,
 
     apply.values = values
     apply.write = write
+    apply.tail_p = functools.partial(kernels.p_matrices_wave, models) \
+        if tail_p_models(M, site_shards) else None
     return apply
 
 
@@ -765,7 +793,9 @@ def run_chunks(models: kernels.DeviceModels, block_part: jax.Array,
                chunks, scale_exp: int, precision) -> Tuple[jax.Array, jax.Array]:
     """Execute an explicit chunk list unrolled, in order (traced; shapes
     static).  The REFERENCE execution strategy: the segment program
-    (`run_segments`) must match it bit for bit.
+    (`run_segments`) must match it bit for bit, and to f32 rounding
+    where many models' one-entry tail builds its P before its scan
+    (`chunk_applier`'s `tail_p`).
 
     clv is [rows, B, lane, R, K]; writes spill up to width-1 junk rows
     past each chunk's real entries — the arena reserves slack for the
@@ -794,9 +824,9 @@ def run_segments(profile, base, lidx, ridx, lcode, rcode, zl, zr,
         return jax.lax.slice_in_dim(a, o, o + w)
 
     def entry_branch(kind):
-        def branch(c, s, li, ri, lc, rc, zl_, zr_):
+        def branch(c, s, li, ri, lc, rc, zl_, zr_, *p):
             return apply.values(c, s, FastChunk(kind, 1, None, li, ri, lc,
-                                                rc, zl_, zr_))
+                                                rc, zl_, zr_), *p)
         return branch
 
     entry_branches = [entry_branch(k) for k in (0, 1, 2)]
@@ -827,6 +857,11 @@ def run_segments(profile, base, lidx, ridx, lcode, rcode, zl, zr,
         xs = (window(base, coff, glen * ns).reshape(glen, ns),
               reshape_xs(lidx), reshape_xs(ridx), reshape_xs(lcode),
               reshape_xs(rcode), reshape_xs(zl), reshape_xs(zr))
+        if seg[0] == "e" and apply.tail_p is not None:
+            # many models: every entry's P before the scan, a step's own
+            # handed to it (`chunk_applier`)
+            xs += tuple(apply.tail_p(window(z, off, span))[:, None]
+                        for z in (zl, zr))
 
         def body(carry, x, subs=subs):
             c, s = carry
@@ -848,11 +883,11 @@ def run_segments(profile, base, lidx, ridx, lcode, rcode, zl, zr,
             # stays outside the conditional (ops/universal.py: XLA
             # copies carry buffers written inside cond branches).
             c, s = carry
-            b, li, ri, lc, rc, zl_, zr_ = x
+            b, li, ri, lc, rc, zl_, zr_, *p = x
             kind = ((li[0] >= 0).astype(jnp.int32)
                     + (ri[0] >= 0).astype(jnp.int32))
             v, sc = jax.lax.switch(kind, entry_branches, c, s, li, ri,
-                                   lc, rc, zl_, zr_)
+                                   lc, rc, zl_, zr_, *p)
             return apply.write(c, s, v, sc, b[0]), None
 
         (clv, scaler), _ = jax.lax.scan(

@@ -271,3 +271,251 @@ def test_take_row_is_the_row_in_every_number_of_pieces(monkeypatch, blocks,
     # two halves of the rate axis a piece; a block start only in pieces
     assert gather.invars[1].aval.shape == (2 * pieces,
                                            2 if pieces == 1 else 3)
+
+
+# -- the one-entry tail's P built before its scan (fastpath.chunk_applier) ---
+
+# genes of one, two and three 128-lane blocks in turn
+GENE_WIDTHS = (90, 200, 300)
+
+
+def _packed(datatype, m, dtype=None, block_multiple=4, ntaxa=24):
+    """(instance, alignment) of `m` random genes of 1-3 blocks each,
+    packed to a multiple of `block_multiple` blocks (the trailing
+    padding blocks carry the last gene's id), each gene with its own
+    empirical frequencies and its own alpha."""
+    import jax.numpy as jnp
+
+    from examl_tpu.instance import PhyloInstance
+    from examl_tpu.io.alignment import build_alignment_data
+    from examl_tpu.io.partitions import PartitionSpec
+    from examl_tpu.models.gtr import with_alpha
+    alphabet = {"AA": "ARNDCQEGHILKMFPSTWYV", "DNA": "ACGT"}[datatype]
+    widths = [GENE_WIDTHS[k % 3] for k in range(m)]
+    ends = np.cumsum(widths)
+    rng = np.random.default_rng(m)
+    names = [f"t{i}" for i in range(ntaxa)]
+    letters = np.array(list(alphabet))
+    seqs = ["".join(letters[rng.integers(0, len(alphabet), ends[-1])])
+            for _ in names]
+    data = build_alignment_data(names, seqs, [
+        PartitionSpec(f"g{k}", datatype,
+                      "GTR" if datatype == "DNA" else "LG",
+                      np.arange(e - w, e), empirical_freqs=True)
+        for k, (w, e) in enumerate(zip(widths, ends))])
+    inst = PhyloInstance(data, dtype=dtype or jnp.float64,
+                         block_multiple=block_multiple)
+    inst.models[:] = [with_alpha(model, 0.3 + 0.25 * k)
+                      for k, model in enumerate(inst.models)]
+    inst.push_models()
+    return inst, data
+
+
+def _site_rel_err(got, want):
+    """Largest difference of CLV entries [..., R, K] over their site's
+    largest entry: f32 rounding in P's small, cancelling entries moves
+    a tiny likelihood by a large share of itself and its site by
+    nothing."""
+    scale = np.abs(want).max(axis=(-2, -1), keepdims=True)
+    return float((np.abs(got - want) / np.where(scale > 0, scale, 1)).max())
+
+
+def _random_lengths(tree):
+    """The tree with a branch length of its own on every branch, so a
+    step that took another entry's or the other child's matrices would
+    show."""
+    rng = np.random.default_rng(43)
+    for p, _ in tree.all_branches():
+        p.z[:] = [float(rng.uniform(0.2, 0.95))]
+    return tree
+
+
+def _tail_structure(inst):
+    """(engine, tree, its full traversal, the structure planned with the
+    one-entry tail, the kinds of the tail's entries).  A full
+    traversal's cherries are all in its first wave, so the tail holds
+    kinds 1 and 2 only."""
+    from examl_tpu.ops import fastpath
+    (eng,) = inst.engines.values()
+    tree = _random_lengths(inst.random_tree(3))
+    flat = tree.flat_full_traversal(tree.centroid_branch())
+    st = fastpath.build_structure(flat, inst.alignment.ntaxa,
+                                  fastpath.ONE_ENTRY_ROW_BYTES)
+    assert st.profile[-1][0] == "e", st.profile
+    L = st.profile[-1][1]
+    kinds = ((np.asarray(st.lidx)[-L:] >= 0).astype(int)
+             + (np.asarray(st.ridx)[-L:] >= 0))
+    return eng, tree, flat, st, set(kinds.tolist())
+
+
+def _both_forms(datatype, m, dtype):
+    """A full traversal's arena rows and scalers, each site's CLV over
+    its largest entry (the f32 and f64 scale thresholds differ), by the
+    unrolled reference `run_chunks`, whose steps build their own P, and
+    by the segment program, whose one-entry tail takes P built before
+    its scan."""
+    import jax.numpy as jnp
+
+    from examl_tpu.ops import fastpath
+    inst, _ = _packed(datatype, m, dtype)
+    eng, tree, flat, st, kinds = _tail_structure(inst)
+    assert kinds == {1, 2} and eng.num_parts == m and eng.B % 4 == 0
+    bp = np.asarray(eng.block_part)
+    assert bp[-1] == m - 1 and (bp == m - 1).sum() > -(-GENE_WIDTHS[
+        (m - 1) % 3] // 128)                 # trailing padding blocks
+    zl, zr = fastpath.refresh_z(st, flat, eng.num_branch_slots, eng.dtype)
+    apply = fastpath.chunk_applier(eng.models, eng.block_part, eng.tips,
+                                   eng.scale_exp, eng.fast_precision)
+    assert apply.tail_p is not None
+    rows = np.sort(st.row_of[st.row_of >= 0])
+    out = []
+    for c, s in (fastpath.run_chunks(
+            eng.models, eng.block_part, eng.tips, jnp.array(eng.clv),
+            jnp.array(eng.scaler), fastpath.structure_chunks(st, zl, zr),
+            eng.scale_exp, eng.fast_precision),
+                 fastpath.run_segments(
+            st.profile, st.base, st.lidx, st.ridx, st.lcode, st.rcode, zl,
+            zr, jnp.array(eng.clv), jnp.array(eng.scaler), apply)):
+        c = np.asarray(c)[rows].astype(np.float64)
+        out += [c / np.abs(c).max(axis=(-2, -1), keepdims=True),
+                np.asarray(s)[rows]]
+    return out
+
+
+@pytest.mark.parametrize("m", [2, 7])
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_tail_p_built_before_the_scan_is_each_steps_own(datatype, m):
+    """With M > 1 models the chunk program's one-entry tail takes each
+    step's transition matrices from P built for all its entries before
+    the scan; the unrolled reference `run_chunks` builds them in the
+    step, with the same einsum (`kernels.p_matrices_wave`).  Over
+    genes of one to three blocks and trailing padding blocks, in f64
+    the two are one arena to 1e-12 of a site, and in f32 they leave the
+    same scalers and an arena no further from the f64 one than twice
+    the reference's own distance (f32 rounding: an einsum over a batch
+    of entries sums in another order than over one; 3.6e-6 to 1.5e-4
+    of a site's largest entry here, which either form reaches within a
+    tenth; a gene's model on another gene's blocks moves a site by
+    percents); with one model there is nothing to build before the
+    scan."""
+    import jax
+    import jax.numpy as jnp
+
+    from examl_tpu.ops import fastpath
+    ref, _, new, _ = _both_forms(datatype, m, jnp.float64)
+    assert _site_rel_err(new, ref) < 1e-12
+    ref32, s1, new32, s2 = _both_forms(datatype, m, jnp.float32)
+    assert np.array_equal(s1, s2) and not np.array_equal(ref32, new32)
+    assert _site_rel_err(new32, ref) <= 2 * _site_rel_err(ref32, ref)
+    inst, _ = _packed(datatype, 1, jnp.float32)
+    (eng,) = inst.engines.values()
+    assert fastpath.chunk_applier(eng.models, eng.block_part, eng.tips,
+                                  eng.scale_exp,
+                                  eng.fast_precision).tail_p is None
+
+
+@pytest.mark.parametrize("m", [2, 7])
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_every_gene_through_the_one_entry_tail_against_the_oracle(
+        datatype, m, monkeypatch):
+    """The engine's own dispatch with the one-entry tail planned (its
+    row threshold forced down to these small rows): every gene's lnL,
+    f64, against `tests/oracle.py` to 1e-9, as in the cell-shaped test
+    above, and `engine.grouped_dispatches` counts the dispatch."""
+    from examl_tpu.ops import fastpath
+    monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", 1)
+    obs.reset()
+    inst, data = _packed(datatype, m)
+    (eng,) = inst.engines.values()
+    assert obs.registry().snapshot()["gauges"]["engine.model_groups"] == m
+    tree = _random_lengths(inst.random_tree(3))
+    total = inst.evaluate(tree, full=True)
+    flat = tree.flat_full_traversal(tree.centroid_branch())
+    assert eng._fast_structure(flat).profile[-1][0] == "e"
+    assert obs.counter("engine.grouped_dispatches") == 1
+    got = np.asarray(inst.per_partition_lnl, dtype=np.float64)
+    want = np.array(_oracle_parts(inst, data, tree))
+    np.testing.assert_allclose(got, want, rtol=1e-9)
+    assert total == pytest.approx(want.sum(), rel=1e-9)
+
+
+def test_one_model_counts_no_groups(monkeypatch):
+    """One partition: the gauge reads 0 and no dispatch counts."""
+    from examl_tpu.ops import fastpath
+    monkeypatch.setattr(fastpath, "ONE_ENTRY_ROW_BYTES", 1)
+    obs.reset()
+    inst, _ = _packed("DNA", 1)
+    assert obs.registry().snapshot()["gauges"]["engine.model_groups"] == 0
+    inst.evaluate(inst.random_tree(3), full=True)
+    assert obs.counter("engine.grouped_dispatches") == 0
+
+
+@pytest.mark.parametrize("nprocs", [1, 2, 4])
+def test_packed_block_part_is_non_decreasing(nprocs):
+    """`pack_partitions` lays each partition out as whole contiguous
+    blocks in order, and `pack_partitions_local` each process's window
+    of that layout: every block_part is non-decreasing, each gene's
+    blocks one run, and the trailing padding blocks carry the last
+    gene's id."""
+    import dataclasses
+
+    from examl_tpu.parallel.packing import (pack_partitions,
+                                            pack_partitions_local)
+    _, data = _packed("AA", 7)
+    parts = data.partitions
+    (glob,) = pack_partitions(parts, block_multiple=4).values()
+    bp = glob.block_part
+    assert (np.diff(bp) >= 0).all() and bp[-1] == len(parts) - 1
+    assert sorted(set(bp.tolist())) == list(range(len(parts)))
+    B = bp.shape[0]
+    lane = glob.lane
+    windows = []
+    for procid in range(nprocs):
+        s0 = procid * B // nprocs * lane
+        s1 = (procid + 1) * B // nprocs * lane
+        sliced = []
+        for k, part in enumerate(parts):
+            off = int(glob.part_offsets[k])
+            lo = min(max(s0 - off, 0), part.width)
+            hi = min(max(s1 - off, 0), part.width)
+            sliced.append(dataclasses.replace(
+                part, patterns=part.patterns[:, lo:hi],
+                weights=part.weights[lo:hi], global_width=part.width,
+                global_col_offset=lo))
+        (loc,) = pack_partitions_local(sliced, procid, nprocs,
+                                       block_multiple=4).values()
+        assert (np.diff(loc.block_part) >= 0).all()
+        windows.append(loc.block_part)
+    assert np.array_equal(np.concatenate(windows), bp)
+
+
+@pytest.mark.parametrize("width", [1, 8])
+@pytest.mark.parametrize("kind", [0, 1, 2])
+@pytest.mark.parametrize("datatype", ["DNA", "AA"])
+def test_a_step_given_its_p_is_the_step_building_it(datatype, kind, width):
+    """`chunk_applier`'s kernel of every kind, at one entry and at
+    eight, on 7 genes of 1-3 blocks with trailing padding blocks: the
+    rows and scalers it computes from the children's P handed to it
+    (`tail_p`, as the one-entry tail's steps take theirs) are those it
+    computes building P itself, to f32 rounding and exactly."""
+    import jax.numpy as jnp
+
+    from examl_tpu.ops import fastpath
+    inst, _ = _packed(datatype, 7, jnp.float32)
+    (eng,) = inst.engines.values()
+    ntips = inst.alignment.ntaxa
+    inst.evaluate(inst.random_tree(3), full=True)   # rows to read
+    rng = np.random.default_rng(kind * 10 + width)
+    rows = jnp.asarray(rng.integers(0, ntips - 2, (2, width)), jnp.int32)
+    tips_ = jnp.asarray(rng.integers(0, ntips, (2, width)), jnp.int32)
+    z = jnp.asarray(rng.uniform(0.2, 0.95, (2, width, 1)), jnp.float32)
+    ch = fastpath.FastChunk(kind, width, jnp.int32(0), rows[0], rows[1],
+                            tips_[0], tips_[1], z[0], z[1])
+    apply = fastpath.chunk_applier(eng.models, eng.block_part, eng.tips,
+                                   eng.scale_exp, eng.fast_precision)
+    v1, s1 = apply.values(eng.clv, eng.scaler, ch)
+    v2, s2 = apply.values(eng.clv, eng.scaler, ch, apply.tail_p(z[0]),
+                          apply.tail_p(z[1]))
+    assert v2.shape == (width, eng.B, eng.lane, eng.R, eng.K)
+    assert np.array_equal(np.asarray(s1), np.asarray(s2))
+    assert _site_rel_err(np.asarray(v2), np.asarray(v1)) < 1e-5

@@ -53,6 +53,16 @@ GRAD_TEMP_MAX = {"dna131k": 3_950_031_872 + 2 ** 20,
 # patterns on a CPU device, x64 off, since PR 36 (PERF.md section 6):
 # one entry a step there before and after PR 39, so not a letter moved.
 GRAD_TEXT_PR36 = {NTAXA: "22eeddda69dad6bf", 49: "ab1572ab8449bb08"}
+# sha256 (first 16) of the chunk traversal programs' lowered text
+# (`jit_impl_eval`, `jit_impl`), one model, CPU device, x64 off, planned
+# with the one-entry tail at the one-chip cells' rows, recorded before a
+# stack of models built its tail's P ahead of the scan: one model's
+# program is this text letter for letter.
+TRAV_TEXT_ONE_MODEL = {
+    ("DNA", NTAXA, 128): ("9c92d7d52ba36c3b", "1697fbb46c984902"),
+    ("DNA", NTAXA, 1024): ("ecfc636df41ec2dd", "7f3fe5e6910fe929"),
+    ("AA", NTAXA, 128): ("f794022d7ea92d04", "b66f8e467799bf95"),
+    ("DNA", 49, 1024): ("4038f9c88b9ceb6b", "a753a1d406ae1d29")}
 
 
 @pytest.fixture(scope="module")
@@ -625,6 +635,36 @@ def _partitioned_engine():
     return eng, p, flat, st
 
 
+@pytest.mark.parametrize("config", sorted(TRAV_TEXT_ONE_MODEL))
+def test_one_model_traversal_programs_text_unchanged(chip_compile, config):
+    """One model: the chunk programs, with and without the root
+    evaluation, at the one-chip cells' shapes lower to the recorded
+    text, so the model-stacking path (`chunk_applier`'s `tail_p`)
+    leaves every one-partition cell's traversal as it was."""
+    datatype, ntaxa, blocks = config
+    _, eng, _, p, flat, _ = _one_block_engine(datatype, ntaxa)
+    st = _one_entry_structure(eng, flat, blocks)
+    assert st.profile[-1][0] == "e", st.profile
+    cpu = SingleDeviceSharding(jax.devices("cpu")[0])
+    fn, args = _chunk_eval_call(eng, p, flat, st)
+    trav = eng._fast_fn_flat(st.profile, with_eval=False)
+    got = tuple(
+        hashlib.sha256(f.lower(*_as_shapes(eng, a, blocks, lambda kind: cpu)
+                               ).as_text().encode()).hexdigest()[:16]
+        for f, a in ((fn, args), (trav, args[:9] + (eng.models,
+                                                    eng.block_part,
+                                                    eng.tips))))
+    assert got == TRAV_TEXT_ONE_MODEL[config]
+
+
+def _p_builds_in_loops(text: str) -> int:
+    """Instructions of an optimized HLO text inside a `while` loop that
+    are the einsum building transition matrices from eigensystems
+    (`kernels.p_matrices_wave`), by their `op_name`."""
+    return sum(b.count("mraj,wmrj,mrjk->wmrak")
+               for b in loop_bodies(text).values())
+
+
 @pytest.mark.parametrize("program", ["chunk", "grad"])
 def test_partitioned_protein_programs_at_the_cells_shape(
         one_chip, chip_compile, program):
@@ -633,7 +673,11 @@ def test_partitioned_protein_programs_at_the_cells_shape(
     at 144 x 156 blocks with 58 models: each block reads its own gene's
     model (`block_part`), and the compiler still cuts no arena into
     operand slices, gathers no arena row, puts no arena-sized value in a
-    loop and adds no collective on one chip."""
+    loop and adds no collective on one chip.  The traversal's one-entry
+    tail builds no transition matrix inside its loop: the 58 models' P
+    for every entry are built before it (built in the steps, 232 a
+    child a step, the loops held 42 such instructions), and its
+    temporaries stay within the bound."""
     eng, p, flat, st = _partitioned_engine()
     if program == "chunk":
         fn, args = _chunk_eval_call(eng, p, flat, st)
@@ -651,6 +695,9 @@ def test_partitioned_protein_programs_at_the_cells_shape(
                "collective-permute", "reduce-scatter"):
         assert f" {op}(" not in text and f" {op}-start(" not in text
     assert sizes["temporaries"] <= PARTS_TEMP_MAX[program]
+    if program == "chunk":
+        assert _p_builds_in_loops(text) == 0
+        assert "mraj,wmrj,mrjk->wmrak" in text       # the head's chunks
 
 
 def test_site_sharded_partitioned_protein_chunk_program(topo, chip_compile):
