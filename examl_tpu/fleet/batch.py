@@ -23,6 +23,20 @@ Job counts pad to a power of two (padding jobs replay job 0, results
 discarded) so compiled variants stay O(log J) and the real/padded
 ratio is the `fleet.batch_occupancy` evidence.
 
+Each batched program traces as an XLA module of its own, so a device
+trace tells them apart: `jit_fleet_eval_impl` (the fast batch),
+`jit_fleet_scan_impl`, `jit_fleet_uni_impl` (the universal batch),
+`jit_fleet_weights_impl` and `jit_fleet_grad_impl` (the gradient
+smoothing sweep).  Every batched dispatch raises
+`fleet.batched_dispatches` (+1), `fleet.batch_jobs` (+J live jobs) and
+`fleet.batch_slots` (+jpad), in all and per family
+(`fleet.batch_jobs.grad`, ...; families `eval`, `scan`, `uni`,
+`weights`, `grad`): a window reads the rise of these counters.  The
+`fleet.batch_occupancy` gauge keeps only the last batch's J/jpad.
+Spans: `fleet:eval` (each eval launch, every tier), `fleet:grad_smooth`
+(a gradient sweep's dispatch), `fleet:smooth_update` (the host Newton
+update between sweeps), `fleet:weights_eval`.
+
 Every batched program here enters the engine's shared cache through
 `cache_put`, which routes it through the exported program bank
 (ops/export_bank.py) when EXAML_EXPORT_BANK is on: a respawned fleet
@@ -52,6 +66,17 @@ from examl_tpu.utils import bucket_len, next_pow2, z_slots
 # Batch-group key for shared-topology weight replicates: the driver's
 # grouping and the evaluator's compiled-pad bookkeeping must agree.
 WEIGHTS_GROUP = ("weights",)
+
+
+def count_dispatch(family: str, jobs: int, jpad: int) -> None:
+    """One batched dispatch in the window counters, in all and per
+    family (`eval`, `scan`, `uni`, `weights`, `grad`): the dispatch, its
+    live jobs and its padded slots."""
+    for name, value in (("fleet.batched_dispatches", 1),
+                        ("fleet.batch_jobs", jobs),
+                        ("fleet.batch_slots", jpad)):
+        obs.inc(name, value)
+        obs.inc(f"{name}.{family}", value)
 
 
 class PreparedJob:
@@ -209,8 +234,9 @@ class BatchEvaluator:
         if fn is not None:
             return fn
 
-        def body(clv, scaler, base, lidx, ridx, lcode, rcode, zl, zr,
-                 p_idx, q_idx, zv, dm, block_part, weights, tips):
+        def fleet_eval_impl(clv, scaler, base, lidx, ridx, lcode, rcode,
+                            zl, zr, p_idx, q_idx, zv, dm, block_part,
+                            weights, tips):
             clv, scaler = eng._run_segments_impl(
                 dm, block_part, tips, clv, scaler, profile, base, lidx,
                 ridx, lcode, rcode, zl, zr)
@@ -221,7 +247,7 @@ class BatchEvaluator:
         # No donation: the body returns only the lnL rows, so the
         # stacked arenas have no donatable destination (jax would warn
         # "donated buffers were not usable" on every dispatch).
-        vb = jax.vmap(body, in_axes=(0,) * 12 + (None,) * 4)
+        vb = jax.vmap(fleet_eval_impl, in_axes=(0,) * 12 + (None,) * 4)
         return eng.cache_put(key, jax.jit(vb))
 
     def _scan_fn(self, eng, shape, jpad: int):
@@ -230,13 +256,13 @@ class BatchEvaluator:
         if fn is not None:
             return fn
 
-        def body(buf, scaler, tv, p_idx, q_idx, zv, dm, block_part,
-                 weights, tips, sr):
+        def fleet_scan_impl(buf, scaler, tv, p_idx, q_idx, zv, dm,
+                            block_part, weights, tips, sr):
             return eng._trav_eval_impl(buf, scaler, (), tv, p_idx, q_idx,
                                        zv, dm, block_part, weights, tips,
                                        sr)
 
-        vb = jax.vmap(body,
+        vb = jax.vmap(fleet_scan_impl,
                       in_axes=(0, 0, Traversal(0, 0, 0, 0, 0), 0, 0, 0,
                                None, None, None, None, None))
         return eng.cache_put(key, jax.jit(vb, donate_argnums=(0, 1)))
@@ -247,15 +273,15 @@ class BatchEvaluator:
         if fn is not None:
             return fn
 
-        def body(w, clv, scaler, p_idx, q_idx, zv, dm, block_part, tips,
-                 sr):
+        def fleet_weights_impl(w, clv, scaler, p_idx, q_idx, zv, dm,
+                               block_part, tips, sr):
             return kernels.root_log_likelihood(
                 dm, block_part, w, tips, clv, scaler, p_idx, q_idx, zv,
                 eng.num_parts, eng.scale_exp, eng.ntips, sr)
 
         # The engine's LIVE arena rides along un-donated (it is read by
         # every job and must survive the dispatch).
-        vb = jax.vmap(body, in_axes=(0,) + (None,) * 9)
+        vb = jax.vmap(fleet_weights_impl, in_axes=(0,) + (None,) * 9)
         return eng.cache_put(key, jax.jit(vb))
 
     # -- dispatch ------------------------------------------------------------
@@ -356,8 +382,9 @@ class BatchEvaluator:
         pq = [(self._gidx_st(j.st, j.p.number),
                self._gidx_st(j.st, j.p.back.number)) for j in jobs]
         obs.inc("engine.dispatch_count")
-        with obs.span("fleet:batch_eval",
-                      args={"jobs": len(jobs), "jpad": jpad}):
+        count_dispatch("eval", len(jobs), jpad)
+        with obs.span("fleet:eval", args={"jobs": len(jobs), "jpad": jpad,
+                                          "tier": "fast"}):
             out = fn(clv, scaler,
                      self._pad_stack([j.st.base for j in jobs], jpad),
                      self._pad_stack([j.st.lidx for j in jobs], jpad),
@@ -394,8 +421,9 @@ class BatchEvaluator:
         pq = [(self._gidx_identity(j.p.number),
                self._gidx_identity(j.p.back.number)) for j in jobs]
         obs.inc("engine.dispatch_count")
-        with obs.span("fleet:batch_eval_scan",
-                      args={"jobs": len(jobs), "jpad": jpad}):
+        count_dispatch("scan", len(jobs), jpad)
+        with obs.span("fleet:eval", args={"jobs": len(jobs), "jpad": jpad,
+                                          "tier": "scan"}):
             _, _, out = fn(clv, scaler, tv,
                            self._pad_stack([jnp.int32(p) for p, _ in pq],
                                            jpad),
@@ -428,9 +456,9 @@ class BatchEvaluator:
         from examl_tpu.ops import universal
         alpha = universal.alphabet(akey)
 
-        def body(clv, scaler, cls, slot, cbase, lidx, ridx, lcode,
-                 rcode, zl, zr, p_idx, q_idx, zv, dm, block_part,
-                 weights, tips):
+        def fleet_uni_impl(clv, scaler, cls, slot, cbase, lidx, ridx,
+                           lcode, rcode, zl, zr, p_idx, q_idx, zv, dm,
+                           block_part, weights, tips):
             apply = fastpath.chunk_applier(dm, block_part, tips,
                                            eng.scale_exp,
                                            eng.fast_precision,
@@ -443,7 +471,7 @@ class BatchEvaluator:
                 q_idx, zv, eng.num_parts, eng.scale_exp, eng.ntips,
                 None)
 
-        vb = jax.vmap(body, in_axes=(0,) * 14 + (None,) * 4)
+        vb = jax.vmap(fleet_uni_impl, in_axes=(0,) * 14 + (None,) * 4)
         return eng.cache_put(key, jax.jit(vb))
 
     def launch_universal(self, jobs: List[PreparedJob], key,
@@ -494,8 +522,10 @@ class BatchEvaluator:
             pq = [(self._gidx_st(j.st, j.p.number),
                    self._gidx_st(j.st, j.p.back.number)) for j in jobs]
             obs.inc("engine.dispatch_count")
-            with obs.span("fleet:batch_universal",
-                          args={"jobs": J, "jpad": jpad, "steps": npad}):
+            count_dispatch("uni", J, jpad)
+            with obs.span("fleet:eval", args={"jobs": J, "jpad": jpad,
+                                              "tier": "universal",
+                                              "steps": npad}):
                 out = fn(clv, scaler,
                          self._pad_stack([d[0] for d in descs], jpad),
                          self._pad_stack([d[1] for d in descs], jpad),
@@ -558,9 +588,9 @@ class BatchEvaluator:
         if fn is not None:
             return fn
 
-        def body(clv, scaler, base, lidx, ridx, lcode, rcode, zl, zr,
-                 p_row, q_row, p_g, q_g, tvp, ex_rows, ey_gidx, ez,
-                 dm, block_part, weights, tips):
+        def fleet_grad_impl(clv, scaler, base, lidx, ridx, lcode, rcode,
+                            zl, zr, p_row, q_row, p_g, q_g, tvp, ex_rows,
+                            ey_gidx, ez, dm, block_part, weights, tips):
             clv, scaler = eng._run_segments_impl(
                 dm, block_part, tips, clv, scaler, profile, base, lidx,
                 ridx, lcode, rcode, zl, zr)
@@ -568,7 +598,7 @@ class BatchEvaluator:
                                   tvp, ex_rows, ey_gidx, ez, dm,
                                   block_part, weights, tips, None)
 
-        vb = jax.vmap(body, in_axes=(0,) * 17 + (None,) * 4)
+        vb = jax.vmap(fleet_grad_impl, in_axes=(0,) * 17 + (None,) * 4)
         return eng.cache_put(key, jax.jit(vb))
 
     def _grad_batch(self, jobs: List[PreparedJob], jpad: int):
@@ -626,6 +656,7 @@ class BatchEvaluator:
                 zr=stk([d[0][7] for d in dyn], eng.dtype))
             obs.inc("engine.dispatch_count")
             obs.inc("engine.grad_pass_dispatches")
+            count_dispatch("grad", J, jpad)
             with obs.span("fleet:grad_smooth",
                           args={"jobs": J, "jpad": jpad}):
                 e1, e2 = fn(
@@ -685,29 +716,30 @@ class BatchEvaluator:
         done = np.zeros(J, dtype=bool)
         for _ in range(max(1, 4 * maxtimes)):
             d1, d2 = self._grad_batch(jobs, jpad)      # [J, E, C]
-            z0 = np.clip(np.stack(
-                [[z_slots(s.z, self.C) for s in sl] for sl in slot_lists]),
-                ZMIN, ZMAX)
-            znew = gradient.newton_step(z0, d1, d2)
-            step = np.log(znew) - np.log(z0)
-            if scale is None:
-                scale = np.full_like(step, damping)
-            else:
-                flip = prev_step * step < 0.0
-                scale = np.maximum(
-                    np.where(flip, scale * 0.5,
-                             np.minimum(scale * 1.2, damping)),
-                    1.0 / 64)
-            prev_step = step
-            zapp = np.clip(z0 * np.exp(step * scale), ZMIN, ZMAX)
-            zapp = np.where(done[:, None, None], z0, zapp)
-            moved = np.abs(zapp - z0) > DELTAZ
-            for ji, sl in enumerate(slot_lists):
-                if done[ji]:
-                    continue
-                for i, s in enumerate(sl):
-                    s.z[:] = zapp[ji, i].tolist()
-            done |= ~moved.any(axis=(1, 2))
+            with obs.span("fleet:smooth_update", args={"jobs": J}):
+                z0 = np.clip(np.stack(
+                    [[z_slots(s.z, self.C) for s in sl]
+                     for sl in slot_lists]), ZMIN, ZMAX)
+                znew = gradient.newton_step(z0, d1, d2)
+                step = np.log(znew) - np.log(z0)
+                if scale is None:
+                    scale = np.full_like(step, damping)
+                else:
+                    flip = prev_step * step < 0.0
+                    scale = np.maximum(
+                        np.where(flip, scale * 0.5,
+                                 np.minimum(scale * 1.2, damping)),
+                        1.0 / 64)
+                prev_step = step
+                zapp = np.clip(z0 * np.exp(step * scale), ZMIN, ZMAX)
+                zapp = np.where(done[:, None, None], z0, zapp)
+                moved = np.abs(zapp - z0) > DELTAZ
+                for ji, sl in enumerate(slot_lists):
+                    if done[ji]:
+                        continue
+                    for i, s in enumerate(sl):
+                        s.z[:] = zapp[ji, i].tolist()
+                done |= ~moved.any(axis=(1, 2))
             obs.inc("fleet.grad_smooth_sweeps")
             if done.all():
                 return True
@@ -752,6 +784,7 @@ class BatchEvaluator:
             buf, _aux = eng._state()
             zv = jnp.asarray(z_slots(p.z, self.C), dtype=eng.dtype)
             obs.inc("engine.dispatch_count")
+            count_dispatch("weights", J, jpad)
             with obs.span("fleet:weights_eval",
                           args={"jobs": J, "jpad": jpad}):
                 out = fn(self._pad_stack(

@@ -46,7 +46,10 @@ seconds (`self_s`: duration less child spans'):
     first_call:<family>/analyze                the jitted call (the compile
                                                or the cache load), and the
                                                program table's row
-  search:*, phase:*, fleet:*                 off the benchmark's timed path
+  search:*, phase:*                          off the benchmark's timed path
+  fleet:eval, fleet:grad_smooth,             the fleet's batched dispatches
+    fleet:smooth_update, fleet:weights_eval    and the host update between
+                                               smoothing sweeps
 
   engine.compile_count, engine.compile_seconds[.family]   the jitted
                                first call alone (the `compile:` span)
@@ -65,6 +68,11 @@ seconds (`self_s`: duration less child spans'):
                                scan-tier family compiled in-process
                                (watchdogged; expected, not a gap)
   engine.watchdog_barks        compile-deadline watchdog firings
+  fleet.batched_dispatches, fleet.batch_jobs, fleet.batch_slots
+                               every batched fleet dispatch: +1, +its
+                               live jobs, +its padded slots, in all and
+                               per family (`.eval`, `.scan`, `.uni`,
+                               `.weights`, `.grad`; fleet/batch.py)
   engine.nonfinite_retries/.nonfinite_recovered   NaN-lnL scan-tier retries
   bank.families/banked/timeouts/errors/skipped/fallbacks   AOT banking
   bank.compile.<family>        per-family subprocess compile (timers)

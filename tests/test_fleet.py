@@ -680,3 +680,88 @@ def test_supervised_kill_mid_fleet_resumes(tmp_path):
     # most one in-flight batch (2 jobs) repeats its cycle.
     assert len(started) <= 6 + 2
     assert sum(1 for e in evs if e["kind"] == "batch.dispatch") >= 3
+
+
+# -- program names and dispatch counters ---------------------------------------
+
+
+def _captured_programs(monkeypatch):
+    """Every batched program as the evaluator hands it to the engine's
+    cache, with the arguments of its first call."""
+    from examl_tpu.ops.engine import LikelihoodEngine
+    seen = {}
+    real = LikelihoodEngine.cache_put
+
+    def put(self, key, fn):
+        cached = real(self, key, fn)
+
+        def first(*args):
+            seen.setdefault(key[0], (fn, args))
+            return cached(*args)
+        return first
+    monkeypatch.setattr(LikelihoodEngine, "cache_put", put)
+    return seen
+
+
+@pytest.mark.parametrize("family,key,module", [
+    ("eval", "fleet", "jit_fleet_eval_impl"),
+    ("scan", "fleetscan", "jit_fleet_scan_impl"),
+    ("uni", "unibatch", "jit_fleet_uni_impl"),
+    ("weights", "fleetw", "jit_fleet_weights_impl"),
+    ("grad", "fleetgrad", "jit_fleet_grad_impl")])
+def test_batched_program_lowers_under_its_own_name(monkeypatch, family, key,
+                                                   module):
+    """Each batched program traces as an XLA module of its own, so a
+    device trace tells the five apart (they all lowered as `jit_body`
+    before); the batch's dispatch raises the family's counters."""
+    from examl_tpu import obs
+    from examl_tpu.fleet import bootstrap, seeds
+    seen = _captured_programs(monkeypatch)
+    data = correlated_dna(14, 200, seed=3)
+    inst = PhyloInstance(data, rate_model="PSR" if family == "scan"
+                         else "GAMMA")
+    ev, group = _profile_group(inst)
+    preps = [prep for _, prep in group]
+    before = {n: obs.counter(f"{n}.{family}") for n in (
+        "fleet.batched_dispatches", "fleet.batch_jobs", "fleet.batch_slots")}
+    if family in ("eval", "scan"):
+        ev.eval_batch(preps)
+    elif family == "uni":
+        ev.collect(ev.launch_universal(preps[:1],
+                                       ev.unibatch_key(preps[0])))
+    elif family == "weights":
+        ev.eval_weights_batch(group[0][0], [
+            bootstrap.bootstrap_weights(data, seeds.derive(1, "bootstrap", k))
+            for k in range(3)])
+    else:
+        ev.smooth_batch(preps[:1], 1)
+    fn, args = seen[key]
+    text = fn.lower(*args).as_text()
+    assert f"module @{module} " in text
+    assert obs.counter(f"fleet.batched_dispatches.{family}") > \
+        before["fleet.batched_dispatches"]
+    assert obs.counter(f"fleet.batch_slots.{family}") >= \
+        obs.counter(f"fleet.batch_jobs.{family}") > before["fleet.batch_jobs"]
+
+
+def test_dispatch_counters_add_live_jobs_and_padded_slots():
+    """A 3-job batch pads to 4: one dispatch, 3 jobs, 4 slots, in all
+    and in its family; a smoothing sweep's update runs under its span."""
+    from examl_tpu import obs
+    data = correlated_dna(14, 200, seed=3)
+    inst = PhyloInstance(data)
+    ev, group = _profile_group(inst, want=3)
+    names = ("fleet.batched_dispatches", "fleet.batch_jobs",
+             "fleet.batch_slots")
+    before = {n: (obs.counter(n), obs.counter(n + ".eval")) for n in names}
+    ev.eval_batch([prep for _, prep in group[:3]])
+    for n, want in zip(names, (1, 3, 4)):
+        assert obs.counter(n) - before[n][0] == want
+        assert obs.counter(n + ".eval") - before[n][1] == want
+    reg = obs.registry()
+    sweeps = reg.counter("fleet.grad_smooth_sweeps")
+    updates = reg.snapshot()["timers"].get("fleet:smooth_update",
+                                           {}).get("count", 0)
+    ev.smooth_batch([group[0][1]], 1)
+    assert reg.snapshot()["timers"]["fleet:smooth_update"]["count"] - \
+        updates == reg.counter("fleet.grad_smooth_sweeps") - sweeps >= 1
